@@ -2,8 +2,8 @@
 
 Prints ``name,us_per_call,derived`` CSV rows.  Each module's ``run()``
 reproduces the measurement behind the corresponding paper artifact at
-CPU-feasible scale; the roofline table (EXPERIMENTS.md) comes from the
-dry-run (repro.launch.dryrun), not from here.
+CPU-feasible scale; the roofline table comes from the dry-run
+(repro.launch.dryrun), not from here.
 
 ``--json PATH`` additionally writes the machine-readable results
 (``{name: us_per_call}``) so the perf trajectory is tracked in-repo:
@@ -47,6 +47,8 @@ def main() -> None:
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="also write {name: us_per_call} JSON to PATH")
     args = ap.parse_args()
+    from repro.launch import compile_cache
+    compile_cache.enable(Path(__file__).resolve().parents[1])
 
     selected = MODULES
     if args.only:

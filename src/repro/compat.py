@@ -1,63 +1,35 @@
-"""Compatibility shims over moving JAX APIs (supports jax >= 0.4.37).
+"""Thin wrappers over JAX APIs the distribution layer and kernels share.
 
-The distribution layer targets the modern surface (``jax.shard_map`` with
-``check_vma``, ``jax.make_mesh(..., axis_types=...)``, ``AxisType``); on
-older installs we fall back to ``jax.experimental.shard_map`` /
-``check_rep`` and positional ``make_mesh``.  Import from here, never from
-``jax.sharding`` directly, for any of these three names.
+Import ``AxisType``, ``make_mesh``, ``shard_map`` and
+``tpu_compiler_params`` from here, so a future API move is one edit.
 """
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5-era explicit-sharding API
-    from jax.sharding import AxisType  # type: ignore[attr-defined]
-    _HAVE_AXIS_TYPE = True
-except ImportError:  # pragma: no cover - depends on installed jax
-    _HAVE_AXIS_TYPE = False
-
-    class AxisType:  # minimal stand-in: old meshes behave as Auto
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
+from jax.sharding import AxisType
 
 
 def make_mesh(axis_shapes, axis_names, *, axis_types=None, devices=None):
-    """``jax.make_mesh`` that tolerates the absence of ``axis_types``."""
+    """``jax.make_mesh`` with every axis ``Auto`` unless told otherwise."""
+    if axis_types is None:
+        axis_types = (AxisType.Auto,) * len(tuple(axis_names))
     kwargs = {"devices": devices} if devices is not None else {}
-    if _HAVE_AXIS_TYPE:
-        if axis_types is None:
-            axis_types = (AxisType.Auto,) * len(tuple(axis_names))
-        try:
-            return jax.make_mesh(axis_shapes, axis_names,
-                                 axis_types=axis_types, **kwargs)
-        except TypeError:  # pragma: no cover - transitional versions
-            pass
-    return jax.make_mesh(axis_shapes, axis_names, **kwargs)
+    return jax.make_mesh(axis_shapes, axis_names, axis_types=axis_types,
+                         **kwargs)
 
 
 def tpu_compiler_params(*, dimension_semantics=None, **kwargs):
-    """Mosaic compiler params across the ``TPUCompilerParams`` ->
-    ``CompilerParams`` rename (jax 0.4.x vs newer).  Used to annotate
-    pallas grids with ``dimension_semantics`` ('parallel' axes may be
-    split across TensorCores; 'arbitrary' axes are sequential revisits,
-    e.g. accumulation over feature chunks)."""
+    """Mosaic compiler params.  Annotates pallas grids with
+    ``dimension_semantics`` ('parallel' axes may be split across
+    TensorCores; 'arbitrary' axes are sequential revisits, e.g.
+    accumulation over feature chunks)."""
     from jax.experimental.pallas import tpu as pltpu
 
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
     if dimension_semantics is not None:
         kwargs["dimension_semantics"] = tuple(dimension_semantics)
-    return cls(**kwargs)
+    return pltpu.CompilerParams(**kwargs)
 
 
-if hasattr(jax, "shard_map"):
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-else:  # pragma: no cover - depends on installed jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=check_vma)
+def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)

@@ -40,7 +40,7 @@ Driver surface (one traced step, three dispatch granularities):
       boundary and retries with backed-off lr/exaggeration (bounded,
       then ``EmbeddingDiverged``), the full state checkpoints through
       ``repro.checkpoint`` for bit-deterministic resume, Pallas launch
-      failures demote per kernel family to the XLA refs
+      failures can (opt-in) demote per kernel family to the XLA refs
       (``repro.kernels.fallback``), and ``repro.runtime.faults`` injects
       every one of those failures deterministically in tests/CI.
 
@@ -49,8 +49,8 @@ Config flag matrix (orthogonal, all combinations tested):
                      (§H12/H13); False: legacy pre-gather wiring
                      (bit-equivalence anchor).
   ``scatter_fused``  True: symmetrisation binned in-kernel into (N, d)
-                     partials (§H14; requires gather_fused); False:
-                     edge-emitting epilogue + XLA scatters.
+                     fields (§H14; requires gather_fused); False (the
+                     default): edge-emitting epilogue + XLA scatters.
   ``merge_fused``    True: the neighbour-selection epilogue (dedup +
                      sorted top-K merge) runs inside the gather kernel
                      (§H16; requires gather_fused; the HD phase falls
@@ -75,13 +75,13 @@ Config flag matrix (orthogonal, all combinations tested):
                      bitwise) from the legacy path; within
                      ``cand_fused=True`` all backend / fused-flag
                      combinations keep their usual parity contracts.
-  ``backend``        'auto' (pallas on TPU else xla) | 'pallas' |
-                     'interpret' | 'xla'.  The scatter kernel's VMEM
-                     plan (ne_forces/ops.py: ~10MB budget, N-chunked
-                     bins, XLA ref fallback only for degenerate plans)
-                     applies on the pallas/interpret paths.
+  ``backend``        'auto' (pallas on TPU else xla; a device that fails
+                     to initialise raises) | 'pallas' | 'interpret' |
+                     'xla'.  The scatter kernel's VMEM plan
+                     (ne_forces/ops.py: N-chunked fields) applies on the
+                     pallas/interpret paths.
 
-Distribution (DESIGN.md Sec. 3/5): inside ``shard_map`` the embedding state
+Distribution: inside ``shard_map`` the embedding state
 is replicated; each device owns a contiguous row slice per phase
 (KNN phases: the ``points`` axes; force phase: points x feat axes) and the
 slices are reassembled with tiled all-gathers / a single force psum.  The HD
@@ -213,10 +213,13 @@ class FuncSNEConfig:
     # (legacy pre-gather wiring, kept for equivalence tests and A/B benches)
     gather_fused: bool = True
     # scatter-fused force epilogue (§Perf H14): symmetrisation edges are
-    # accumulated in-kernel into (N, d) partials; False keeps the
+    # accumulated in-kernel into (N, d) fields; False keeps the
     # edge-emitting kernel + XLA ``.at[].add`` scatters.  Only takes
     # effect with gather_fused (the scatter kernel is index-taking).
-    scatter_fused: bool = True
+    # Off by default: on a TPU the lane-padded fields fit VMEM only in
+    # N-chunks, and every chunk re-stages all rows and replays a serial
+    # per-edge bin loop, so at n=65536 it would be the slowest phase.
+    scatter_fused: bool = False
     # merge-fused neighbour selection (§Perf H16): dedup + sorted top-K
     # merge happen inside the gather kernel; False keeps the XLA
     # selection epilogue (dedup_candidates -> distance kernel ->

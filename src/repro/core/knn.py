@@ -153,15 +153,9 @@ def counter_candidates(salt, rows, sources, first_tables=(),
             a = counter_randint(salt, rows_c, 2 * slots, f.shape[1])
             cand = jnp.take_along_axis(f, a, axis=1)
         elif kind == "two_hop":
-            f = first_tables[src[1]]
-            s = second_tables[src[2]]
-            n2, k2 = s.shape
-            a = counter_randint(salt, rows_c, 2 * slots, f.shape[1])
-            mid = jnp.take_along_axis(f, a, axis=1)
-            mid = jnp.where(mid == SENTINEL, rows_c % n2, mid)
-            mid = jnp.clip(mid, 0, n2 - 1)
-            bb = counter_randint(salt, rows_c, 2 * slots + 1, k2)
-            cand = s.reshape(-1)[mid * k2 + bb]
+            cand = two_hop_picks(salt, rows_c, slots,
+                                 first_tables[src[1]],
+                                 second_tables[src[2]])
         elif kind == "extra":
             cand = extra[:, e0:e0 + c]
             e0 += c
@@ -172,6 +166,23 @@ def counter_candidates(salt, rows, sources, first_tables=(),
     if not parts:
         return jnp.zeros((b, 0), jnp.int32)
     return jnp.concatenate(parts, axis=1)
+
+
+def two_hop_picks(salt, rows_c, slots, first, second):
+    """``second[first[r, a], b]`` for counter draws (a, b) of ``slots``.
+
+    rows_c: (B, 1) global row ids; slots: (1, c) slot ids; first: (B, K1)
+    the rows' own lists; second: (N2, K2) global table.  SENTINEL mids
+    fall back to the row id, as ``sample_hops`` does.  The gather is flat
+    (``reshape(-1)``), so no (B, c, K2) broadcast exists in the HLO.
+    """
+    n2, k2 = second.shape
+    a = counter_randint(salt, rows_c, 2 * slots, first.shape[1])
+    mid = jnp.take_along_axis(first, a, axis=1)
+    mid = jnp.where(mid == SENTINEL, rows_c % n2, mid)
+    mid = jnp.clip(mid, 0, n2 - 1)
+    b = counter_randint(salt, rows_c, 2 * slots + 1, k2)
+    return second.reshape(-1)[mid * k2 + b]
 
 
 def counter_fill(salt, n, r):
@@ -304,3 +315,48 @@ def exact_knn(X, k: int, active=None):
         d2 = jnp.where(active[None, :], d2, jnp.inf)
     neg_top, idx = jax.lax.top_k(-d2, k)
     return idx.astype(jnp.int32), -neg_top
+
+
+@functools.partial(jax.jit, static_argnames=("k", "block"))
+def exact_knn_rows(X, rows, k: int, block: int = 4096):
+    """Exact KNN of the query rows ``X[rows]`` over all of ``X``, in blocks.
+
+    The reference at any n: columns stream in blocks of ``block`` with a
+    running top-k merge, so memory is O(len(rows) * block) and the n x n
+    matrix of :func:`exact_knn` is never built.  Self-matches are
+    excluded; distance ties keep the lower index first, as ``exact_knn``
+    does.  Products run at HIGHEST precision (a TPU rounds f32 matmul
+    operands to bf16 by default, which would reorder near neighbours).
+    Returns ((S, k) int32 ids, (S, k) f32 squared distances).
+    """
+    X = X.astype(jnp.float32)
+    n = X.shape[0]
+    rows = rows.astype(jnp.int32)
+    q = X[rows]
+    qn = jnp.sum(q * q, axis=1)[:, None]
+    n_blocks = -(-n // block)
+    Xp = jnp.pad(X, ((0, n_blocks * block - n), (0, 0)))
+    xn = jnp.sum(Xp * Xp, axis=1)
+
+    def body(best, j):
+        best_d, best_i = best
+        xb = jax.lax.dynamic_slice_in_dim(Xp, j * block, block)
+        xnb = jax.lax.dynamic_slice_in_dim(xn, j * block, block)
+        d2 = qn + xnb[None, :] - 2.0 * jnp.dot(
+            q, xb.T, precision=jax.lax.Precision.HIGHEST)
+        col = j * block + jnp.arange(block, dtype=jnp.int32)[None, :]
+        d2 = jnp.where((col == rows[:, None]) | (col >= n), jnp.inf,
+                       jnp.maximum(d2, 0.0))
+        neg_top, pos = jax.lax.top_k(
+            -jnp.concatenate([best_d, d2], axis=1), k)
+        # positions < k are the running best, the rest this block's cols
+        kept = jnp.take_along_axis(best_i, jnp.minimum(pos, k - 1), axis=1)
+        idx = jnp.where(pos < k, kept, j * block + pos - k)
+        return (-neg_top, idx), None
+
+    s = rows.shape[0]
+    init = (jnp.full((s, k), jnp.inf, jnp.float32),
+            jnp.full((s, k), -1, jnp.int32))
+    (d, idx), _ = jax.lax.scan(body, init,
+                               jnp.arange(n_blocks, dtype=jnp.int32))
+    return idx, d

@@ -11,8 +11,13 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from repro.core.knn import exact_knn
+from repro.core.knn import exact_knn, exact_knn_rows
+
+# query rows the sampled criteria score: enough for a stable AUC, and the
+# blocked reference stays O(rows * block) memory at any n
+N_SAMPLE = 2048
 
 
 def _rank_in_true(est_idx, true_idx):
@@ -66,19 +71,41 @@ def knn_set_quality(est_idx, X, kmax: int = None):
     return rnx_auc(rnx_curve(est_idx[:, :k], true_idx, X.shape[0]))
 
 
-def embedding_quality(X, Y, kmax: int = 64):
-    """AUC of R_NX comparing LD neighbourhoods to HD neighbourhoods."""
-    kmax = min(kmax, X.shape[0] - 2)
-    true_idx, _ = exact_knn(X, kmax)
-    emb_idx, _ = exact_knn(Y, kmax)
-    return rnx_auc(rnx_curve(emb_idx, true_idx, X.shape[0]))
+def sample_rows(n: int):
+    """Fixed sorted sample of N_SAMPLE query rows (all rows when fewer)."""
+    if n <= N_SAMPLE:
+        return jnp.arange(n, dtype=jnp.int32)
+    rows = np.random.default_rng(0).choice(n, N_SAMPLE, replace=False)
+    return jnp.asarray(np.sort(rows), jnp.int32)
 
 
 def embedding_rnx_curve(X, Y, kmax: int = 64):
-    kmax = min(kmax, X.shape[0] - 2)
-    true_idx, _ = exact_knn(X, kmax)
-    emb_idx, _ = exact_knn(Y, kmax)
-    return rnx_curve(emb_idx, true_idx, X.shape[0])
+    """R_NX(K) of the LD neighbourhoods against the HD ones, over a fixed
+    sample of query rows; exact KNN of both is computed in blocks, so no
+    n x n matrix is built at any n."""
+    n = X.shape[0]
+    kmax = min(kmax, n - 2)
+    rows = sample_rows(n)
+    true_idx, _ = exact_knn_rows(X, rows, kmax)
+    emb_idx, _ = exact_knn_rows(Y, rows, kmax)
+    return rnx_curve(emb_idx, true_idx, n)
+
+
+def embedding_quality(X, Y, kmax: int = 64):
+    """AUC of R_NX comparing LD neighbourhoods to HD neighbourhoods (over
+    the fixed sample of query rows of :func:`embedding_rnx_curve`)."""
+    return rnx_auc(embedding_rnx_curve(X, Y, kmax))
+
+
+def knn_recall(est_idx, X):
+    """Mean recall@K of the estimated (n, K) HD lists against exact KNN,
+    over the same fixed sample of query rows."""
+    rows = sample_rows(X.shape[0])
+    k = est_idx.shape[1]
+    true_idx, _ = exact_knn_rows(X, rows, k)
+    est = est_idx[rows]
+    hit = jnp.any(est[:, :, None] == true_idx[:, None, :], axis=-1)
+    return jnp.mean(hit.astype(jnp.float32))
 
 
 def one_nn_accuracy(Z, labels, rng, n_trials: int = 1, one_shot: bool = False):

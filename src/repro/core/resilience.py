@@ -101,7 +101,10 @@ class ResiliencePolicy:
     # extra host sync per audited chunk, so leave sparse in production
     audit_every: int = 0
     # -- graceful degradation ---------------------------------------------
-    sticky_fallback: bool = True        # Pallas failure -> XLA ref, sticky
+    # opt-in: a raising Pallas launch is answered by its XLA ref for the
+    # rest of the run.  Off by default, so a kernel that fails to lower
+    # fails the run instead of passing on the reference path.
+    sticky_fallback: bool = False
     # -- hang / straggler watchdog ----------------------------------------
     hang_timeout: float = 600.0         # seconds per *chunk* dispatch
     straggler_z: float = 4.0

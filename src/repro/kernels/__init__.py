@@ -17,14 +17,17 @@ Kernels (the compute hot-spots the paper optimises on GPU, re-tiled for TPU):
 The two NE kernels each come in two flavours: the pre-gather form takes
 already-gathered (B, C, M) / (B, K, d) operands, and the gather-fused form
 (``*_gather``) takes *indices* and DMAs only the needed rows in-kernel
-(source matrix stays in HBM/ANY; index slabs staged into SMEM by the
-pipeline).  ``ne_forces_gather`` additionally offers a scatter-fused
-output mode (``scatter_fused=True``): per-edge forces and their symmetric
-reactions are index-binned in-kernel into per-segment (N, d)
-displacement-field partials (grid partials reduced by one XLA sum; XLA
-fallback on ``jax.ops.segment_sum``), so the per-edge tensors never
-round-trip through HBM.  The gather-fused forms are the per-iteration
-default and scatter fusion the default force epilogue (funcsne §Perf
-H12/H13/H14); the pre-gather and edge-emitting forms remain for A/B
-testing and as building blocks elsewhere.
+(source matrix stays in HBM/ANY, read as lane-padded rows; index slabs
+staged into SMEM by the pipeline).  ``ne_forces_gather`` additionally
+offers a scatter-fused output mode (``scatter_fused=True``): per-edge
+forces and their symmetric reactions are index-binned in-kernel into
+per-segment (N, d) displacement fields (reference on
+``jax.ops.segment_sum``), so the per-edge tensors never round-trip
+through HBM.  The gather-fused forms are the per-iteration default
+(funcsne §Perf H12/H13); the edge-emitting force epilogue is the
+default and scatter fusion (H14) an option; the pre-gather forms remain
+for A/B testing and as building blocks elsewhere.
+
+``backend.resolve`` maps ``backend="auto"`` to 'pallas' on a TPU and
+'xla' elsewhere for every ``ops.py`` wrapper.
 """

@@ -10,17 +10,17 @@ shape, a VMEM plan that doesn't fit, a driver hiccup.  Every kernel
   * disabled (the default) it is a pure passthrough -- exceptions
     propagate exactly as before, so kernel tests keep failing loudly;
   * enabled (``funcsne.fit`` turns it on while a ``ResiliencePolicy``
-    with ``sticky_fallback=True`` is active), a raising Pallas launch
-    demotes its *family* to the XLA ref for the remainder of the process
-    and the call is answered by the reference instead.  The demotion is
+    with the opt-in ``sticky_fallback=True`` is active), a raising Pallas
+    launch demotes its *family* to the XLA ref for the remainder of the
+    process and the call is answered by the reference instead.  The demotion is
     sticky: later traces consult the registry up front, so one failure
     never re-raises per chunk.
 
-Demotions and degenerate-plan fallbacks are recorded as structured events
-(:func:`events`) -- the telemetry channel the resilience layer drains
-into its own log.  ``repro.runtime.faults.KernelLaunchFault`` injects a
-failure right before the Pallas builder runs, so the whole path is
-exercised deterministically in CI.
+Demotions are recorded as structured events (:func:`events`) -- the
+telemetry channel the resilience layer drains into its own log.
+``repro.runtime.faults.KernelLaunchFault`` injects a failure right
+before the Pallas kernel is built, so the whole path is exercised
+deterministically in CI.
 """
 from __future__ import annotations
 
@@ -41,7 +41,6 @@ _LOCK = threading.Lock()
 _ENABLED = False
 _DEMOTED: Dict[str, str] = {}       # family -> reason
 _EVENTS: List[dict] = []
-_NOTED: set = set()                 # dedup key of already-logged notes
 
 
 def is_enabled() -> bool:
@@ -85,18 +84,6 @@ def demotions() -> Dict[str, str]:
         return dict(_DEMOTED)
 
 
-def note(family: str, reason: str) -> None:
-    """Log a non-sticky degradation event (e.g. a degenerate VMEM plan
-    answered by the XLA ref for one shape) exactly once per reason."""
-    key = (family, reason)
-    with _LOCK:
-        if key in _NOTED:
-            return
-        _NOTED.add(key)
-        _EVENTS.append({"kind": "kernel_fallback", "family": family,
-                        "reason": reason})
-
-
 def events(since: int = 0) -> List[dict]:
     with _LOCK:
         return list(_EVENTS[since:])
@@ -114,7 +101,6 @@ def reset() -> None:
         _ENABLED = False
         _DEMOTED.clear()
         _EVENTS.clear()
-        _NOTED.clear()
 
 
 def guarded(family: str, run_pallas: Callable[[], object],

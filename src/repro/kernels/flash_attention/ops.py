@@ -1,25 +1,15 @@
 """Public jit'd wrapper for causal GQA flash attention."""
 from __future__ import annotations
 
-import jax
-
+from repro.kernels.backend import resolve
 from repro.kernels.flash_attention.kernel import flash_attention_pallas
 from repro.kernels.flash_attention.ref import flash_attention_ref
-
-
-def _default_backend() -> str:
-    try:
-        platform = jax.devices()[0].platform
-    except Exception:  # pragma: no cover
-        platform = "cpu"
-    return "pallas" if platform == "tpu" else "xla"
 
 
 def flash_attention(q, k, v, *, scale=None, softcap: float = 0.0,
                     window: int = 0, backend: str = "auto"):
     """Causal GQA attention; see ref.py for exact semantics."""
-    if backend == "auto":
-        backend = _default_backend()
+    backend = resolve(backend)
     if backend == "pallas":
         return flash_attention_pallas(q, k, v, scale=scale, softcap=softcap,
                                       window=window)

@@ -55,8 +55,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.compat import tpu_compiler_params
-from repro.kernels.pairwise_sqdist.kernel import (_round_up, plan_row_gather,
-                                                  score_gather_block)
+from repro.kernels.pairwise_sqdist.kernel import (LANES, _round_up, pad_lanes,
+                                                  plan_row_gather,
+                                                  score_gather_block,
+                                                  smem_ids)
 
 _SENTINEL = jnp.iinfo(jnp.int32).max
 
@@ -125,6 +127,23 @@ def merge_select(qid_col, cur_idx, cur_d, cand, cand_d, ext_valid):
     return new_idx.astype(i32), new_d, improved
 
 
+def for_row_slices(block_b: int, fn, rows: int = 8):
+    """Run ``fn(pl.ds(base, rows))`` over the row slices of a block.
+
+    The merge's rank compares build (rows, K+C, K+C) intermediates; over a
+    whole 128-row block they would outgrow VMEM, so the epilogue walks
+    the block one f32 sublane tile at a time.
+    """
+    if block_b % rows:
+        rows = block_b
+
+    def body(p, _):
+        fn(pl.ds(pl.multiple_of(p * rows, rows), rows))
+        return _
+
+    jax.lax.fori_loop(0, block_b // rows, body, None)
+
+
 def _knn_merge_kernel(qid_ref, gat_ref, cur_idx_ref, cand_ref, qid_v_ref,
                       curw_ref, candval_ref, x_ref, idx_out, d_out, imp_out,
                       acc, q_scr, c_scr, q_sem, c_sem, *, m_size: int,
@@ -132,7 +151,7 @@ def _knn_merge_kernel(qid_ref, gat_ref, cur_idx_ref, cand_ref, qid_v_ref,
                       k_cur: int, rescore: bool):
     """One (block_b, block_m) tile: gather+score rows, merge on last chunk.
 
-    qid_ref: (block_b,) SMEM        query row ids (DMA addresses)
+    qid_ref: (1, block_b) SMEM      query row ids (DMA addresses)
     gat_ref: (block_b, G) SMEM      clipped gather ids (G = C, or K+C when
                                     ``rescore``: [cur, cand] order)
     cur_idx_ref: (block_b, K) VMEM  unclipped resident ids (dedup compares)
@@ -140,7 +159,7 @@ def _knn_merge_kernel(qid_ref, gat_ref, cur_idx_ref, cand_ref, qid_v_ref,
     qid_v_ref: (block_b, 1) VMEM    query ids (self-dedup compares)
     curw_ref: (block_b, K) VMEM     f32 cur_d (HD) / i32 cur_valid (rescore)
     candval_ref: (block_b, C) VMEM  i32 external candidate validity
-    x_ref: (N, M) ANY               source matrix (stays in HBM)
+    x_ref: (N, M) ANY               lane-padded source (stays in HBM)
     idx_out/d_out: (block_b, K)     merged neighbour list
     imp_out: (block_b, 1) i32       per-row improved flag
     acc: (block_b, G) VMEM          squared-distance accumulator scratch
@@ -153,18 +172,22 @@ def _knn_merge_kernel(qid_ref, gat_ref, cur_idx_ref, cand_ref, qid_v_ref,
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _merge():
-        if rescore:
-            cur_d = jnp.where(curw_ref[...] != 0, acc[:, :k_cur], jnp.inf)
-            cand_d = acc[:, k_cur:]
-        else:
-            cur_d = curw_ref[...]
-            cand_d = acc[...]
-        new_idx, new_d, improved = merge_select(
-            qid_v_ref[...], cur_idx_ref[...], cur_d, cand_ref[...], cand_d,
-            candval_ref[...] != 0)
-        idx_out[...] = new_idx
-        d_out[...] = new_d
-        imp_out[...] = improved.astype(jnp.int32)[:, None]
+        def rows(sl):
+            if rescore:
+                cur_d = jnp.where(curw_ref[sl] != 0, acc[sl, :k_cur],
+                                  jnp.inf)
+                cand_d = acc[sl, k_cur:]
+            else:
+                cur_d = curw_ref[sl]
+                cand_d = acc[sl]
+            new_idx, new_d, improved = merge_select(
+                qid_v_ref[sl], cur_idx_ref[sl], cur_d, cand_ref[sl], cand_d,
+                candval_ref[sl] != 0)
+            idx_out[sl] = new_idx
+            d_out[sl] = new_d
+            imp_out[sl] = improved.astype(jnp.int32)[:, None]
+
+        for_row_slices(acc.shape[0], rows)
 
 
 @functools.partial(
@@ -201,6 +224,7 @@ def knn_merge_pallas(
     Returns:
       (new_idx (B, K) int32, new_d (B, K) f32, improved (B,) bool).
     """
+    x = pad_lanes(x)
     N, M = x.shape
     B, K = cur_idx.shape
     Bc, C = cand.shape
@@ -233,6 +257,7 @@ def knn_merge_pallas(
         cur_w = jnp.pad(cur_w, ((0, pad), (0, 0)))
         cand_valid = jnp.pad(cand_valid, ((0, pad), (0, 0)))
 
+    qid_s, qid_spec = smem_ids(qid, block_b)
     grid = (Bp // block_b, n_mchunks)
     outs = pl.pallas_call(
         functools.partial(_knn_merge_kernel, m_size=M, block_m=block_m,
@@ -240,8 +265,7 @@ def knn_merge_pallas(
                           rescore=rescore),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_b,), lambda i, j: (i,),
-                         memory_space=pltpu.SMEM),
+            qid_spec,
             pl.BlockSpec((block_b, G), lambda i, j: (i, 0),
                          memory_space=pltpu.SMEM),
             pl.BlockSpec((block_b, K), lambda i, j: (i, 0)),
@@ -249,7 +273,7 @@ def knn_merge_pallas(
             pl.BlockSpec((block_b, 1), lambda i, j: (i, 0)),
             pl.BlockSpec((block_b, K), lambda i, j: (i, 0)),
             pl.BlockSpec((block_b, C), lambda i, j: (i, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
             pl.BlockSpec((block_b, K), lambda i, j: (i, 0)),
@@ -271,7 +295,7 @@ def knn_merge_pallas(
         compiler_params=tpu_compiler_params(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(qid, gat, cur_idx, cand, qid[:, None], cur_w, cand_valid, x)
+    )(qid_s, gat, cur_idx, cand, qid[:, None], cur_w, cand_valid, x)
     new_idx, new_d, imp = outs
     return new_idx[:B], new_d[:B], imp[:B, 0] != 0
 
@@ -295,18 +319,18 @@ def knn_merge_pallas(
 #     sampler (``knn_lib.counter_candidates``) is bit-exact against both;
 #   * one-hop picks read the row's resident first-table slab
 #     (SMEM for addresses, VMEM one-hot for the vector value);
-#   * two-hop picks chain through the second-table channel: the kernel
-#     computes ``mid = first[r, a]`` from SMEM, DMAs the single element
-#     ``second[mid, b]`` from HBM into paired SMEM/VMEM chain staging
-#     (``plan_row_gather(chain_slots=...)``), and only then issues the
-#     ``X[cand]`` row DMA through the shared double-buffered pipeline;
 #   * uniform probes are pure hash arithmetic;
-#   * precomputed "extra" slots (e.g. the cached reverse-edge table) ride
-#     in as classic SMEM/VMEM operand slabs.
+#   * two-hop picks ``second[first[r, a], b]`` are resolved by the
+#     wrapper with one flat (B, c) element gather (``knn_lib.two_hop_picks``,
+#     no (B, c, K2) broadcast) and ride in like the precomputed "extra"
+#     slots (e.g. the cached reverse-edge table): Mosaic DMAs only
+#     lane-aligned rows, so a 4-byte chained element DMA cannot be used.
 #
-# Per-candidate ``active``-row flags are fetched by element DMAs issued at
-# generation time and awaited just before the merge, so the whole
-# activity gather overlaps the scoring sweep.
+# Per-candidate ``active``-row flags come from a lane-dense (N/128, 128)
+# packing of the mask: the kernel DMAs the 128-flag row holding each
+# candidate at generation time, awaits it just before the merge, and
+# picks the candidate's lane with a one-hot select, so the whole activity
+# gather overlaps the scoring sweep.
 
 
 def _slot_plan(sources):
@@ -322,7 +346,6 @@ def _slot_plan(sources):
             if kind == "one_hop":
                 ent["f"] = src[1]
             elif kind == "two_hop":
-                ent["f"], ent["s"] = src[1], src[2]
                 ent["t"] = n_chain
                 n_chain += 1
             elif kind == "extra":
@@ -334,41 +357,38 @@ def _slot_plan(sources):
     return slots, n_chain, n_extra
 
 
-def _make_cand_kernel(*, sources, n_first, first_widths, second_shapes,
+def _make_cand_kernel(*, sources, n_first, first_widths, have_chain,
                       have_extra, have_active, rescore, k_cur, n_rows,
                       m_size, block_m, sub_b, persistent_q):
     """Build the kernel body for one static candidate-fused config."""
     from repro.core import knn as knn_lib   # deferred: core imports kernels
 
-    slots, n_chain, _ = _slot_plan(sources)
-    c_total = len(slots)
+    slots, _, _ = _slot_plan(sources)
     koff = k_cur if rescore else 0
-    chains = [e for e in slots if e["kind"] == "two_hop"]
 
     def kernel(*refs):
         it = iter(refs)
-        qid_ref = next(it)                          # (block_b,) SMEM
+        qid_ref = next(it)                          # (1, block_b) SMEM
         salt_ref = next(it)                         # (1, 1) SMEM
         first_s = [next(it) for _ in range(n_first)]
+        chain_s = next(it) if have_chain else None
         extra_s = next(it) if have_extra else None
         curs_ref = next(it) if rescore else None    # clipped cur ids, SMEM
         cur_idx_ref = next(it)                      # (block_b, K) VMEM
         qid_v_ref = next(it)                        # (block_b, 1) VMEM
         curw_ref = next(it)                         # (block_b, K) VMEM
         first_v = [next(it) for _ in range(n_first)]
+        chain_v = next(it) if have_chain else None
         extra_v = next(it) if have_extra else None
-        second = [next(it) for _ in range(len(second_shapes))]
-        act_ref = next(it) if have_active else None  # (N, 1) i32 ANY
+        act_ref = next(it) if have_active else None  # (N/128, 128) i32 ANY
         x_ref = next(it)                            # (N, M) ANY
         idx_out, d_out, imp_out = next(it), next(it), next(it)
         acc, q_scr, c_scr, q_sem, c_sem = (next(it), next(it), next(it),
                                            next(it), next(it))
         gat_smem = next(it)                         # (block_b, G) SMEM
         cand_vmem = next(it)                        # (block_b, C) VMEM
-        if n_chain:
-            chain_smem, chain_vmem, chain_sem = next(it), next(it), next(it)
         if have_active:
-            actv, act_sem = next(it), next(it)
+            act_rows, act_sem = next(it), next(it)  # (block_b, C, 128)
 
         j = pl.program_id(1)
         block_b = acc.shape[0]
@@ -379,43 +399,15 @@ def _make_cand_kernel(*, sources, n_first, first_widths, second_shapes,
             h = knn_lib.hash3(salt, row, jnp.int32(draw))
             return (h & knn_lib._POS_MASK) % bound
 
-        def chain_ends(r, ent):
-            """(second table ref, mid, b) of one two-hop chain element."""
-            row = qid_ref[r]
-            sec = second[ent["s"]]
-            n2, k2 = second_shapes[ent["s"]]
-            a = sdraw(row, 2 * ent["g"], first_widths[ent["f"]])
-            mid = first_s[ent["f"]][r, a]
-            mid = jnp.where(mid == _SENTINEL, row % n2, mid)
-            mid = jnp.clip(mid, 0, n2 - 1)
-            return sec, mid, sdraw(row, 2 * ent["g"] + 1, k2)
-
-        def chain_copies(op):
-            def per_row(r, _):
-                for ent in chains:            # static unroll (C is small)
-                    sec, mid, b = chain_ends(r, ent)
-                    op(pltpu.make_async_copy(
-                        sec.at[mid, b], chain_smem.at[r, ent["t"]],
-                        chain_sem.at[0]))
-                    op(pltpu.make_async_copy(
-                        sec.at[mid, b], chain_vmem.at[r, ent["t"]],
-                        chain_sem.at[1]))
-                return _
-            jax.lax.fori_loop(0, block_b, per_row, None)
-
         def act_copy(r, g):
-            return pltpu.make_async_copy(
-                act_ref.at[gat_smem[r, koff + g], 0], actv.at[r, g],
-                act_sem)
+            row = jax.lax.shift_right_logical(gat_smem[r, koff + g], 7)
+            return pltpu.make_async_copy(act_ref.at[row], act_rows.at[r, g],
+                                         act_sem)
 
         @pl.when(j == 0)
         def _generate():
-            if n_chain:
-                chain_copies(lambda cp: cp.start())
-                chain_copies(lambda cp: cp.wait())
-
             def fill_row(r, _):
-                row = qid_ref[r]
+                row = qid_ref[0, r]
                 if rescore:
                     def cp_cur(k, _):
                         gat_smem[r, k] = curs_ref[r, k]
@@ -429,7 +421,7 @@ def _make_cand_kernel(*, sources, n_first, first_widths, second_shapes,
                         a = sdraw(row, 2 * g, first_widths[ent["f"]])
                         v = first_s[ent["f"]][r, a]
                     elif kind == "two_hop":
-                        v = chain_smem[r, ent["t"]]
+                        v = chain_s[r, ent["t"]]
                     else:                     # extra
                         v = extra_s[r, ent["e"]]
                     gat_smem[r, koff + g] = jnp.clip(v, 0, n_rows - 1)
@@ -457,9 +449,8 @@ def _make_cand_kernel(*, sources, n_first, first_widths, second_shapes,
                     blk = jnp.sum(jnp.where(a[:, :, None] == kk,
                                             tab[:, None, :], 0), axis=2)
                 elif kind == "two_hop":
-                    t0 = next(e["t"] for e in slots
-                              if e["g"] == g0)
-                    blk = chain_vmem[:, t0:t0 + c]
+                    t0 = next(e["t"] for e in slots if e["g"] == g0)
+                    blk = chain_v[:, t0:t0 + c]
                 else:                                     # extra
                     e0 = next(e["e"] for e in slots if e["g"] == g0)
                     blk = extra_v[:, e0:e0 + c]
@@ -478,27 +469,54 @@ def _make_cand_kernel(*, sources, n_first, first_widths, second_shapes,
                         act_copy(r, ent["g"]).wait()
                     return _
                 jax.lax.fori_loop(0, block_b, drain, None)
-                ext_valid = actv[...] != 0
-            else:
-                # all-true, computed (a literal bool array would be a
-                # captured kernel constant)
-                cv = cand_vmem[...]
-                ext_valid = cv == cv
-            if rescore:
-                cur_d = jnp.where(curw_ref[...] != 0, acc[:, :k_cur],
-                                  jnp.inf)
-                cand_d = acc[:, k_cur:]
-            else:
-                cur_d = curw_ref[...]
-                cand_d = acc[...]
-            new_idx, new_d, improved = merge_select(
-                qid_v_ref[...], cur_idx_ref[...], cur_d, cand_vmem[...],
-                cand_d, ext_valid)
-            idx_out[...] = new_idx
-            d_out[...] = new_d
-            imp_out[...] = improved.astype(jnp.int32)[:, None]
+
+            def rows(sl):
+                cand = cand_vmem[sl]
+                if have_active:
+                    # the candidate's flag is lane (id mod 128) of its row
+                    lane = jnp.clip(cand, 0, n_rows - 1) & 127
+                    ll = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 128), 2)
+                    ext_valid = jnp.sum(jnp.where(lane[:, :, None] == ll,
+                                                  act_rows[sl], 0),
+                                        axis=2) != 0
+                else:
+                    # all-true, computed (a literal bool array would be a
+                    # captured kernel constant)
+                    ext_valid = cand == cand
+                if rescore:
+                    cur_d = jnp.where(curw_ref[sl] != 0, acc[sl, :k_cur],
+                                      jnp.inf)
+                    cand_d = acc[sl, k_cur:]
+                else:
+                    cur_d = curw_ref[sl]
+                    cand_d = acc[sl]
+                new_idx, new_d, improved = merge_select(
+                    qid_v_ref[sl], cur_idx_ref[sl], cur_d, cand, cand_d,
+                    ext_valid)
+                idx_out[sl] = new_idx
+                d_out[sl] = new_d
+                imp_out[sl] = improved.astype(jnp.int32)[:, None]
+
+            for_row_slices(block_b, rows)
 
     return kernel
+
+
+def _chain_picks(salt, qid, sources, first_tables, second_tables):
+    """(B, n_chain) values of the two-hop slots, in slot order."""
+    from repro.core import knn as knn_lib   # deferred: core imports kernels
+
+    rows_c = qid[:, None]
+    parts, g0 = [], 0
+    for src in sources:
+        c = src[-1]
+        if src[0] == "two_hop":
+            slots = g0 + jnp.arange(c, dtype=jnp.int32)[None, :]
+            parts.append(knn_lib.two_hop_picks(
+                salt, rows_c, slots, first_tables[src[1]],
+                second_tables[src[2]]))
+        g0 += c
+    return jnp.concatenate(parts, axis=1).astype(jnp.int32)
 
 
 @functools.partial(
@@ -530,11 +548,12 @@ def knn_merge_cand_pallas(
     operand is replaced by its *generator*: ``salt`` (int32 counter-RNG
     salt), ``sources`` (static layout, see ``knn_lib.counter_candidates``),
     ``first_tables`` (tuple of (B, Kf) resident slabs), ``second_tables``
-    (tuple of (N2, K2) HBM tables for the chained two-hop picks) and
-    optional ``extra`` precomputed slots.  ``active`` is the global (N,)
-    bool membership mask (None == all rows active): per-candidate flags
-    are DMA'd in-kernel, matching ``active[clip(cand)]`` on the ref.
+    (tuple of (N2, K2) tables for the two-hop picks) and optional
+    ``extra`` precomputed slots.  ``active`` is the global (N,) bool
+    membership mask (None == all rows active): per-candidate flags are
+    DMA'd in-kernel, matching ``active[clip(cand)]`` on the ref.
     """
+    x = pad_lanes(x)
     N, M = x.shape
     B, K = cur_idx.shape
     # zero-width sources are legal in the grammar but contribute no
@@ -545,6 +564,7 @@ def knn_merge_cand_pallas(
     slots, n_chain, n_extra = _slot_plan(sources)
     C = len(slots)
     assert C > 0, "cand-fused merge needs at least one candidate source"
+    have_chain = n_chain > 0
     have_extra = n_extra > 0
     if have_extra:
         assert extra is not None and extra.shape == (B, n_extra), \
@@ -560,15 +580,19 @@ def knn_merge_cand_pallas(
     cur_w = cur_w.astype(jnp.int32 if rescore else jnp.float32)
     if rescore:
         curs = jnp.clip(cur_idx, 0, N - 1)
+    if have_chain:
+        chain = _chain_picks(salt[0, 0], qid, sources, first_tables,
+                             second_tables)
     if have_extra:
         extra = extra.astype(jnp.int32)
     if have_active:
-        act = active.astype(jnp.int32)[:, None]
+        act = pad_lanes(active.astype(jnp.int32)[None, :]).reshape(-1, LANES)
 
     block_b, block_m, sub_b, persistent_q, n_mchunks, q_scr_shape = \
         plan_row_gather(B, M, G, x.dtype.itemsize, block_b=block_b,
                         block_m=block_m, sub_b=sub_b,
-                        persistent_q=persistent_q, chain_slots=n_chain)
+                        persistent_q=persistent_q,
+                        row_bytes=C * LANES * 4 if have_active else 0)
     Bp = _round_up(B, block_b)
     if Bp != B:
         pad = Bp - B
@@ -579,6 +603,8 @@ def knn_merge_cand_pallas(
                              for f in first_tables)
         if rescore:
             curs = jnp.pad(curs, ((0, pad), (0, 0)))
+        if have_chain:
+            chain = jnp.pad(chain, ((0, pad), (0, 0)))
         if have_extra:
             extra = jnp.pad(extra, ((0, pad), (0, 0)))
 
@@ -586,37 +612,32 @@ def knn_merge_cand_pallas(
         kw = {} if space is None else {"memory_space": space}
         return pl.BlockSpec((block_b, width), lambda i, j: (i, 0), **kw)
 
-    operands = [qid, salt]
+    qid_s, qid_spec = smem_ids(qid, block_b)
+    operands = [qid_s, salt]
     in_specs = [
-        pl.BlockSpec((block_b,), lambda i, j: (i,),
-                     memory_space=pltpu.SMEM),
+        qid_spec,
         pl.BlockSpec((1, 1), lambda i, j: (0, 0), memory_space=pltpu.SMEM),
     ]
-    for f in first_tables:
-        operands.append(f)
-        in_specs.append(blk(f.shape[1], pltpu.SMEM))
-    if have_extra:
-        operands.append(extra)
-        in_specs.append(blk(n_extra, pltpu.SMEM))
+    # SMEM slabs (scalar reads -> DMA addresses), then their VMEM twins
+    # (vector reads -> the merge's dedup operands)
+    slabs = list(first_tables) + ([chain] if have_chain else []) \
+        + ([extra] if have_extra else [])
+    for t in slabs:
+        operands.append(t)
+        in_specs.append(blk(t.shape[1], pltpu.SMEM))
     if rescore:
         operands.append(curs)
         in_specs.append(blk(K, pltpu.SMEM))
     operands += [cur_idx, qid[:, None], cur_w]
     in_specs += [blk(K), blk(1), blk(K)]
-    for f in first_tables:
-        operands.append(f)
-        in_specs.append(blk(f.shape[1]))
-    if have_extra:
-        operands.append(extra)
-        in_specs.append(blk(n_extra))
-    for s in second_tables:
-        operands.append(s)
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.ANY))
+    for t in slabs:
+        operands.append(t)
+        in_specs.append(blk(t.shape[1]))
     if have_active:
         operands.append(act)
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.ANY))
+        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
     operands.append(x)
-    in_specs.append(pl.BlockSpec(memory_space=pltpu.ANY))
+    in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
 
     scratch = [
         pltpu.VMEM((block_b, G), jnp.float32),
@@ -627,26 +648,22 @@ def knn_merge_cand_pallas(
         pltpu.SMEM((block_b, G), jnp.int32),
         pltpu.VMEM((block_b, C), jnp.int32),
     ]
-    if n_chain:
-        scratch += [pltpu.SMEM((block_b, n_chain), jnp.int32),
-                    pltpu.VMEM((block_b, n_chain), jnp.int32),
-                    pltpu.SemaphoreType.DMA((2,))]
     if have_active:
-        scratch += [pltpu.VMEM((block_b, C), jnp.int32),
+        scratch += [pltpu.VMEM((block_b, C, LANES), jnp.int32),
                     pltpu.SemaphoreType.DMA(())]
 
     kernel = _make_cand_kernel(
         sources=sources, n_first=len(first_tables),
         first_widths=tuple(f.shape[1] for f in first_tables),
-        second_shapes=tuple(s.shape for s in second_tables),
-        have_extra=have_extra, have_active=have_active, rescore=rescore,
-        k_cur=K, n_rows=N, m_size=M, block_m=block_m, sub_b=sub_b,
-        persistent_q=persistent_q)
+        have_chain=have_chain, have_extra=have_extra,
+        have_active=have_active, rescore=rescore, k_cur=K, n_rows=N,
+        m_size=M, block_m=block_m, sub_b=sub_b, persistent_q=persistent_q)
 
     grid = (Bp // block_b, n_mchunks)
     outs = pl.pallas_call(
         kernel,
         grid=grid,
+        name="knn_merge_cand",
         in_specs=in_specs,
         out_specs=[blk(K), blk(K), blk(1)],
         out_shape=[

@@ -10,21 +10,13 @@ Backend selection matches the other kernel packages:
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from repro.kernels import fallback
+from repro.kernels.backend import resolve
 from repro.kernels.knn_merge.kernel import (knn_merge_cand_pallas,
                                             knn_merge_pallas)
 from repro.kernels.knn_merge.ref import knn_merge_cand_ref, knn_merge_ref
-
-
-def _default_backend() -> str:
-    try:
-        platform = jax.devices()[0].platform
-    except Exception:  # pragma: no cover - device init failure
-        platform = "cpu"
-    return "pallas" if platform == "tpu" else "xla"
 
 
 def knn_merge(x, qid, cur_idx, cur_d, cand=None, *, cand_active=None,
@@ -77,8 +69,7 @@ def knn_merge(x, qid, cur_idx, cur_d, cand=None, *, cand_active=None,
         assert cur_valid is not None, "rescore mode requires cur_valid"
     else:
         assert cur_valid is None, "cur_valid is a rescore-mode option"
-    if backend == "auto":
-        backend = _default_backend()
+    backend = resolve(backend)
 
     if sources is not None:
         assert salt is not None, "candidate-fused mode requires a salt"

@@ -31,6 +31,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.compat import tpu_compiler_params
+from repro.kernels.pairwise_sqdist.kernel import pad_lanes, smem_ids
 
 
 def _edge_wsum(delta, coef, alpha, mode: str):
@@ -124,11 +125,14 @@ def _round_up(x: int, mult: int) -> int:
 # Segment boundaries are static config, so each segment's closed-form tail
 # power is compiled straight-line -- no per-edge mode mask is evaluated.
 #
-# Index slabs are staged into SMEM by the pipeline (O(block_b * K), never
-# O(B)).  The b loop is double-buffered: rows are processed in ``sub_b``
-# sub-blocks through 2-slot VMEM staging with sub-block p+1's row DMAs
-# issued before sub-block p is computed, so the row-gather latency hides
-# behind the tail-power math instead of preceding it.
+# Mosaic DMAs only lane-aligned rows, so the embedding is read lane-padded
+# (``pad_lanes``: d -> 128, zero columns give zero deltas) and the math
+# runs on padded rows; outputs keep their true width d.  Index slabs are
+# staged into SMEM by the pipeline (O(block_b * K), never O(B)).  The b
+# loop is double-buffered: rows are processed in ``sub_b`` sub-blocks
+# through 2-slot VMEM staging with sub-block p+1's row DMAs started before
+# sub-block p is computed, so the row-gather latency hides behind the
+# tail-power math instead of preceding it.
 
 
 def _dma_query_and_neighbour_rows(x_ref, qid_ref, nbr_ref, q_scr, n_scr, sem):
@@ -136,13 +140,13 @@ def _dma_query_and_neighbour_rows(x_ref, qid_ref, nbr_ref, q_scr, n_scr, sem):
 
     Issued back-to-back on one semaphore and drained in issue order
     (distinct destination slots -> no WAR hazard).  Used by the
-    scatter-fused kernel, whose whole block stays resident across its
-    N-chunk sweep.
+    scatter-fused kernel, which stages a whole block at once.
     """
     block_b, K, _ = n_scr.shape
 
     def q_dma(r):
-        return pltpu.make_async_copy(x_ref.at[qid_ref[r]], q_scr.at[r], sem)
+        return pltpu.make_async_copy(x_ref.at[qid_ref[0, r]], q_scr.at[r],
+                                     sem)
 
     def n_dma(r, k):
         return pltpu.make_async_copy(x_ref.at[nbr_ref[r, k]], n_scr.at[r, k],
@@ -166,11 +170,11 @@ def _dma_query_and_neighbour_rows(x_ref, qid_ref, nbr_ref, q_scr, n_scr, sem):
 
 def _ne_forces_gather_kernel(qid_ref, nbr_ref, alpha_ref, coef_ref, x_ref,
                              *refs, segments: tuple, emit_edges: tuple,
-                             sub_b: int):
-    """qid (bb,) SMEM; nbr (bb, K) SMEM; alpha (1,1) SMEM; coef (bb, K) VMEM;
-    x (N, d) ANY -> per segment s: agg (bb, d), edge (bb, K_s, d) for
-    segments with emit_edges[s], wsum (bb, 1); then scratch
-    (q_scr (2, sub_b, d), n_scr (2, sub_b, K, d), sem (2,))."""
+                             sub_b: int, d: int):
+    """qid (1, bb) SMEM; nbr (bb, K) SMEM; alpha (1,1) SMEM; coef (bb, K)
+    VMEM; x (N, dp) ANY lane-padded -> per segment s: agg (bb, d), edge
+    (bb, K_s, d) for segments with emit_edges[s], wsum (bb, 1); then
+    scratch (q_scr (2, sub_b, dp), n_scr (2, sub_b, K, dp), sem (2,))."""
     S = len(segments)
     E = sum(emit_edges)
     agg_refs = refs[:S]
@@ -187,7 +191,7 @@ def _ne_forces_gather_kernel(qid_ref, nbr_ref, alpha_ref, coef_ref, x_ref,
 
         def row(lr, _):
             r = p * sub_b + lr
-            op(pltpu.make_async_copy(x_ref.at[qid_ref[r]],
+            op(pltpu.make_async_copy(x_ref.at[qid_ref[0, r]],
                                      q_scr.at[slot, lr], sem.at[slot]))
             jax.lax.fori_loop(
                 0, K, lambda k, x: (op(pltpu.make_async_copy(
@@ -209,19 +213,19 @@ def _ne_forces_gather_kernel(qid_ref, nbr_ref, alpha_ref, coef_ref, x_ref,
         sub_copies(p, lambda cp: cp.wait())
 
         base = p * sub_b
-        y = q_scr[slot].astype(jnp.float32)         # (sub_b, d)
-        nbr = n_scr[slot].astype(jnp.float32)       # (sub_b, K, d)
+        y = q_scr[slot].astype(jnp.float32)         # (sub_b, dp)
+        nbr = n_scr[slot].astype(jnp.float32)       # (sub_b, K, dp)
         coef = coef_ref[pl.ds(base, sub_b)].astype(jnp.float32)
 
         k0, e_i = 0, 0
         for s, (mode, size) in enumerate(segments):
             sl = slice(k0, k0 + size)
-            delta = nbr[:, sl] - y[:, None, :]      # (sub_b, size, d)
+            delta = nbr[:, sl] - y[:, None, :]      # (sub_b, size, dp)
             edge, wsum = _edge_wsum(delta, coef[:, sl], alpha, mode)
             if emit_edges[s]:
-                edge_refs[e_i][pl.ds(base, sub_b)] = edge
+                edge_refs[e_i][pl.ds(base, sub_b)] = edge[..., :d]
                 e_i += 1
-            agg_refs[s][pl.ds(base, sub_b)] = jnp.sum(edge, axis=1)
+            agg_refs[s][pl.ds(base, sub_b)] = jnp.sum(edge, axis=1)[:, :d]
             wsum_refs[s][pl.ds(base, sub_b)] = wsum[:, None]
             k0 += size
         return _
@@ -270,6 +274,8 @@ def ne_forces_gather_pallas(x, qid, nbr_idx, coef, alpha, *,
       wsums: tuple of (B,) w partial sums (Z-hat estimator terms).
     """
     N, d = x.shape
+    x = pad_lanes(x)
+    dp = x.shape[1]
     B, K = nbr_idx.shape
     S = len(segments)
     if emit_edges is None:
@@ -287,7 +293,7 @@ def ne_forces_gather_pallas(x, qid, nbr_idx, coef, alpha, *,
     if sub_b is None:
         sub_b = _pick_sub_b(block_b)
     assert block_b % sub_b == 0, (block_b, sub_b)
-    while block_b > 8 and 2 * (K + 1) * min(sub_b, block_b) * d \
+    while block_b > 8 and 2 * (K + 1) * min(sub_b, block_b) * dp \
             * x.dtype.itemsize > 8 * 2 ** 20:
         block_b //= 2
         # a halved block_b may no longer be a multiple of sub_b: every row
@@ -300,23 +306,23 @@ def ne_forces_gather_pallas(x, qid, nbr_idx, coef, alpha, *,
         coef = jnp.pad(coef, ((0, Bp - B), (0, 0)))
     alpha_arr = jnp.asarray(alpha, jnp.float32).reshape(1, 1)
 
+    qid, qid_spec = smem_ids(qid, block_b)
     grid = (Bp // block_b,)
     emitted_sizes = [size for (_, size), em in zip(segments, emit_edges)
                      if em]
     E = len(emitted_sizes)
     outs = pl.pallas_call(
         functools.partial(_ne_forces_gather_kernel, segments=segments,
-                          emit_edges=emit_edges, sub_b=sub_b),
+                          emit_edges=emit_edges, sub_b=sub_b, d=d),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_b,), lambda i: (i,),
-                         memory_space=pltpu.SMEM),
+            qid_spec,
             pl.BlockSpec((block_b, K), lambda i: (i, 0),
                          memory_space=pltpu.SMEM),
             pl.BlockSpec((1, 1), lambda i: (0, 0),
                          memory_space=pltpu.SMEM),
             pl.BlockSpec((block_b, K), lambda i: (i, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=(
             [pl.BlockSpec((block_b, d), lambda i: (i, 0))] * S
@@ -331,8 +337,8 @@ def ne_forces_gather_pallas(x, qid, nbr_idx, coef, alpha, *,
             + [jax.ShapeDtypeStruct((Bp, 1), jnp.float32)] * S
         ),
         scratch_shapes=[
-            pltpu.VMEM((2, sub_b, d), x.dtype),
-            pltpu.VMEM((2, sub_b, K, d), x.dtype),
+            pltpu.VMEM((2, sub_b, dp), x.dtype),
+            pltpu.VMEM((2, sub_b, K, dp), x.dtype),
             pltpu.SemaphoreType.DMA((2,)),
         ],
         # one independent row block per grid step: Mosaic may split the
@@ -356,77 +362,76 @@ def ne_forces_gather_pallas(x, qid, nbr_idx, coef, alpha, *,
 # caller can symmetrise them (buf.at[nbr].add(-edge)) -- two (B, K, d)
 # HBM round-trips per step that exist only to feed an XLA scatter.  This
 # variant folds the symmetrisation into the kernel: each edge's force is
-# accumulated straight into a per-segment (N, d) displacement-field
-# partial (+edge at the query row, -edge at the neighbour row for
-# symmetrised segments), binned by index with the VMEM accumulate
-# pattern.  Each grid block writes its own (1, N, d) partial slab; the
-# partials are reduced across the grid with one cheap XLA sum, so the
-# only HBM traffic the epilogue pays is G * N * d per segment instead of
-# write+scatter-read of B * K_s * d edges.
+# accumulated straight into a per-segment (N, d) displacement field
+# (+edge at the query row, -edge at the neighbour row for symmetrised
+# segments), binned by index with serialised VMEM read-modify-writes, so
+# no (B, K_s, d) edge tensor is ever written to HBM.
 #
 # Segment scale factors (attraction/repulsion/negative-sampling weights)
 # stay *outside*: the repulsion scale depends on the Z estimator, which
 # is computed from this very launch's wsums, so the kernel returns raw
 # per-segment fields and the caller combines them with traced scalars.
 #
-# VMEM note: only the current *N-chunk* of each per-segment partial is
-# resident during a grid step -- a second grid axis sweeps the target
-# rows in ``chunk_n`` slabs of (1, chunk_n, d), so the resident footprint
-# is S * chunk_n * 512B at d<=128 regardless of N.  The staged query /
-# neighbour rows are DMA'd once per block (at chunk 0) and stay resident
-# across that block's chunk sweep; each chunk replays the (cheap,
-# vectorised) tail-power math and bins only the edges whose target falls
-# inside the chunk.  ops.py picks ``chunk_n`` so the slabs fit the VMEM
-# budget (see ``scatter_chunk_plan``), which is what lifts the old
-# whole-(N, d)-resident cap that forced large-N runs back to the XLA
-# segment-sum ref.
+# VMEM: the field of one *N-chunk* of ``chunk_n`` target rows per segment
+# stays resident (lane-padded, (chunk_n, 128) f32) while the inner grid
+# axis sweeps every row block and accumulates into it; the outer axis
+# walks the chunks.  Each (chunk, block) step re-stages the block's rows,
+# replays the (vectorised) tail-power math into VMEM scratch and bins the
+# edges whose target falls inside the chunk.  ops.py picks ``chunk_n`` so
+# the slabs fit the VMEM budget (see ``scatter_chunk_plan``): N only
+# raises the chunk count.
 
 
 def _ne_forces_scatter_kernel(qid_ref, nbr_ref, alpha_ref, coef_ref, x_ref,
                               *refs, segments: tuple, scatter_back: tuple,
                               chunk_n: int):
-    """qid (bb,) SMEM; nbr (bb, K) SMEM; alpha (1,1) SMEM; coef (bb, K) VMEM;
-    x (N, d) ANY -> per segment s: scat (1, chunk_n, d) grid-block x
-    N-chunk partial, wsum (bb, 1); then scratch (q_scr, n_scr, sem)."""
+    """qid (1, bb) SMEM; nbr (bb, K) SMEM; alpha (1,1) SMEM; coef (bb, K)
+    VMEM; x (N, dp) ANY lane-padded -> per segment s: scat (chunk_n, dp)
+    resident field of N-chunk c, wsum (bb, 1); then scratch (q_scr,
+    n_scr, edge_scr, agg_scr, sem)."""
     S = len(segments)
     scat_refs = refs[:S]
     wsum_refs = refs[S:2 * S]
-    q_scr, n_scr, sem = refs[2 * S:]
-    block_b, K, _ = n_scr.shape
-    c = pl.program_id(1)
+    q_scr, n_scr, edge_scr, agg_scr, sem = refs[2 * S:]
+    block_b = n_scr.shape[0]
+    c, i = pl.program_id(0), pl.program_id(1)
     off = c * chunk_n
 
-    @pl.when(c == 0)
-    def _stage():        # rows stay resident across this block's chunk sweep
-        _dma_query_and_neighbour_rows(x_ref, qid_ref, nbr_ref, q_scr, n_scr,
-                                      sem)
+    @pl.when(i == 0)
+    def _zero():         # a chunk's field accumulates over every row block
+        for sc in scat_refs:
+            sc[...] = jnp.zeros_like(sc)
 
+    _dma_query_and_neighbour_rows(x_ref, qid_ref, nbr_ref, q_scr, n_scr, sem)
     alpha = alpha_ref[0, 0]
-    y = q_scr[...].astype(jnp.float32)              # (bb, d)
-    nbr = n_scr[...].astype(jnp.float32)            # (bb, K, d)
+    y = q_scr[...].astype(jnp.float32)              # (bb, dp)
+    nbr = n_scr[...].astype(jnp.float32)            # (bb, K, dp)
     coef = coef_ref[...].astype(jnp.float32)        # (bb, K)
 
-    def accumulate(scat_ref, agg, edge, k0, size, back):
+    def in_chunk(t):
+        return (t >= off) & (t < off + chunk_n)
+
+    def accumulate(sc, k0, size, back):
         # Index-binned accumulation: serialised read-modify-writes handle
         # duplicate targets (negatives / shared neighbours) exactly; the
-        # chunk guard keeps every write inside this step's (chunk_n, d)
+        # chunk guard keeps every write inside this step's (chunk_n, dp)
         # slab.
         def nbr_body(r):
             def body(k, _):
                 t = nbr_ref[r, k0 + k]
 
-                @pl.when((t >= off) & (t < off + chunk_n))
-                def _in_chunk():
-                    scat_ref[0, t - off] += -edge[r, k]
+                @pl.when(in_chunk(t))
+                def _bin():
+                    sc[pl.ds(t - off, 1)] -= edge_scr[r, pl.ds(k, 1)]
                 return _
             jax.lax.fori_loop(0, size, body, None)
 
         def row_body(r, _):
-            q = qid_ref[r]
+            q = qid_ref[0, r]
 
-            @pl.when((q >= off) & (q < off + chunk_n))
-            def _in_chunk():
-                scat_ref[0, q - off] += agg[r]
+            @pl.when(in_chunk(q))
+            def _bin():
+                sc[pl.ds(q - off, 1)] += agg_scr[pl.ds(r, 1)]
             if back:
                 nbr_body(r)
             return _
@@ -439,10 +444,15 @@ def _ne_forces_scatter_kernel(qid_ref, nbr_ref, alpha_ref, coef_ref, x_ref,
         edge, wsum = _edge_wsum(nbr[:, sl] - y[:, None, :], coef[:, sl],
                                 alpha, mode)
         wsum_refs[s][...] = wsum[:, None]
-        scat_refs[s][...] = jnp.zeros_like(scat_refs[s])
-        accumulate(scat_refs[s], jnp.sum(edge, axis=1), edge, k0, size,
-                   scatter_back[s])
+        edge_scr[:, :size] = edge
+        agg_scr[...] = jnp.sum(edge, axis=1)
+        accumulate(scat_refs[s], k0, size, scatter_back[s])
         k0 += size
+
+
+# scoped-VMEM limit of the scatter kernel: its resident fields outgrow
+# Mosaic's 16 MiB default; a TPU v5e core has 128 MiB of VMEM
+SCATTER_VMEM_LIMIT = 96 * 2 ** 20
 
 
 @functools.partial(
@@ -450,7 +460,7 @@ def _ne_forces_scatter_kernel(qid_ref, nbr_ref, alpha_ref, coef_ref, x_ref,
                               "chunk_n", "interpret"))
 def ne_forces_scatter_pallas(x, qid, nbr_idx, coef, alpha, *,
                              segments: tuple, scatter_back: tuple = None,
-                             block_b: int = None, chunk_n: int = None,
+                             block_b: int = 64, chunk_n: int = None,
                              interpret: bool = False):
     """Scatter-fused segmented force kernel (see block comment above).
 
@@ -460,17 +470,18 @@ def ne_forces_scatter_pallas(x, qid, nbr_idx, coef, alpha, *,
         neighbour's row (the symmetrisation); False segments (e.g.
         negative samples) contribute only the query-side aggregate.
       chunk_n: target rows binned per grid step (default: all N in one
-        chunk).  The resident per-segment slab is (chunk_n, d), so
-        ``chunk_n`` bounds VMEM regardless of N; each block's staged rows
-        are reused across its chunk sweep (one DMA round per block).
+        chunk).  The resident per-segment slab is (chunk_n, d) (lane-
+        padded), so ``chunk_n`` bounds VMEM regardless of N.
     Returns:
-      scats: tuple of (N, d) f32 per-segment displacement-field partials,
-        already reduced over grid blocks -- scats[s][i] carries every
-        force this launch exerts on point i through segment s.  No
-        (B, K_s, d) edge tensor is ever written to HBM.
+      scats: tuple of (N, d) f32 per-segment displacement fields --
+        scats[s][i] carries every force this launch exerts on point i
+        through segment s.  No (B, K_s, d) edge tensor is ever written to
+        HBM.
       wsums: tuple of (B,) w partial sums (Z-hat estimator terms).
     """
     N, d = x.shape
+    x = pad_lanes(x)
+    dp = x.shape[1]
     B, K = nbr_idx.shape
     S = len(segments)
     if scatter_back is None:
@@ -488,16 +499,10 @@ def ne_forces_scatter_pallas(x, qid, nbr_idx, coef, alpha, *,
     nbr_idx = jnp.clip(nbr_idx.astype(jnp.int32), 0, N - 1)
     coef = coef.astype(jnp.float32)
 
-    if block_b is None:
-        # Unlike the edge-emitting kernel, each grid block here writes
-        # S * N * d of partials, so the epilogue's HBM traffic is
-        # G * S * N * d: cap the number of grid blocks (G <= 8) instead
-        # of fixing block_b, keeping the partial traffic below the edge
-        # write+scatter-read it replaces at any B.
-        block_b = max(128, _round_up(-(-B // 8), 8))
+    k_max = max(size for _, size in segments)
     block_b = min(block_b, _round_up(B, 8))
-    while block_b > 8 and (K + 1) * block_b * d * x.dtype.itemsize \
-            > 8 * 2 ** 20:
+    # staged rows + per-segment edge/agg scratch, all lane-padded
+    while block_b > 8 and (K + k_max + 2) * block_b * dp * 4 > 4 * 2 ** 20:
         block_b //= 2
     Bp = _round_up(B, block_b)
     if Bp != B:
@@ -507,39 +512,45 @@ def ne_forces_scatter_pallas(x, qid, nbr_idx, coef, alpha, *,
         coef = jnp.pad(coef, ((0, Bp - B), (0, 0)))
     alpha_arr = jnp.asarray(alpha, jnp.float32).reshape(1, 1)
 
+    qid, qid_spec = smem_ids(qid, block_b, grid_axis=1)
     G = Bp // block_b
     Np = _round_up(N, chunk_n)
     n_chunks = Np // chunk_n
     outs = pl.pallas_call(
         functools.partial(_ne_forces_scatter_kernel, segments=segments,
                           scatter_back=scatter_back, chunk_n=chunk_n),
-        grid=(G, n_chunks),
+        # chunk-outer: a chunk's field stays resident across the inner
+        # sweep over row blocks, which must therefore run in order
+        grid=(n_chunks, G),
         in_specs=[
-            pl.BlockSpec((block_b,), lambda i, c: (i,),
+            qid_spec,
+            pl.BlockSpec((block_b, K), lambda c, i: (i, 0),
                          memory_space=pltpu.SMEM),
-            pl.BlockSpec((block_b, K), lambda i, c: (i, 0),
+            pl.BlockSpec((1, 1), lambda c, i: (0, 0),
                          memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1), lambda i, c: (0, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((block_b, K), lambda i, c: (i, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec((block_b, K), lambda c, i: (i, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=(
-            [pl.BlockSpec((1, chunk_n, d), lambda i, c: (i, c, 0))] * S
-            + [pl.BlockSpec((block_b, 1), lambda i, c: (i, 0))] * S
+            [pl.BlockSpec((chunk_n, dp), lambda c, i: (c, 0))] * S
+            + [pl.BlockSpec((block_b, 1), lambda c, i: (i, 0))] * S
         ),
         out_shape=(
-            [jax.ShapeDtypeStruct((G, Np, d), jnp.float32)] * S
+            [jax.ShapeDtypeStruct((Np, dp), jnp.float32)] * S
             + [jax.ShapeDtypeStruct((Bp, 1), jnp.float32)] * S
         ),
         scratch_shapes=[
-            pltpu.VMEM((block_b, d), x.dtype),
-            pltpu.VMEM((block_b, K, d), x.dtype),
+            pltpu.VMEM((block_b, dp), x.dtype),
+            pltpu.VMEM((block_b, K, dp), x.dtype),
+            pltpu.VMEM((block_b, k_max, dp), jnp.float32),
+            pltpu.VMEM((block_b, dp), jnp.float32),
             pltpu.SemaphoreType.DMA(()),
         ],
+        compiler_params=tpu_compiler_params(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=SCATTER_VMEM_LIMIT),
         interpret=interpret,
     )(qid, nbr_idx, alpha_arr, coef, x)
-    # the final cheap XLA reduction of the per-grid-block partials
-    scats = tuple(jnp.sum(o, axis=0)[:N] for o in outs[:S])
+    scats = tuple(o[:N, :d] for o in outs[:S])
     wsums = tuple(o[:B, 0] for o in outs[S:])
     return scats, wsums

@@ -1,9 +1,8 @@
 """Public jit'd wrapper for the fused NE force kernel."""
 from __future__ import annotations
 
-import jax
-
 from repro.kernels import fallback
+from repro.kernels.backend import resolve
 from repro.kernels.ne_forces.kernel import (ne_forces_gather_pallas,
                                             ne_forces_pallas,
                                             ne_forces_scatter_pallas)
@@ -11,51 +10,33 @@ from repro.kernels.ne_forces.ref import (ne_forces_gather_ref, ne_forces_ref,
                                          ne_forces_scatter_ref)
 
 
-def _default_backend() -> str:
-    try:
-        platform = jax.devices()[0].platform
-    except Exception:  # pragma: no cover
-        platform = "cpu"
-    return "pallas" if platform == "tpu" else "xla"
-
-
 # VMEM budget for the scatter kernel's resident per-segment (chunk_n, d)
-# slabs.  Mosaic pads the trailing dim to the 128-lane tile and all S
-# segment slabs stay resident for a whole grid step, so S * chunk_n *
-# 512B at d<=128 must leave room for the neighbour scratch.  Unlike the
-# pre-chunking kernel (whole (N, d) resident -> hard N cap, XLA fallback
-# past ~6.8k rows at d=2/S=3) the budget now sizes the *chunk*: N only
-# raises the chunk count.  The XLA segment-sum ref remains as a guard
-# for degenerate plans (chunk counts so high the staged-row reuse stops
-# paying for the replayed per-chunk sweep).
-_SCATTER_VMEM_BUDGET = 10 * 2 ** 20
-_SCATTER_MAX_CHUNKS = 64
+# fields.  Mosaic pads the trailing dim to the 128-lane tile and all S
+# segment fields stay resident (double-buffered) across a chunk's sweep,
+# so 2 * S * chunk_n * 512B at d<=128 plus the staged rows and the edge
+# scratch must fit the kernel's scoped-VMEM limit
+# (``kernel.SCATTER_VMEM_LIMIT``).  The budget sizes the *chunk*: N only
+# raises the chunk count.
+_SCATTER_VMEM_BUDGET = 24 * 2 ** 20
 
 
-def scatter_chunk_plan(n: int, d: int, n_segments: int):
-    """Rows binned per grid step so the S resident slabs fit VMEM.
+def scatter_chunk_plan(n: int, d: int, n_segments: int) -> int:
+    """Rows binned per grid step so the S resident fields fit VMEM.
 
-    Returns ``chunk_n`` (== n when everything fits in one chunk), or
-    ``None`` when even a degenerate chunking can't make the kernel
-    worthwhile -> caller falls back to the XLA segment-sum ref.
+    Returns ``chunk_n`` (== n when everything fits in one chunk).  Every
+    shape gets a plan; a large N only costs more chunks.
     """
     lane_padded = -(-d // 128) * 128
     bytes_per_row = n_segments * lane_padded * 4
     max_rows = _SCATTER_VMEM_BUDGET // max(bytes_per_row, 1)
     if max_rows >= n:
         return n
-    chunk_n = (max_rows // 8) * 8          # keep sublane-tile alignment
-    if chunk_n < 8:
-        return None
-    if -(-n // chunk_n) > _SCATTER_MAX_CHUNKS:
-        return None
-    return chunk_n
+    return max(8, (max_rows // 8) * 8)      # keep sublane-tile alignment
 
 
 def ne_forces(y, nbr, coef, alpha, *, mode: str, backend: str = "auto"):
     """Fused variable-tail force evaluation; see ref.py for semantics."""
-    if backend == "auto":
-        backend = _default_backend()
+    backend = resolve(backend)
     if backend in ("pallas", "interpret"):
         return fallback.guarded(
             "ne_forces",
@@ -92,21 +73,12 @@ def ne_forces_gather(x, qid, nbr_idx, coef, alpha, *, segments,
         (scats, wsums); ``emit_edges`` must be left None.
     """
     segments = tuple((str(m), int(s)) for m, s in segments)
-    if backend == "auto":
-        backend = _default_backend()
+    backend = resolve(backend)
     if scatter_fused:
         assert emit_edges is None, "emit_edges is an edge-mode option"
         if scatter_back is not None:
             scatter_back = tuple(bool(b) for b in scatter_back)
         chunk_n = scatter_chunk_plan(x.shape[0], x.shape[1], len(segments))
-        if backend in ("pallas", "interpret") and chunk_n is None:
-            # degenerate VMEM plan: the XLA segment-sum ref answers this
-            # shape; logged once on the telemetry channel (non-sticky --
-            # other shapes may still plan fine)
-            fallback.note("ne_forces",
-                          f"scatter chunk plan degenerate at n={x.shape[0]} "
-                          f"d={x.shape[1]} S={len(segments)}; XLA ref")
-            backend = "xla"
 
         def run_scatter_ref():
             return ne_forces_scatter_ref(x, qid, nbr_idx, coef, alpha,

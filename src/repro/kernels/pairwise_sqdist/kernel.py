@@ -88,6 +88,35 @@ def _round_up(x: int, mult: int) -> int:
     return ((x + mult - 1) // mult) * mult
 
 
+LANES = 128
+
+
+def pad_lanes(x):
+    """Zero-pad the minor dim of ``x`` to a multiple of the 128-lane tile.
+
+    Mosaic DMAs only lane-aligned row slices out of HBM, so every source
+    matrix a row-gather kernel reads is stored lane-padded.  Zero columns
+    leave squared distances and force deltas unchanged; an aligned ``x``
+    is returned as is (callers that pad once up front pay nothing here).
+    """
+    pad = -x.shape[-1] % LANES
+    if not pad:
+        return x
+    return jnp.pad(x, ((0, 0),) * (x.ndim - 1) + ((0, pad),))
+
+
+def smem_ids(ids, block_b: int, grid_axis: int = 0):
+    """(Bp,) int32 row ids -> ((Bp / block_b, 1, block_b) array, SMEM spec).
+
+    1-D int32 operands get a different tiling from XLA than from Mosaic,
+    so id vectors travel as one row per row block; the kernel reads
+    ``ref[0, r]``.  ``grid_axis`` is the grid axis that walks row blocks.
+    """
+    return ids.reshape(-1, 1, block_b), pl.BlockSpec(
+        (None, 1, block_b), lambda *g: (g[grid_axis], 0, 0),
+        memory_space=pltpu.SMEM)
+
+
 # --------------------------------------------------------------------------
 # Gather-fused variant: the kernel takes *indices*, not gathered operands.
 #
@@ -129,9 +158,10 @@ def score_gather_block(qid_ref, gat_ref, x_ref, acc, q_scr, c_scr, q_sem,
     ``knn_merge`` kernel (which runs its selection epilogue on ``acc``
     after the final chunk).
 
-    qid_ref: (block_b,) SMEM        query row ids
+    qid_ref: (1, block_b) SMEM      query row ids
     gat_ref: (block_b, G) SMEM      gathered (clipped) row ids
-    x_ref: (N, M) ANY               source matrix (stays in HBM)
+    x_ref: (N, M) ANY               lane-padded source matrix (stays in
+                                    HBM)
     acc: (block_b, G) VMEM          squared-distance accumulator
                                     (output block or scratch)
     q_scr: (n_mchunks, block_b, block_m) if persistent_q
@@ -154,7 +184,7 @@ def score_gather_block(qid_ref, gat_ref, x_ref, acc, q_scr, c_scr, q_sem,
 
         def q_dma(jc, r):
             return pltpu.make_async_copy(
-                x_ref.at[qid_ref[r], pl.ds(chunk_start(jc), block_m)],
+                x_ref.at[qid_ref[0, r], pl.ds(chunk_start(jc), block_m)],
                 q_scr.at[jc, r], q_sem.at[jc])
 
         @pl.when(j == 0)
@@ -174,7 +204,7 @@ def score_gather_block(qid_ref, gat_ref, x_ref, acc, q_scr, c_scr, q_sem,
             r = p * sub_b + lr
             if not persistent_q:
                 op(pltpu.make_async_copy(
-                    x_ref.at[qid_ref[r], pl.ds(m0, block_m)],
+                    x_ref.at[qid_ref[0, r], pl.ds(m0, block_m)],
                     q_scr.at[slot, lr], c_sem.at[slot]))
             jax.lax.fori_loop(
                 0, G, lambda k, x: (op(pltpu.make_async_copy(
@@ -234,18 +264,15 @@ def _pick_sub_b(block_b: int) -> int:
 
 
 def plan_row_gather(B, M, G, itemsize, *, block_b, block_m, sub_b,
-                    persistent_q, chain_slots=0):
+                    persistent_q, row_bytes=0):
     """Tiling plan for the row-gather scoring pipeline (shared with the
     merge-fused ``knn_merge`` kernel): resolves the block/sub-block sizes
     against the VMEM staging budget and the persistent-q heuristic.
 
-    ``chain_slots`` is the second-table channel (§Perf H17): the
-    candidate-fused merge kernel stages that many chained
-    ``second_idx[mid, b]`` int32 picks per block row (one SMEM + one VMEM
-    element each, so the in-flight X-row DMAs can take their addresses
-    from SMEM while the merge reads the same values as vectors); the
-    per-row chain staging is charged against the same budget as the row
-    staging so a wide chain shrinks ``block_b`` like a wide ``G`` does.
+    ``row_bytes`` is any further VMEM staging a kernel keeps per block
+    row (the candidate-fused merge's activity rows); it is charged
+    against the same budget as the row staging, so it shrinks
+    ``block_b`` like a wide ``G`` does.
 
     Returns (block_b, block_m, sub_b, persistent_q, n_mchunks,
     q_scr_shape) with ``G`` gathered rows per block row.
@@ -256,9 +283,8 @@ def plan_row_gather(B, M, G, itemsize, *, block_b, block_m, sub_b,
         sub_b = _pick_sub_b(block_b)
     assert block_b % sub_b == 0, (block_b, sub_b)
     # keep the 2-slot (G+1) row-chunk staging comfortably inside VMEM
-    # (+ the chained second-table picks: 2 int32 copies per chain slot)
     while block_b > 8 and 2 * min(sub_b, block_b) * (G + 1) * block_m \
-            * itemsize + 2 * block_b * chain_slots * 4 > 8 * 2 ** 20:
+            * itemsize + block_b * row_bytes > 8 * 2 ** 20:
         block_b //= 2
         # a halved block_b may no longer be a multiple of sub_b: every row
         # of a block must land in some sub-block, so re-derive a divisor
@@ -290,13 +316,15 @@ def pairwise_sqdist_gather_pallas(
 
     Indices are clipped to [0, N); callers mask invalid slots themselves
     (SENTINEL handling lives in the KNN merge).  B is padded to ``block_b``
-    with row-0 gathers that are dropped on exit; M is tiled at ``block_m``
-    with a clamped+masked final chunk, so X is never padded or copied.
+    with row-0 gathers that are dropped on exit.  X is lane-padded
+    (:func:`pad_lanes`, a no-op when M is a multiple of 128) and M is
+    tiled at ``block_m`` with a clamped+masked final chunk.
 
     ``sub_b`` (must divide ``block_b``) sets the double-buffer sub-block;
     ``persistent_q`` keeps all M-chunks of the block's q rows VMEM-resident
     (auto: on when M spans >1 chunk and the slab stays under ~4MB).
     """
+    x = pad_lanes(x)
     N, M = x.shape
     B, = qid.shape
     Bc, C = cand.shape
@@ -314,17 +342,17 @@ def pairwise_sqdist_gather_pallas(
         qid = jnp.pad(qid, (0, Bp - B))
         cand = jnp.pad(cand, ((0, Bp - B), (0, 0)))
 
+    qid, qid_spec = smem_ids(qid, block_b)
     grid = (Bp // block_b, n_mchunks)
     out = pl.pallas_call(
         functools.partial(score_gather_block, m_size=M, block_m=block_m,
                           sub_b=sub_b, persistent_q=persistent_q),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_b,), lambda i, j: (i,),
-                         memory_space=pltpu.SMEM),
+            qid_spec,
             pl.BlockSpec((block_b, C), lambda i, j: (i, 0),
                          memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((block_b, C), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Bp, C), jnp.float32),

@@ -8,27 +8,17 @@ Backend selection:
 """
 from __future__ import annotations
 
-import jax
-
 from repro.kernels import fallback
+from repro.kernels.backend import resolve
 from repro.kernels.pairwise_sqdist.kernel import (
     pairwise_sqdist_gather_pallas, pairwise_sqdist_pallas)
 from repro.kernels.pairwise_sqdist.ref import (
     pairwise_sqdist_gather_ref, pairwise_sqdist_ref)
 
 
-def _default_backend() -> str:
-    try:
-        platform = jax.devices()[0].platform
-    except Exception:  # pragma: no cover - device init failure
-        platform = "cpu"
-    return "pallas" if platform == "tpu" else "xla"
-
-
 def pairwise_sqdist(q, c, *, backend: str = "auto"):
     """Squared distances between queries (B, M) and candidates (B, C, M)."""
-    if backend == "auto":
-        backend = _default_backend()
+    backend = resolve(backend)
     if backend in ("pallas", "interpret"):
         return fallback.guarded(
             "pairwise_sqdist",
@@ -48,8 +38,7 @@ def pairwise_sqdist_gather(x, qid, cand, *, backend: str = "auto"):
     The 'xla' path is the pure-jnp fallback used on CPU and as the dry-run
     lowering; it gathers explicitly but keeps the same semantics.
     """
-    if backend == "auto":
-        backend = _default_backend()
+    backend = resolve(backend)
     if backend in ("pallas", "interpret"):
         return fallback.guarded(
             "pairwise_sqdist",

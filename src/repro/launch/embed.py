@@ -6,13 +6,15 @@
 Runs on the scan-chunked driver: ``--chunk T`` iterations execute per
 device dispatch (T=1 reproduces the per-step dispatch baseline).  A full
 warmup chunk runs before the clock starts, so the reported steps/sec
-excludes compile time and is the paper-style speed number.  Prints R_NX
-AUC quality and (optionally) writes the embedding to .npy.
+excludes compile time and is the paper-style speed number.  Prints the
+R_NX AUC over a fixed sample of 2048 query rows (exact KNN in blocks, so
+it works at any n) and (optionally) writes the embedding to .npy.
 """
 from __future__ import annotations
 
 import argparse
 import time
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -21,6 +23,7 @@ import numpy as np
 from repro.core import funcsne
 from repro.core.quality import embedding_quality
 from repro.data import synthetic
+from repro.launch import compile_cache
 
 
 def load_dataset(name: str, n: int, seed: int = 0):
@@ -87,6 +90,7 @@ def main():
     args = ap.parse_args()
     if args.resume and not args.checkpoint_dir:
         ap.error("--resume requires --checkpoint-dir")
+    compile_cache.enable(Path(__file__).resolve().parents[3])
 
     multiprocess = args.num_processes > 1
     if multiprocess:

@@ -66,6 +66,13 @@ CLI::
 ``python -m repro.launch.embed --num-processes N --process-id I
 --coordinator H:P`` is the manual (no-supervisor) multi-process launch
 of the same worker loop.
+
+One process per chip: a process that initialises JAX on an accelerator
+holds every local chip until it exits, and a second process that needs
+the same chip fails or hangs.  On one TPU host a single process drives
+all local chips (``python -m repro.launch.embed --devices 4``); the
+pods of this module are processes on separate hosts, or CPU workers
+(``JAX_PLATFORMS=cpu``) as in the ``process_kill`` drill.
 """
 from __future__ import annotations
 
@@ -97,22 +104,11 @@ class SupervisorError(RuntimeError):
 
 
 def gloo_available() -> bool:
-    """True when this jaxlib exposes CPU cross-process collectives.
-
-    Feature-detected through the PUBLIC config API -- ``jax.config
-    .update`` raises for unknown option names -- never through private
-    registries a jax refactor can silently rename (``hasattr(jax.config,
-    ...)`` is additionally a false negative for config knobs).  The
-    probe re-writes the current value, so it never changes the probing
-    process's behaviour.  Imports jax lazily: the supervisor itself must
-    stay JAX-runtime-free."""
-    try:
-        import jax
-        prev = jax.config.read("jax_cpu_collectives_implementation")
-        jax.config.update("jax_cpu_collectives_implementation", prev)
-        return True
-    except Exception:
-        return False
+    """True when this jax exposes CPU cross-process collectives (the
+    ``jax_cpu_collectives_implementation`` option).  Imports jax lazily:
+    the supervisor itself must stay JAX-runtime-free."""
+    import jax
+    return "jax_cpu_collectives_implementation" in jax.config.values
 
 
 def _free_port() -> int:

@@ -469,7 +469,7 @@ def scenario_kernel_fallback(backend="interpret") -> dict:
 
     fallback.reset()
     with active(FaultScript(KernelLaunchFault("knn_merge"))):
-        policy = ResiliencePolicy()
+        policy = ResiliencePolicy(sticky_fallback=True)
         st_fault, _ = funcsne.fit(X, cfg=cfg, n_iter=8, chunk_size=4,
                                   resilience=policy)
     assert "knn_merge" in fallback.demotions(), fallback.demotions()
@@ -478,7 +478,8 @@ def scenario_kernel_fallback(backend="interpret") -> dict:
     fallback.demote("knn_merge", "pre-demoted (smoke parity reference)")
     with fallback.enabled():
         st_ref, _ = funcsne.fit(X, cfg=cfg, n_iter=8, chunk_size=4,
-                                resilience=ResiliencePolicy())
+                                resilience=ResiliencePolicy(
+                                    sticky_fallback=True))
     fallback.reset()
     np.testing.assert_array_equal(np.asarray(st_fault.Y),
                                   np.asarray(st_ref.Y))
@@ -716,9 +717,10 @@ def scenario_process_kill(backend="interpret", tmpdir=None) -> dict:
         tmpdir, n_pods=2, n_iter=n_iter, chunk_size=chunk, n=64, dim=6,
         backend=backend, kill_pod=1, kill_at_chunk=8,
         heartbeat_timeout=20.0, total_timeout=480.0,
-        # pin workers to 1 local device each: the scenario may itself
-        # run under --xla_force_host_platform_device_count
-        extra_env={"XLA_FLAGS": ""})
+        # the drill tests the control plane, not the chip: workers run
+        # on the CPU (this process may hold the accelerator), one local
+        # device each even under --xla_force_host_platform_device_count
+        extra_env={"XLA_FLAGS": "", "JAX_PLATFORMS": "cpu"})
     report = sup.run()
 
     # the survivor finished every iteration and committed the boundary

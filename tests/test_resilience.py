@@ -227,7 +227,7 @@ def test_kernel_fault_demotes_and_matches_predemoted_run():
     kw = dict(cfg=cfg, n_iter=4, chunk_size=2)
     try:
         fallback.reset()
-        policy = ResiliencePolicy()
+        policy = ResiliencePolicy(sticky_fallback=True)
         with faults.active(FaultScript(KernelLaunchFault("knn_merge"))):
             st_fault, _ = funcsne.fit(X, resilience=policy, **kw)
         assert "knn_merge" in fallback.demotions()
@@ -237,15 +237,16 @@ def test_kernel_fault_demotes_and_matches_predemoted_run():
         with pytest.warns(RuntimeWarning):
             fallback.demote("knn_merge", "pre-demoted (parity reference)")
         with fallback.enabled():
-            st_ref, _ = funcsne.fit(X, resilience=ResiliencePolicy(), **kw)
+            st_ref, _ = funcsne.fit(
+                X, resilience=ResiliencePolicy(sticky_fallback=True), **kw)
         _assert_state_equal(st_fault, st_ref)
     finally:
         fallback.reset()
 
 
 def test_fallback_registry_is_thread_safe_under_churn():
-    """Two threads hammer the registry -- one demoting/noting fresh
-    families, one reading events()/demotions()/is_demoted() -- while the
+    """Two threads hammer the registry -- one demoting fresh families,
+    one reading events()/demotions()/is_demoted() -- while the
     readers iterate snapshots.  Before the lock fix the readers copied
     the shared dict/list WHILE the writer appended (a genuine race:
     `dict(_DEMOTED)` and `list(_EVENTS[...])` iterate the live
@@ -266,7 +267,6 @@ def test_fallback_registry_is_thread_safe_under_churn():
                 i = 0
                 while not stop.is_set():
                     fallback.demote(f"fam_{i}", "stress")
-                    fallback.note(f"fam_{i}", f"reason_{i}")
                     i += 1
         except Exception as e:          # pragma: no cover - fail surface
             errors.append(e)

@@ -205,21 +205,23 @@ def test_scatter_kernel_chunked_bins_vs_ref(chunk_n, n, b, d, block_b):
 
 
 def test_scatter_chunk_plan_lifts_large_n_vmem_cap():
-    """Acceptance: n=16384 at d=2 with the step's 3 segments no longer
-    falls back to the XLA segment-sum ref -- the plan chunks the bins so
-    the resident slabs fit the ~10MB VMEM budget."""
+    """Acceptance: n=65536 at d=2 with the step's 3 segments does not
+    fall back to the XLA segment-sum ref -- the plan chunks the bins so
+    the resident slabs fit the VMEM budget."""
     from repro.kernels.ne_forces import ops
 
-    chunk_n = ops.scatter_chunk_plan(16384, 2, 3)
-    assert chunk_n is not None, "fused epilogue fell back at n=16384/d=2"
-    n_chunks = -(-16384 // chunk_n)
+    chunk_n = ops.scatter_chunk_plan(65536, 2, 3)
+    assert chunk_n is not None, "fused epilogue fell back at n=65536/d=2"
+    n_chunks = -(-65536 // chunk_n)
     assert n_chunks > 1, "plan claims a whole-(N,d) slab fits; it cannot"
     lane_padded = 128                       # d=2 pads to one 128-lane tile
     assert 3 * chunk_n * lane_padded * 4 <= ops._SCATTER_VMEM_BUDGET
     assert chunk_n % 8 == 0                 # sublane-tile aligned
-    # small problems stay single-chunk; absurd ones still decline
+    # small problems stay single-chunk; huge ones only take more chunks
+    # (no shape is sent to the XLA ref)
     assert ops.scatter_chunk_plan(2048, 2, 3) == 2048
-    assert ops.scatter_chunk_plan(10 ** 7, 2, 3) is None
+    big = ops.scatter_chunk_plan(10 ** 7, 2, 3)
+    assert big == chunk_n and -(-10 ** 7 // big) > 64
 
 
 def test_scatter_ops_dispatch_uses_chunked_kernel_past_old_cap(monkeypatch):
