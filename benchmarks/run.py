@@ -6,9 +6,8 @@ CPU-feasible scale; the roofline table comes from the dry-run
 (repro.launch.dryrun), not from here.
 
 ``--json PATH`` additionally writes the machine-readable results
-(``{name: us_per_call}``) so the perf trajectory is tracked in-repo:
-``BENCH_kernels.json`` (kernel microbenches) and ``BENCH_step.json``
-(fig8 step timings) are the committed baselines.
+(``{name: us_per_call}``).  They are CPU and interpret-mode numbers;
+the on-chip benchmark is ``bench/``.
 
   PYTHONPATH=src python -m benchmarks.run [--only fig6,fig8] [--fast]
                                           [--json PATH]

@@ -355,6 +355,25 @@ def _row_sqdist(X, ids, cand, ctx: AxisCtx, cfg: "FuncSNEConfig"):
     return d
 
 
+def _phase_scope(scope: str):
+    """Trace the decorated phase function under ``jax.named_scope(scope)``.
+
+    The scope is HLO metadata only (``op_name=".../<scope>/..."`` on every
+    instruction the phase emits, in ``lax.cond`` branch bodies too): it
+    changes no op, fusion or layout, and lets a device trace attribute
+    each op to its phase.  Applied to the function itself, so every entry
+    point (``make_step``, ``make_chunked_step``,
+    ``make_distributed_step``) carries it.
+    """
+    def wrap(fn):
+        @functools.wraps(fn)
+        def scoped(*args, **kwargs):
+            with jax.named_scope(scope):
+                return fn(*args, **kwargs)
+        return scoped
+    return wrap
+
+
 # --------------------------------------------------------------------------
 # Phase 1: HD neighbour refinement
 
@@ -379,6 +398,7 @@ def _rev_update(cfg: FuncSNEConfig, st: FuncSNEState, fill):
     return st._replace(rev_idx=rev, rev_step=rstep)
 
 
+@_phase_scope("funcsne.hd_refine")
 def _hd_refine(cfg: FuncSNEConfig, st: FuncSNEState, X, rng, ctx: AxisCtx):
     n = cfg.n_points
     start, n_loc = _phase_rows(n, ctx.points)
@@ -486,6 +506,7 @@ def _hd_refine(cfg: FuncSNEConfig, st: FuncSNEState, X, rng, ctx: AxisCtx):
 # Phase 2: sigma (beta) refresh for flagged rows
 
 
+@_phase_scope("funcsne.sigma_refresh")
 def _sigma_refresh(cfg: FuncSNEConfig, st: FuncSNEState, hp: HParams,
                    ctx: AxisCtx):
     start, n_loc = _phase_rows(cfg.n_points, ctx.all_rows)
@@ -508,6 +529,7 @@ def _sigma_refresh(cfg: FuncSNEConfig, st: FuncSNEState, hp: HParams,
 # Phase 3: LD neighbour refinement (every iteration)
 
 
+@_phase_scope("funcsne.ld_refine")
 def _ld_refine(cfg: FuncSNEConfig, st: FuncSNEState, rng, ctx: AxisCtx):
     n = cfg.n_points
     start, n_loc = _phase_rows(n, ctx.all_rows)
@@ -599,6 +621,7 @@ def _ld_refine(cfg: FuncSNEConfig, st: FuncSNEState, rng, ctx: AxisCtx):
 # Phase 4: forces + embedding update
 
 
+@_phase_scope("funcsne.forces_update")
 def _forces_update(cfg: FuncSNEConfig, st: FuncSNEState, hp: HParams, rng,
                    ctx: AxisCtx):
     n, d = cfg.n_points, cfg.dim_ld
@@ -767,6 +790,16 @@ def _forces_update(cfg: FuncSNEConfig, st: FuncSNEState, hp: HParams, rng,
 def funcsne_step(cfg: FuncSNEConfig, st: FuncSNEState, X, hp: HParams,
                  ctx: AxisCtx = AxisCtx()) -> FuncSNEState:
     """One fused FUnc-SNE iteration (see module docstring)."""
+    return _step_flags(cfg, st, X, hp, ctx)[0]
+
+
+def _step_flags(cfg: FuncSNEConfig, st: FuncSNEState, X, hp: HParams,
+                ctx: AxisCtx):
+    """:func:`funcsne_step`, also returning whether this step's HD
+    refinement gate (``do_hd``) and sigma-refresh condition
+    (``do_sigma``) fired, as () bool -- the chunk scan counts them into
+    :class:`ChunkMetrics` without a second draw.  Both are drawn from
+    replicated state, so every shard of a mesh sees the same flags."""
     # stochastic HD refinement: p = 0.05 + 0.95 E[N_new/N]  (paper Sec. 3)
     p_ref = cfg.min_refresh_prob + (1.0 - cfg.min_refresh_prob) \
         * st.ema_new_frac
@@ -796,7 +829,7 @@ def funcsne_step(cfg: FuncSNEConfig, st: FuncSNEState, X, hp: HParams,
 
     st = _ld_refine(cfg, st, r_ld, ctx)
     st = _forces_update(cfg, st, hp, r_force, ctx)
-    return st._replace(step=st.step + 1)
+    return st._replace(step=st.step + 1), do_hd, do_sigma
 
 
 # --------------------------------------------------------------------------
@@ -935,6 +968,10 @@ class ChunkMetrics(NamedTuple):
     #                     active rows' finite entries (explosion probe)
     bad_step: Any       # () i32  first global step whose embedding held a
     #                     non-finite active entry; -1 = none this chunk
+    hd_fires: Any       # () i32  steps of this chunk whose HD refinement
+    #                     gate fired
+    sigma_fires: Any    # () i32  steps of this chunk whose sigma refresh
+    #                     ran (step on the cadence and a row flagged)
 
 
 # decay of the per-chunk ChunkMetrics EMAs; ``fit`` needs the same
@@ -964,7 +1001,8 @@ def _chunk_fn(cfg: FuncSNEConfig, T: int, *, schedule=None, n_iter=None,
         same instants the host loop device_get'd); the host drains
         ``snaps[:metrics.n_snapshots]`` once per chunk;
       * metrics: per-step scalars are EMA'd into :class:`ChunkMetrics` so
-        the driver/GUI syncs one tuple per chunk;
+        the driver/GUI syncs one tuple per chunk; the steps whose HD gate
+        and sigma refresh fired are counted into the same tuple;
       * health telemetry: the finite-fraction of ``Y`` (min over the
         chunk), the max |Y| (max over the chunk) and the first step with
         a non-finite active entry fold into the same carry
@@ -1003,9 +1041,11 @@ def _chunk_fn(cfg: FuncSNEConfig, T: int, *, schedule=None, n_iter=None,
         health0 = (jnp.float32(1.0), jnp.float32(0.0), jnp.int32(-1))
 
         def body(carry, _):
-            st, snaps, k, disp, health = carry
+            st, snaps, k, disp, health, fires = carry
             hp_t = schedule(st.step, n_iter, hp) if schedule else hp
-            st = funcsne_step(cfg, st, X, hp_t, ctx)
+            st, do_hd, do_sigma = _step_flags(cfg, st, X, hp_t, ctx)
+            fires = (fires[0] + do_hd.astype(jnp.int32),
+                     fires[1] + do_sigma.astype(jnp.int32))
             act_col = st.active[:, None].astype(jnp.float32)
             n_act = jnp.maximum(jnp.sum(st.active.astype(jnp.float32)), 1.0)
             act_disp = jnp.sum(jnp.abs(st.vel) * act_col) / (n_act * d)
@@ -1049,10 +1089,11 @@ def _chunk_fn(cfg: FuncSNEConfig, T: int, *, schedule=None, n_iter=None,
                         s, st.Y, jnp.clip(k, 0, n_snap - 1), 0),
                     lambda s: s, snaps)
                 k = k + due.astype(jnp.int32)
-            return (st, snaps, k, disp, health), None
+            return (st, snaps, k, disp, health, fires), None
 
-        (st, snaps, k, disp, health), _ = jax.lax.scan(
-            body, (st, snaps0, jnp.int32(0), jnp.float32(0.0), health0),
+        (st, snaps, k, disp, health, fires), _ = jax.lax.scan(
+            body, (st, snaps0, jnp.int32(0), jnp.float32(0.0), health0,
+                   (jnp.int32(0), jnp.int32(0))),
             None, length=T)
         ff_min, ymax, bad = health
         if health_metrics and health_axes is not None:
@@ -1069,7 +1110,8 @@ def _chunk_fn(cfg: FuncSNEConfig, T: int, *, schedule=None, n_iter=None,
         metrics = ChunkMetrics(step=st.step, n_snapshots=k, disp_ema=disp,
                                zhat=st.zhat, ema_new_frac=st.ema_new_frac,
                                finite_frac=ff_min, y_max_abs=ymax,
-                               bad_step=bad)
+                               bad_step=bad, hd_fires=fires[0],
+                               sigma_fires=fires[1])
         return st, snaps, metrics
 
     return chunk
@@ -1164,6 +1206,15 @@ def remove_points(st: FuncSNEState, ids) -> FuncSNEState:
     ids = jnp.asarray(ids, jnp.int32)
     return st._replace(active=st.active.at[ids].set(False),
                        new_flag=st.new_flag.at[ids].set(False))
+
+
+def _span(name: str):
+    """Host span ``funcsne.<name>`` on the profiler's trace, on the same
+    clock as the device planes.  ``fit`` and ``fit_elastic`` open a
+    handful per chunk, never per step; with no profiler running one
+    costs well under a microsecond.  Capture them with
+    ``jax.profiler.trace(dir)`` around ``fit``."""
+    return jax.profiler.TraceAnnotation("funcsne." + name)
 
 
 def _copy_state(st: FuncSNEState) -> FuncSNEState:
@@ -1459,110 +1510,118 @@ def fit(X, *, cfg: FuncSNEConfig = None, n_iter: int = 750, rng=None,
             # exception (the happy path surfaces it via wait() below)
             stack.callback(ck.close)
         while it < n_iter:
-            T = min(chunk_size, n_iter - it)
-            if T not in chunks:
-                chunks[T] = make_chunked_step(cfg, T, schedule=schedule,
-                                              n_iter=n_iter,
-                                              snapshot_every=snapshot_every)
-            hp_run = _scaled_hp(hparams, lr_scale, ex_scale)
-            if policy is not None or faults.current() is not None:
-                # the chunk program donates its input; the live `st` is
-                # the rollback anchor, so dispatch a copy.  Scripted
-                # faults poison the *copy*: the anchor stays clean, as it
-                # would for a divergence that happens inside the chunk.
-                st_in = faults.corrupt_state(_copy_state(st), it)
-            else:
-                st_in = st
-            t0 = time.time()
-            st_out, snaps, metrics = chunks[T](st_in, X, hp_run)
-            alarm = None
-            if policy is not None:
-                m = jax.device_get(metrics)   # THE one host sync per chunk
-                alarm = monitor.observe(time.time() - t0)
-                if alarm is not None:
-                    policy.log("straggler", step=it, alarm=alarm)
-                for e in fallback.events(fb_seen):
-                    policy.log(**e)
-                fb_seen = fallback.n_events()
-                reason = policy.check(m)
-                if reason is None and policy.audit_every \
-                        and (n_healthy + 1) % policy.audit_every == 0:
-                    # chunk-boundary invariant audit: catches index
-                    # corruption the finite-fraction probes are blind
-                    # to; a violation feeds the SAME rollback path
-                    aud = jax.device_get(audit_state(st_out, cfg, X))
-                    reason = policy.audit_check(aud)
-                    if reason is not None:
-                        policy.log("audit_violation", step=it,
-                                   reason=reason)
-                if reason is not None:
-                    if retries >= policy.max_retries:
-                        policy.log("giving_up", step=it, reason=reason,
-                                   retries=retries)
-                        raise EmbeddingDiverged(it, reason, retries,
-                                                policy.events)
-                    retries += 1
-                    lr_scale *= policy.lr_backoff
-                    ex_scale *= policy.exaggeration_backoff
-                    policy.log("rollback", step=it, reason=reason,
-                               retry=retries, lr_scale=lr_scale,
-                               ex_scale=ex_scale)
-                    continue    # `st` still holds the last healthy state
-                retries = 0
-            else:
-                m = metrics
-            st = st_out
-            if snapshot_every:
-                taken = int(m.n_snapshots)
-                if taken:
-                    snapshots.extend(list(jax.device_get(snaps[:taken])))
-            if callback is not None:
-                callback(it + T - 1, st)
-            it += T
-            if policy is not None:
-                n_healthy += 1
-                if ck is not None:
-                    from repro.checkpoint import cfg_compat
-                    meta = {"lr_scale": lr_scale, "ex_scale": ex_scale,
-                            "compat": cfg_compat(cfg)}
-                    saved = n_healthy % policy.checkpoint_every == 0
-                    if saved:
-                        ck.save(it, st, metadata=meta)
+            with _span("chunk"):
+                T = min(chunk_size, n_iter - it)
+                if T not in chunks:
+                    chunks[T] = make_chunked_step(
+                        cfg, T, schedule=schedule, n_iter=n_iter,
+                        snapshot_every=snapshot_every)
+                hp_run = _scaled_hp(hparams, lr_scale, ex_scale)
+                if policy is not None or faults.current() is not None:
+                    # the chunk program donates its input; the live `st` is
+                    # the rollback anchor, so dispatch a copy.  Scripted
+                    # faults poison the *copy*: the anchor stays clean, as it
+                    # would for a divergence that happens inside the chunk.
+                    st_in = faults.corrupt_state(_copy_state(st), it)
+                else:
+                    st_in = st
+                t0 = time.time()
+                with _span("dispatch"):
+                    st_out, snaps, metrics = chunks[T](st_in, X, hp_run)
+                alarm = None
+                if policy is not None:
+                    with _span("sync"):     # THE one host sync per chunk
+                        m = jax.device_get(metrics)
+                    alarm = monitor.observe(time.time() - t0)
                     if alarm is not None:
-                        # hang/straggler escalation: commit THIS
-                        # boundary before the next dispatch
-                        # (straggler.py's contract) so a subsequent
-                        # kill loses at most one chunk
+                        policy.log("straggler", step=it, alarm=alarm)
+                    for e in fallback.events(fb_seen):
+                        policy.log(**e)
+                    fb_seen = fallback.n_events()
+                    reason = policy.check(m)
+                    if reason is None and policy.audit_every \
+                            and (n_healthy + 1) % policy.audit_every == 0:
+                        # chunk-boundary invariant audit: catches index
+                        # corruption the finite-fraction probes are blind
+                        # to; a violation feeds the SAME rollback path
+                        with _span("audit"):
+                            aud = jax.device_get(audit_state(st_out, cfg, X))
+                        reason = policy.audit_check(aud)
+                        if reason is not None:
+                            policy.log("audit_violation", step=it,
+                                       reason=reason)
+                    if reason is not None:
+                        if retries >= policy.max_retries:
+                            policy.log("giving_up", step=it, reason=reason,
+                                       retries=retries)
+                            raise EmbeddingDiverged(it, reason, retries,
+                                                    policy.events)
+                        retries += 1
+                        lr_scale *= policy.lr_backoff
+                        ex_scale *= policy.exaggeration_backoff
+                        policy.log("rollback", step=it, reason=reason,
+                                   retry=retries, lr_scale=lr_scale,
+                                   ex_scale=ex_scale)
+                        continue    # `st` still holds the last healthy state
+                    retries = 0
+                else:
+                    m = metrics
+                st = st_out
+                if snapshot_every:
+                    with _span("snapshots"):
+                        taken = int(m.n_snapshots)
+                        if taken:
+                            snapshots.extend(
+                                list(jax.device_get(snaps[:taken])))
+                if callback is not None:
+                    callback(it + T - 1, st)
+                it += T
+                if policy is not None:
+                    n_healthy += 1
+                    if ck is not None:
+                        from repro.checkpoint import cfg_compat
+                        meta = {"lr_scale": lr_scale, "ex_scale": ex_scale,
+                                "compat": cfg_compat(cfg)}
+                        saved = n_healthy % policy.checkpoint_every == 0
                         if saved:
-                            ck.wait()       # land the in-flight write
-                        else:
-                            ck.save(it, st, metadata=meta,
-                                    blocking=True)
-                        policy.log("early_checkpoint", step=it,
-                                   alarm=alarm)
-            # scripted damage to the newest COMMITTED checkpoint (the
-            # hook waits for the in-flight write): exercises the
-            # verified-restore fallback chain on resume
-            faults.maybe_corrupt_checkpoint(it, ck)
-            # simulated kill between chunks; the ExitStack's ck.close()
-            # is the preemption grace period that lets the in-flight
-            # checkpoint write land, so the just-saved boundary is
-            # committed for resume
-            faults.maybe_preempt(it)
-            # normalise the per-chunk EMA by its saturation factor so the
-            # threshold reads in steady-state per-step displacement units
-            # whatever the chunk size (host loop parity: T=1 factor is
-            # exactly the 0.1 single-step weight)
-            if early_stop is not None or auto_rescale is not None:
-                disp = float(m.disp_ema) / (1.0 - _METRICS_DECAY ** T)
-                if early_stop is not None and disp < early_stop:
-                    break
-                if auto_rescale is not None and it < n_iter \
-                        and disp < auto_rescale:
-                    # the paper's implosion button, driven by telemetry:
-                    # the layout froze relative to its own scale --
-                    # shrink it so gradients matter again and keep going
-                    st = rescale_embedding(st)
+                            with _span("checkpoint"):
+                                ck.save(it, st, metadata=meta)
+                        if alarm is not None:
+                            # hang/straggler escalation: commit THIS
+                            # boundary before the next dispatch
+                            # (straggler.py's contract) so a subsequent
+                            # kill loses at most one chunk
+                            with _span("checkpoint"):
+                                if saved:
+                                    ck.wait()   # land the in-flight write
+                                else:
+                                    ck.save(it, st, metadata=meta,
+                                            blocking=True)
+                            policy.log("early_checkpoint", step=it,
+                                       alarm=alarm)
+                # scripted damage to the newest COMMITTED checkpoint (the
+                # hook waits for the in-flight write): exercises the
+                # verified-restore fallback chain on resume
+                faults.maybe_corrupt_checkpoint(it, ck)
+                # simulated kill between chunks; the ExitStack's ck.close()
+                # is the preemption grace period that lets the in-flight
+                # checkpoint write land, so the just-saved boundary is
+                # committed for resume
+                faults.maybe_preempt(it)
+                # normalise the per-chunk EMA by its saturation factor so the
+                # threshold reads in steady-state per-step displacement units
+                # whatever the chunk size (host loop parity: T=1 factor is
+                # exactly the 0.1 single-step weight)
+                if early_stop is not None or auto_rescale is not None:
+                    disp = float(m.disp_ema) / (1.0 - _METRICS_DECAY ** T)
+                    if early_stop is not None and disp < early_stop:
+                        break
+                    if auto_rescale is not None and it < n_iter \
+                            and disp < auto_rescale:
+                        # the paper's implosion button, driven by telemetry:
+                        # the layout froze relative to its own scale --
+                        # shrink it so gradients matter again and keep going
+                        st = rescale_embedding(st)
         if ck is not None:
             ck.wait()   # surface async write failures BEFORE returning:
             #             the final checkpoint of a run must not vanish

@@ -231,103 +231,109 @@ def fit_elastic(X, *, cfg: "funcsne.FuncSNEConfig" = None,
             stack.callback(ck.close)    # flush on every exit path
         beat(it)    # entry beat: the pod is alive before first compile
         while it < n_iter:
-            T = min(chunk_size, n_iter - it)
-            if T not in chunks:
-                chunks[T], _ = funcsne.make_distributed_step(
-                    cfg, mesh, chunk=T, schedule=schedule, n_iter=n_iter)
-            hp_run = funcsne._scaled_hp(hparams, lr_scale, ex_scale)
-            if policy is not None or faults.current() is not None:
-                # donated input: dispatch a copy, keep `st` as the
-                # rollback anchor (scripted faults poison the copy)
-                st_in = faults.corrupt_state(funcsne._copy_state(st), it)
-            else:
-                st_in = st
-            t0 = time.time()
-            st_out, _, metrics = chunks[T](st_in, Xs, hp_run)
-            alarm = None
-            if policy is not None:
-                m = jax.device_get(metrics)   # the one host sync
-                alarm = monitor.observe(time.time() - t0)
-                if alarm is not None:
-                    log("straggler", step=it, alarm=alarm)
-                for e in fallback.events(fb_seen):
-                    log(**e)
-                fb_seen = fallback.n_events()
-                reason = policy.check(m)
-                if reason is None and policy.audit_every \
-                        and (n_healthy + 1) % policy.audit_every == 0:
-                    # chunk-boundary invariant audit (index corruption
-                    # is invisible to the finite-fraction probes); the
-                    # reductions AllReduce across the mesh, so one bad
-                    # replica trips the global rollback
-                    aud = jax.device_get(
-                        funcsne.audit_state(st_out, cfg, Xs))
-                    reason = policy.audit_check(aud)
+            with funcsne._span("chunk"):
+                T = min(chunk_size, n_iter - it)
+                if T not in chunks:
+                    chunks[T], _ = funcsne.make_distributed_step(
+                        cfg, mesh, chunk=T, schedule=schedule, n_iter=n_iter)
+                hp_run = funcsne._scaled_hp(hparams, lr_scale, ex_scale)
+                if policy is not None or faults.current() is not None:
+                    # donated input: dispatch a copy, keep `st` as the
+                    # rollback anchor (scripted faults poison the copy)
+                    st_in = faults.corrupt_state(funcsne._copy_state(st), it)
+                else:
+                    st_in = st
+                t0 = time.time()
+                with funcsne._span("dispatch"):
+                    st_out, _, metrics = chunks[T](st_in, Xs, hp_run)
+                alarm = None
+                if policy is not None:
+                    with funcsne._span("sync"):     # the one host sync
+                        m = jax.device_get(metrics)
+                    alarm = monitor.observe(time.time() - t0)
+                    if alarm is not None:
+                        log("straggler", step=it, alarm=alarm)
+                    for e in fallback.events(fb_seen):
+                        log(**e)
+                    fb_seen = fallback.n_events()
+                    reason = policy.check(m)
+                    if reason is None and policy.audit_every \
+                            and (n_healthy + 1) % policy.audit_every == 0:
+                        # chunk-boundary invariant audit (index corruption
+                        # is invisible to the finite-fraction probes); the
+                        # reductions AllReduce across the mesh, so one bad
+                        # replica trips the global rollback
+                        with funcsne._span("audit"):
+                            aud = jax.device_get(
+                                funcsne.audit_state(st_out, cfg, Xs))
+                        reason = policy.audit_check(aud)
+                        if reason is not None:
+                            log("audit_violation", step=it, reason=reason)
                     if reason is not None:
-                        log("audit_violation", step=it, reason=reason)
-                if reason is not None:
-                    if retries >= policy.max_retries:
-                        log("giving_up", step=it, reason=reason,
-                            retries=retries)
-                        raise EmbeddingDiverged(it, reason, retries,
-                                                policy.events)
-                    retries += 1
-                    lr_scale *= policy.lr_backoff
-                    ex_scale *= policy.exaggeration_backoff
-                    log("rollback", step=it, reason=reason,
-                        retry=retries, lr_scale=lr_scale,
-                        ex_scale=ex_scale)
-                    beat(it)    # a retry storm is alive, not dead
-                    continue
-                retries = 0
-            st = st_out
-            it += T
-            if policy is not None:
-                n_healthy += 1
-                if ck is not None:
-                    saved = n_healthy % policy.checkpoint_every == 0
-                    if saved:
-                        save_all_hosts(it, st)
-                    if alarm is not None and not multiprocess:
-                        # hang/straggler escalation: commit this
-                        # boundary now so a kill loses at most one chunk.
-                        # Multi-process pods skip this: the alarm is
-                        # decided by ONE process's clock, and a shard
-                        # set only some processes stage never commits
-                        # (the straggler event above still logs).
+                        if retries >= policy.max_retries:
+                            log("giving_up", step=it, reason=reason,
+                                retries=retries)
+                            raise EmbeddingDiverged(it, reason, retries,
+                                                    policy.events)
+                        retries += 1
+                        lr_scale *= policy.lr_backoff
+                        ex_scale *= policy.exaggeration_backoff
+                        log("rollback", step=it, reason=reason,
+                            retry=retries, lr_scale=lr_scale,
+                            ex_scale=ex_scale)
+                        beat(it)    # a retry storm is alive, not dead
+                        continue
+                    retries = 0
+                st = st_out
+                it += T
+                if policy is not None:
+                    n_healthy += 1
+                    if ck is not None:
+                        saved = n_healthy % policy.checkpoint_every == 0
                         if saved:
-                            ck.wait()
-                        else:
-                            save_all_hosts(it, st, blocking=True)
-                        log("early_checkpoint", step=it, alarm=alarm)
-            beat(it)
-            faults.maybe_corrupt_checkpoint(it, ck)
-            faults.maybe_preempt(it)
-            try:
-                faults.maybe_host_loss(it)
-            except faults.HostLost as e:
-                if ck is None or ck.latest_step() is None:
-                    raise   # nothing committed: the run is not resumable
-                log("host_lost", step=e.step, host=e.host)
-                ck.wait()   # quiesce: the in-flight write is the truth
-                blocks = host_device_blocks(devices, n_hosts)
-                lost = blocks[e.host % n_hosts]
-                devices = [d for d in devices if d not in lost]
-                n_hosts = max(1, n_hosts - 1)
-                mesh, Xs, repl = build(devices)
-                chunks.clear()          # old programs pin the old mesh
-                # fallback-chain restore: the newest boundary may be the
-                # one the lost host's write tore -- degrade to the last
-                # verified one instead of materialising garbage
-                tree, meta = restore_chain(ck, st)
-                st = tree
-                it = int(meta["step"])
-                lr_scale = float(meta.get("lr_scale", 1.0))
-                ex_scale = float(meta.get("ex_scale", 1.0))
-                retries = 0
-                log("remesh", step=it, host_lost=e.host,
-                    n_devices=len(devices), n_hosts=n_hosts,
-                    mesh=dict(mesh.shape))
+                            with funcsne._span("checkpoint"):
+                                save_all_hosts(it, st)
+                        if alarm is not None and not multiprocess:
+                            # hang/straggler escalation: commit this
+                            # boundary now so a kill loses at most one chunk.
+                            # Multi-process pods skip this: the alarm is
+                            # decided by ONE process's clock, and a shard
+                            # set only some processes stage never commits
+                            # (the straggler event above still logs).
+                            with funcsne._span("checkpoint"):
+                                if saved:
+                                    ck.wait()
+                                else:
+                                    save_all_hosts(it, st, blocking=True)
+                            log("early_checkpoint", step=it, alarm=alarm)
+                beat(it)
+                faults.maybe_corrupt_checkpoint(it, ck)
+                faults.maybe_preempt(it)
+                try:
+                    faults.maybe_host_loss(it)
+                except faults.HostLost as e:
+                    if ck is None or ck.latest_step() is None:
+                        raise   # nothing committed: the run is not resumable
+                    log("host_lost", step=e.step, host=e.host)
+                    ck.wait()   # quiesce: the in-flight write is the truth
+                    blocks = host_device_blocks(devices, n_hosts)
+                    lost = blocks[e.host % n_hosts]
+                    devices = [d for d in devices if d not in lost]
+                    n_hosts = max(1, n_hosts - 1)
+                    mesh, Xs, repl = build(devices)
+                    chunks.clear()          # old programs pin the old mesh
+                    # fallback-chain restore: the newest boundary may be the
+                    # one the lost host's write tore -- degrade to the last
+                    # verified one instead of materialising garbage
+                    tree, meta = restore_chain(ck, st)
+                    st = tree
+                    it = int(meta["step"])
+                    lr_scale = float(meta.get("lr_scale", 1.0))
+                    ex_scale = float(meta.get("ex_scale", 1.0))
+                    retries = 0
+                    log("remesh", step=it, host_lost=e.host,
+                        n_devices=len(devices), n_hosts=n_hosts,
+                        mesh=dict(mesh.shape))
         if ck is not None:
             ck.wait()   # surface async write failures before returning
     return st

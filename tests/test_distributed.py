@@ -138,6 +138,12 @@ def test_funcsne_distributed_chunked_step_matches_sequential():
         st_c, snaps, metrics = chunk(cp(st0), Xs, hp)
         assert int(metrics.step) == T and int(metrics.n_snapshots) == 2
         assert snaps.shape[1:] == (256, 2), snaps.shape
+        # every shard draws the gates from replicated state: the mesh
+        # counts the single-device program's fires
+        _, _, m1 = funcsne.make_chunked_step(cfg, T)(
+            jax.tree.map(lambda a: jnp.array(a, copy=True), st0), Xj, hp)
+        assert (int(metrics.hd_fires), int(metrics.sigma_fires)) == \
+            (int(m1.hd_fires), int(m1.sigma_fires)) == (T, 1)
         for name in funcsne.FuncSNEState._fields:
             a = np.asarray(getattr(st_c, name))
             b = np.asarray(getattr(st_seq, name))

@@ -136,10 +136,11 @@ def test_ne_forces_scatter_compiles_for_v5e(spec, d):
     assert _tpu_kernels(c)
 
 
-def test_default_chunk_program_compiles_for_v5e(spec):
-    """The whole default ``make_chunked_step`` program (all fused flags at
-    their defaults) at n=16384, M=50, d_ld=2, with Mosaic kernels for
-    candidate-fused HD and LD refinement and for the forces."""
+@pytest.fixture(scope="module")
+def chunk_compiled(spec):
+    """The default ``make_chunked_step`` program (all fused flags
+    at their defaults) compiled for one v5e chip at n=16384, M=50,
+    d_ld=2."""
     assert CFG == funcsne.FuncSNEConfig(n_points=N, dim_hd=50,
                                         backend="pallas")
     x = jax.ShapeDtypeStruct((N, CFG.dim_hd), jnp.float32)
@@ -150,6 +151,38 @@ def test_default_chunk_program_compiles_for_v5e(spec):
     chunk = funcsne.make_chunked_step(CFG, 10,
                                       schedule=funcsne.default_schedule,
                                       n_iter=500)
-    kernels = _tpu_kernels(chunk.lower(*placed).compile())
+    return chunk.lower(*placed).compile()
+
+
+def test_default_chunk_program_compiles_for_v5e(chunk_compiled):
+    """The whole default chunk program compiles, with Mosaic kernels for
+    candidate-fused HD and LD refinement and for the forces."""
+    kernels = _tpu_kernels(chunk_compiled)
     assert kernels.count("knn_merge_cand") == 2, kernels
     assert "ne_forces_gather_pallas" in kernels, kernels
+
+
+PHASE = re.compile(r"funcsne\.(hd_refine|sigma_refresh|ld_refine|"
+                   r"forces_update)(?=/|$)")
+
+
+def test_chunk_program_ops_carry_their_phase_scope(chunk_compiled):
+    """Every Mosaic kernel of the compiled chunk program keeps exactly one
+    ``funcsne.<phase>`` scope in its ``op_name`` metadata: the HD merge
+    under ``hd_refine``, the LD merge under ``ld_refine``, the force
+    kernel under ``forces_update``; every phase owns some instruction."""
+    kernels, seen = [], set()
+    for line in chunk_compiled.as_text().splitlines():
+        m = re.search(r'op_name="([^"]*)"', line)
+        found = PHASE.findall(m.group(1)) if m else []
+        seen.update(found)
+        if 'custom_call_target="tpu_custom_call"' in line:
+            name = re.match(r"\s*(?:ROOT )?%([A-Za-z_]\w*?)(?:\.\d+)? = ",
+                            line).group(1)
+            assert len(found) == 1, line[:300]
+            kernels.append((name, found[0]))
+    assert sorted(kernels) == [("knn_merge_cand", "hd_refine"),
+                               ("knn_merge_cand", "ld_refine"),
+                               ("ne_forces_gather_pallas", "forces_update")]
+    assert seen == {"hd_refine", "sigma_refresh", "ld_refine",
+                    "forces_update"}
