@@ -6,7 +6,9 @@
 
 Phase 1 runs each Pallas kernel family of the default step through its
 ``ops`` entry point on the device and compares it with the pure-jnp
-reference (``backend="xla"``) at the shapes of phase 2.  Phase 2 runs
+reference (``backend="xla"``) at the shapes of phase 2; the edge-mode
+force kernel runs through both of its row sources, and each line names
+the one it took.  Phase 2 runs
 ``funcsne.fit`` with the default ``FuncSNEConfig`` on
 ``hierarchical_cells(n=65536, dim=50)`` into 2-D (500 iterations in
 chunks of 50, fixed seed) with kernel fallback off, then scores the HD
@@ -107,7 +109,8 @@ def kernel_parity(backend: str, n: int = N, seed: int = SEED) -> None:
     from repro.core import knn as knn_lib
     from repro.data import synthetic
     from repro.kernels.knn_merge.ops import knn_merge
-    from repro.kernels.ne_forces.ops import ne_forces_gather
+    from repro.kernels.ne_forces.kernel import ne_forces_gather_pallas
+    from repro.kernels.ne_forces.ops import ne_forces_gather, row_source
     from repro.kernels.pairwise_sqdist.ops import pairwise_sqdist_gather
 
     t_start = time.time()
@@ -189,15 +192,22 @@ def kernel_parity(backend: str, n: int = N, seed: int = SEED) -> None:
     for d in (2, 32):
         Y = jnp.asarray(rng.normal(size=(n, d)).astype(np.float32))
         kw = dict(segments=segments, emit_edges=(True, True, False))
-        got = timed(f"ne_forces_gather edge d={d}", lambda: ne_forces_gather(
-            Y, qid, nbr, coef, 1.0, backend=backend, **kw))
         want = ref(f"ne_forces_gather edge d={d}", lambda: ne_forces_gather(
             Y, qid, nbr, coef, 1.0, backend="xla", **kw))
-        for part, gs, ws in zip(("agg", "edge", "wsum"), got, want):
-            for s, (g, w) in enumerate(zip(gs, ws)):
-                if w is not None:
-                    chk.close(np, f"ne_forces_gather edge d={d} {part}[{s}]",
-                              g, w)
+        # the row source ops picks for (n, d); at d=2 also the DMA rows,
+        # through the kernel's static argument
+        runs = [(row_source(n, d), lambda: ne_forces_gather(
+            Y, qid, nbr, coef, 1.0, backend=backend, **kw))]
+        if d == 2 and backend == "pallas":
+            runs.append(("dma", lambda: ne_forces_gather_pallas(
+                Y, qid, nbr, coef, 1.0, row_source="dma", **kw)))
+        for rows, run in runs:
+            name = f"ne_forces_gather edge d={d} rows={rows}"
+            got = timed(name, run)
+            for part, gs, ws in zip(("agg", "edge", "wsum"), got, want):
+                for s, (g, w) in enumerate(zip(gs, ws)):
+                    if w is not None:
+                        chk.close(np, f"{name} {part}[{s}]", g, w)
         kw = dict(segments=segments, scatter_fused=True,
                   scatter_back=(True, True, False))
         got = timed(f"ne_forces_gather scatter d={d}",

@@ -18,8 +18,10 @@ The two NE kernels each come in two flavours: the pre-gather form takes
 already-gathered (B, C, M) / (B, K, d) operands, and the gather-fused form
 (``*_gather``) takes *indices* and DMAs only the needed rows in-kernel
 (source matrix stays in HBM/ANY, read as lane-padded rows; index slabs
-staged into SMEM by the pipeline).  ``ne_forces_gather`` additionally
-offers a scatter-fused output mode (``scatter_fused=True``): per-edge
+staged into SMEM by the pipeline).  At d <= 4, when the embedding fits
+a VMEM budget, ``ne_forces_gather``'s edge mode holds it packed in VMEM
+for the launch and reads rows on-core instead (``ops.row_source``).  It
+additionally offers a scatter-fused output mode (``scatter_fused=True``): per-edge
 forces and their symmetric reactions are index-binned in-kernel into
 per-segment (N, d) displacement fields (reference on
 ``jax.ops.segment_sum``), so the per-edge tensors never round-trip

@@ -16,9 +16,12 @@ hyperparameter changes never recompile (paper Sec. 3).
 Grid: (B/block_b,) -- one parallel sweep; K and d live fully in VMEM
 (K <= ~128 neighbours, d <= ~64 embedding dims by design).  On TPU the
 (K, d) trailing dims map to (sublane, lane); Mosaic pads d to the 128-lane
-tile.  For visualisation-scale d (2..8) the arithmetic is lane-sparse but
-the kernel stays bandwidth-bound on the (B, K, d) neighbour gather, which
-is the term that matters.
+tile, so at visualisation-scale d (2..8) the arithmetic is lane-sparse.
+The index-taking variant below is bound by neither bytes nor arithmetic
+when it fetches each neighbour row with its own HBM DMA: at d=2 a v5e
+spends ~33 ns per 512-byte row DMA, 1.9% of HBM bandwidth.  So at small
+d it reads the rows from a lane-dense packing of the whole embedding
+held in VMEM instead (``row_source='vmem'``).
 """
 from __future__ import annotations
 
@@ -31,7 +34,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.compat import tpu_compiler_params
-from repro.kernels.pairwise_sqdist.kernel import pad_lanes, smem_ids
+from repro.kernels.pairwise_sqdist.kernel import LANES, pad_lanes, smem_ids
 
 
 def _edge_wsum(delta, coef, alpha, mode: str):
@@ -125,14 +128,66 @@ def _round_up(x: int, mult: int) -> int:
 # Segment boundaries are static config, so each segment's closed-form tail
 # power is compiled straight-line -- no per-edge mode mask is evaluated.
 #
-# Mosaic DMAs only lane-aligned rows, so the embedding is read lane-padded
-# (``pad_lanes``: d -> 128, zero columns give zero deltas) and the math
-# runs on padded rows; outputs keep their true width d.  Index slabs are
-# staged into SMEM by the pipeline (O(block_b * K), never O(B)).  The b
-# loop is double-buffered: rows are processed in ``sub_b`` sub-blocks
-# through 2-slot VMEM staging with sub-block p+1's row DMAs started before
-# sub-block p is computed, so the row-gather latency hides behind the
-# tail-power math instead of preceding it.
+# Row sources (static ``row_source``, chosen by ``ops.row_source`` from
+# (N, d) alone):
+#
+# * ``'dma'``: Mosaic DMAs only lane-aligned rows, so the embedding is read
+#   lane-padded (``pad_lanes``: d -> 128, zero columns give zero deltas)
+#   with one HBM row DMA per query and per neighbour.  The b loop is
+#   double-buffered: rows are processed in ``sub_b`` sub-blocks through
+#   2-slot VMEM staging with sub-block p+1's row DMAs started before
+#   sub-block p is computed.
+# * ``'vmem'``: at small d the whole embedding fits VMEM once packed
+#   lane-dense (``pack_rows``: 128 points per row, one 128-lane tile per
+#   coordinate -- the embedding's own HBM tiling, so packing is a pad and
+#   one cheap copy), so it stays resident for the launch.  Each id costs
+#   one on-core load of its packed row (row ``id >> 7``, addressed from
+#   SMEM) into the same staging, and a one-hot select on lane
+#   ``id & 127`` moves the point's d coordinates to lanes 0..d-1
+#   (``_unpack_rows``): the math then sees exactly the rows the DMA path
+#   stages.
+#
+# Either way the math runs on lane-padded rows and outputs keep their true
+# width d.  Index slabs are staged into SMEM by the pipeline
+# (O(block_b * K), never O(B)).
+
+
+_ROW_SHIFT = LANES.bit_length() - 1     # point id -> its packed row
+
+
+def pack_rows(x):
+    """(N, d) -> (R, d*128) f32, R = N/128 rounded up to the 8-row tile.
+
+    Point i's coordinate c sits at row ``i >> 7``, lane
+    ``c*128 + (i & 127)``; padding rows are zero.  On a TPU an (N, d)
+    f32 array is stored in (d, 128)-point tiles already, so this is a
+    pad and one cheap copy (an interleaved (N*d/128, 128) packing is a
+    relayout that XLA takes ~20 s to compile at N=70,000).
+    """
+    n, d = x.shape
+    x = jnp.pad(x.astype(jnp.float32), ((0, -n % (8 * LANES)), (0, 0)))
+    return x.reshape(-1, LANES, d).transpose(0, 2, 1).reshape(-1, d * LANES)
+
+
+def packed_bytes(n: int, d: int) -> int:
+    """Bytes of :func:`pack_rows` of an (n, d) embedding."""
+    return _round_up(n, 8 * LANES) * d * 4
+
+
+def _unpack_rows(rows, lane, d: int):
+    """Packed rows (..., d*128) of points at lane ``lane`` (..., 1) of
+    each 128-lane tile -> (..., 128) with the point's d coordinates at
+    lanes 0..d-1, zeros elsewhere.  One-hot selects: every coordinate
+    comes out exactly."""
+    shape = rows.shape[:-1] + (LANES,)
+    ll = jax.lax.broadcasted_iota(jnp.int32, shape, len(shape) - 1)
+    hit = ll == lane
+    out = jnp.zeros(shape, rows.dtype)
+    for c in range(d):
+        v = jnp.sum(jnp.where(hit, rows[..., c * LANES:(c + 1) * LANES],
+                              0.0), axis=-1, keepdims=True)
+        out = jnp.where(ll == c, v, out)
+    return out
 
 
 def _dma_query_and_neighbour_rows(x_ref, qid_ref, nbr_ref, q_scr, n_scr, sem):
@@ -168,53 +223,87 @@ def _dma_query_and_neighbour_rows(x_ref, qid_ref, nbr_ref, q_scr, n_scr, sem):
     jax.lax.fori_loop(0, block_b, drain, None)
 
 
-def _ne_forces_gather_kernel(qid_ref, nbr_ref, alpha_ref, coef_ref, x_ref,
-                             *refs, segments: tuple, emit_edges: tuple,
-                             sub_b: int, d: int):
+def _ne_forces_gather_kernel(qid_ref, nbr_ref, alpha_ref, coef_ref, *refs,
+                             segments: tuple, emit_edges: tuple, sub_b: int,
+                             d: int, row_source: str):
     """qid (1, bb) SMEM; nbr (bb, K) SMEM; alpha (1,1) SMEM; coef (bb, K)
-    VMEM; x (N, dp) ANY lane-padded -> per segment s: agg (bb, d), edge
-    (bb, K_s, d) for segments with emit_edges[s], wsum (bb, 1); then
-    scratch (q_scr (2, sub_b, dp), n_scr (2, sub_b, K, dp), sem (2,))."""
+    VMEM; then by ``row_source``: 'dma': x (N, dp) ANY lane-padded;
+    'vmem': qid_v (bb, 1) and nbr_v (bb, K) VMEM ids, x (R, d*128) VMEM
+    packed (``pack_rows``) -> per segment s: agg (bb, d), edge (bb, K_s, d)
+    for segments with emit_edges[s], wsum (bb, 1); then scratch: 'dma':
+    q_scr (2, sub_b, dp), n_scr (2, sub_b, K, dp), sem (2,); 'vmem':
+    q_scr (sub_b, d*128), n_scr (sub_b, K, d*128)."""
+    vmem = row_source == "vmem"
+    if vmem:
+        qid_v_ref, nbr_v_ref, x_ref, *refs = refs
+    else:
+        x_ref, *refs = refs
     S = len(segments)
     E = sum(emit_edges)
     agg_refs = refs[:S]
     edge_refs = refs[S:S + E]
     wsum_refs = refs[S + E:2 * S + E]
-    q_scr, n_scr, sem = refs[2 * S + E:]
     block_b, K = coef_ref.shape
     n_sub = block_b // sub_b
     alpha = alpha_ref[0, 0]
 
-    def sub_copies(p, op):
-        """Start/wait the 2-slot staged row DMAs of sub-block ``p``."""
-        slot = p % 2
+    if vmem:
+        q_scr, n_scr = refs[2 * S + E:]
 
-        def row(lr, _):
-            r = p * sub_b + lr
-            op(pltpu.make_async_copy(x_ref.at[qid_ref[0, r]],
-                                     q_scr.at[slot, lr], sem.at[slot]))
-            jax.lax.fori_loop(
-                0, K, lambda k, x: (op(pltpu.make_async_copy(
-                    x_ref.at[nbr_ref[r, k]], n_scr.at[slot, lr, k],
-                    sem.at[slot])), x)[1], None)
-            return _
+        def rows(p):
+            """Load sub-block ``p``'s packed rows from the resident table
+            and unpack them to (sub_b, 128) / (sub_b, K, 128)."""
+            base = p * sub_b
 
-        jax.lax.fori_loop(0, sub_b, row, None)
+            def row(lr, _):
+                r = base + lr
+                q_scr[pl.ds(lr, 1)] = x_ref[pl.ds(qid_ref[0, r] >> _ROW_SHIFT,
+                                                  1)]
+                for k in range(K):
+                    n_scr[lr, pl.ds(k, 1)] = x_ref[
+                        pl.ds(nbr_ref[r, k] >> _ROW_SHIFT, 1)]
+                return _
 
-    sub_copies(0, lambda cp: cp.start())
+            jax.lax.fori_loop(0, sub_b, row, None)
+            q_lane = qid_v_ref[pl.ds(base, sub_b)] & (LANES - 1)
+            n_lane = nbr_v_ref[pl.ds(base, sub_b)] & (LANES - 1)
+            return (_unpack_rows(q_scr[...], q_lane, d),
+                    _unpack_rows(n_scr[...], n_lane[:, :, None], d))
+    else:
+        q_scr, n_scr, sem = refs[2 * S + E:]
+
+        def sub_copies(p, op):
+            """Start/wait the 2-slot staged row DMAs of sub-block ``p``."""
+            slot = p % 2
+
+            def row(lr, _):
+                r = p * sub_b + lr
+                op(pltpu.make_async_copy(x_ref.at[qid_ref[0, r]],
+                                         q_scr.at[slot, lr], sem.at[slot]))
+                jax.lax.fori_loop(
+                    0, K, lambda k, x: (op(pltpu.make_async_copy(
+                        x_ref.at[nbr_ref[r, k]], n_scr.at[slot, lr, k],
+                        sem.at[slot])), x)[1], None)
+                return _
+
+            jax.lax.fori_loop(0, sub_b, row, None)
+
+        sub_copies(0, lambda cp: cp.start())
+
+        def rows(p):
+            slot = p % 2
+
+            @pl.when(p + 1 < n_sub)
+            def _prefetch():                 # overlap: copy p+1, compute p
+                sub_copies(p + 1, lambda cp: cp.start())
+
+            sub_copies(p, lambda cp: cp.wait())
+            return (q_scr[slot].astype(jnp.float32),     # (sub_b, dp)
+                    n_scr[slot].astype(jnp.float32))     # (sub_b, K, dp)
 
     def body(p, _):
-        slot = p % 2
-
-        @pl.when(p + 1 < n_sub)
-        def _prefetch():                     # overlap: copy p+1, compute p
-            sub_copies(p + 1, lambda cp: cp.start())
-
-        sub_copies(p, lambda cp: cp.wait())
-
+        y, nbr = rows(p)
         base = p * sub_b
-        y = q_scr[slot].astype(jnp.float32)         # (sub_b, dp)
-        nbr = n_scr[slot].astype(jnp.float32)       # (sub_b, K, dp)
         coef = coef_ref[pl.ds(base, sub_b)].astype(jnp.float32)
 
         k0, e_i = 0, 0
@@ -241,17 +330,26 @@ def _pick_sub_b(block_b: int) -> int:
     return 8
 
 
+# scoped-VMEM limit of the gather kernel's 'vmem' row source: the resident
+# packed embedding (``ops.VMEM_ROWS_BUDGET`` at most) outgrows Mosaic's
+# 16 MiB default; a TPU v5e core has 128 MiB of VMEM
+VMEM_ROWS_LIMIT = 64 * 2 ** 20
+
+
 @functools.partial(
     jax.jit, static_argnames=("segments", "emit_edges", "block_b", "sub_b",
-                              "interpret"))
+                              "row_source", "interpret"))
 def ne_forces_gather_pallas(x, qid, nbr_idx, coef, alpha, *,
                             segments: tuple, emit_edges: tuple = None,
                             block_b: int = 128, sub_b: int = None,
+                            row_source: str = "dma",
                             interpret: bool = False):
     """Index-taking segmented force kernel.
 
     Args:
-      x: (N, d) embedding, kept in HBM/ANY memory space.
+      x: (N, d) embedding; read in HBM/ANY memory space one row DMA at a
+        time (``row_source='dma'``), or packed and held in VMEM for the
+        launch (``'vmem'``, see the block comment above).
       qid: (B,) int32 row ids of the points the forces act on.
       nbr_idx: (B, K) int32 neighbour ids, K = sum of segment sizes;
         clipped to [0, N) (callers zero invalid slots via ``coef``).
@@ -265,6 +363,7 @@ def ne_forces_gather_pallas(x, qid, nbr_idx, coef, alpha, *,
         whose symmetric contribution is never scattered).
       sub_b: double-buffer sub-block size (must divide ``block_b``);
         default: 8-row sub-blocks for blocks > 16 rows.
+      row_source: static 'dma' or 'vmem'; both give the same results.
     Returns (one entry per segment -- no packed buffers, so consumers
     never pay a concat/re-slice round-trip):
       aggs: tuple of (B, d) per-point aggregate forces,
@@ -274,7 +373,9 @@ def ne_forces_gather_pallas(x, qid, nbr_idx, coef, alpha, *,
       wsums: tuple of (B,) w partial sums (Z-hat estimator terms).
     """
     N, d = x.shape
-    x = pad_lanes(x)
+    assert row_source in ("dma", "vmem"), row_source
+    vmem = row_source == "vmem"
+    x = pack_rows(x) if vmem else pad_lanes(x)
     dp = x.shape[1]
     B, K = nbr_idx.shape
     S = len(segments)
@@ -306,14 +407,33 @@ def ne_forces_gather_pallas(x, qid, nbr_idx, coef, alpha, *,
         coef = jnp.pad(coef, ((0, Bp - B), (0, 0)))
     alpha_arr = jnp.asarray(alpha, jnp.float32).reshape(1, 1)
 
+    if vmem:
+        # ids again as VMEM blocks (lane offsets for the unpack); the
+        # packed table is one block fetched once and held for the launch
+        row_args = (qid.reshape(-1, 1), nbr_idx, x)
+        row_specs = [pl.BlockSpec((block_b, 1), lambda i: (i, 0)),
+                     pl.BlockSpec((block_b, K), lambda i: (i, 0)),
+                     pl.BlockSpec(x.shape, lambda i: (0, 0),
+                                  pipeline_mode=pl.Buffered(1))]
+        scratch = [pltpu.VMEM((sub_b, dp), x.dtype),
+                   pltpu.VMEM((sub_b, K, dp), x.dtype)]
+        limit = {"vmem_limit_bytes": VMEM_ROWS_LIMIT}
+    else:
+        row_args = (x,)
+        row_specs = [pl.BlockSpec(memory_space=pl.ANY)]
+        scratch = [pltpu.VMEM((2, sub_b, dp), x.dtype),
+                   pltpu.VMEM((2, sub_b, K, dp), x.dtype),
+                   pltpu.SemaphoreType.DMA((2,))]
+        limit = {}
     qid, qid_spec = smem_ids(qid, block_b)
     grid = (Bp // block_b,)
     emitted_sizes = [size for (_, size), em in zip(segments, emit_edges)
                      if em]
     E = len(emitted_sizes)
-    outs = pl.pallas_call(
+    call = pl.pallas_call(
         functools.partial(_ne_forces_gather_kernel, segments=segments,
-                          emit_edges=emit_edges, sub_b=sub_b, d=d),
+                          emit_edges=emit_edges, sub_b=sub_b, d=d,
+                          row_source=row_source),
         grid=grid,
         in_specs=[
             qid_spec,
@@ -322,8 +442,7 @@ def ne_forces_gather_pallas(x, qid, nbr_idx, coef, alpha, *,
             pl.BlockSpec((1, 1), lambda i: (0, 0),
                          memory_space=pltpu.SMEM),
             pl.BlockSpec((block_b, K), lambda i: (i, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
+        ] + row_specs,
         out_specs=(
             [pl.BlockSpec((block_b, d), lambda i: (i, 0))] * S
             + [pl.BlockSpec((block_b, size, d), lambda i: (i, 0, 0))
@@ -336,18 +455,14 @@ def ne_forces_gather_pallas(x, qid, nbr_idx, coef, alpha, *,
                for size in emitted_sizes]
             + [jax.ShapeDtypeStruct((Bp, 1), jnp.float32)] * S
         ),
-        scratch_shapes=[
-            pltpu.VMEM((2, sub_b, dp), x.dtype),
-            pltpu.VMEM((2, sub_b, K, dp), x.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
+        scratch_shapes=scratch,
         # one independent row block per grid step: Mosaic may split the
-        # sweep across TensorCores (each core double-buffers its own
-        # scratch slots)
+        # sweep across TensorCores (each core keeps its own scratch)
         compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel",)),
+            dimension_semantics=("parallel",), **limit),
         interpret=interpret,
-    )(qid, nbr_idx, alpha_arr, coef, x)
+    )
+    outs = call(qid, nbr_idx, alpha_arr, coef, *row_args)
     aggs = tuple(o[:B] for o in outs[:S])
     edge_iter = iter(outs[S:S + E])
     edges = tuple(next(edge_iter)[:B] if em else None for em in emit_edges)

@@ -266,6 +266,60 @@ def test_ne_forces_gather_emit_edges_skips_output():
                                        rtol=1e-6)
 
 
+@pytest.mark.parametrize("d", [1, 2, 3, 4])
+@pytest.mark.parametrize("n,b,bb", [(1100, 37, 16),   # N % 128, B % bb
+                                    (70, 64, 32)])    # one packed row
+def test_ne_forces_gather_vmem_rows_parity(d, n, b, bb):
+    """The resident packed-table row source matches the reference, and the
+    DMA row source bit for bit, on the step's three segments with the
+    negatives' edges elided: repeated ids, ids past both ends and
+    SENTINEL (all clipped), N and B off every tile."""
+    from repro.core.knn import SENTINEL
+    segments = (("attraction", 5), ("repulsion", 4), ("repulsion", 3))
+    emit = (True, True, False)
+    rng = np.random.default_rng(n + 10 * d)
+    x = jnp.asarray(rng.normal(size=(n, d)).astype(np.float32))
+    qid = rng.integers(0, n, b).astype(np.int32)
+    qid[1] = qid[0]
+    nbr = rng.integers(-2, n + 2, (b, 12)).astype(np.int32)
+    nbr[:, 1] = nbr[:, 0]
+    nbr[0, 3:6] = SENTINEL
+    nbr[2, :] = n - 1
+    coef = jnp.asarray(rng.random((b, 12)).astype(np.float32))
+    args = (x, jnp.asarray(qid), jnp.asarray(nbr), coef, 0.8)
+    kw = dict(segments=segments, emit_edges=emit, block_b=bb,
+              interpret=True)
+    got = ne_forces_gather_pallas(*args, row_source="vmem", **kw)
+    dma = ne_forces_gather_pallas(*args, row_source="dma", **kw)
+    want = ne_forces_gather_ref(*args, segments=segments, emit_edges=emit)
+    for gs, ds, ws, name in zip(got, dma, want, ("agg", "edge", "wsum")):
+        for s, (g, dd, w) in enumerate(zip(gs, ds, ws)):
+            if w is None:
+                assert g is None and dd is None
+                continue
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                       rtol=2e-5, atol=2e-5,
+                                       err_msg=f"{name}[{s}]")
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(dd),
+                                          err_msg=f"{name}[{s}] vs dma")
+
+
+@pytest.mark.parametrize("n,d,want", [
+    (262144, 2, "vmem"),            # atlas-262k
+    (70000, 2, "vmem"),             # mnist-70k
+    (2 ** 21, 2, "vmem"),           # the budget's d=2 edge
+    (2 ** 21 + 1, 2, "dma"),        # one tile over it
+    (2 ** 20, 4, "vmem"),
+    (2 ** 20 + 1024, 4, "dma"),
+    (16384, 8, "dma"),              # wider than the packing takes
+    (16384, 32, "dma"),             # backbone features
+    (2 ** 20, 32, "dma"),
+])
+def test_ne_forces_row_source(n, d, want):
+    from repro.kernels.ne_forces.ops import row_source
+    assert row_source(n, d) == want
+
+
 def test_ne_forces_action_reaction():
     """Aggregated force equals the sum of edge forces (Newton pairs)."""
     rng = np.random.default_rng(3)

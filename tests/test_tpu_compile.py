@@ -19,7 +19,7 @@ from repro.kernels.knn_merge.kernel import (knn_merge_cand_pallas,
                                             knn_merge_pallas)
 from repro.kernels.ne_forces.kernel import (ne_forces_gather_pallas,
                                             ne_forces_scatter_pallas)
-from repro.kernels.ne_forces.ops import scatter_chunk_plan
+from repro.kernels.ne_forces.ops import row_source, scatter_chunk_plan
 from repro.kernels.pairwise_sqdist.kernel import pairwise_sqdist_gather_pallas
 
 N = 16384
@@ -125,6 +125,19 @@ def test_ne_forces_gather_compiles_for_v5e(spec, d):
     assert _tpu_kernels(c)
 
 
+@pytest.mark.parametrize("n", [262144, 70000])
+def test_ne_forces_gather_vmem_rows_compiles_for_v5e(spec, n):
+    """The resident packed-table row source at the benchmark cells' sizes
+    (d=2), the packing included: one Mosaic launch, under its name."""
+    assert row_source(n, 2) == "vmem"
+    c = _compile(lambda y, q, nb, cf, a: ne_forces_gather_pallas(
+        y, q, nb, cf, a, segments=SEGMENTS, emit_edges=(True, True, False),
+        row_source="vmem"),
+        spec((n, 2)), _i32(spec, n), _i32(spec, n, K_ALL),
+        spec((n, K_ALL)), spec(()))
+    assert _tpu_kernels(c) == ["ne_forces_gather_pallas"]
+
+
 @pytest.mark.parametrize("d", [2, 32])
 def test_ne_forces_scatter_compiles_for_v5e(spec, d):
     chunk_n = scatter_chunk_plan(4 * N, d, len(SEGMENTS))
@@ -160,6 +173,23 @@ def test_default_chunk_program_compiles_for_v5e(chunk_compiled):
     kernels = _tpu_kernels(chunk_compiled)
     assert kernels.count("knn_merge_cand") == 2, kernels
     assert "ne_forces_gather_pallas" in kernels, kernels
+
+
+def test_chunk_program_force_kernel_reads_vmem_rows(chunk_compiled):
+    """At d=2 the step's one force launch takes the resident row source:
+    exactly one ``ne_forces_gather*`` kernel, named
+    ``ne_forces_gather_pallas``, traced under ``ne_forces.vmem_rows``."""
+    kernels = _tpu_kernels(chunk_compiled)
+    forces = [k for k in kernels if k.startswith("ne_forces_gather")]
+    assert forces == ["ne_forces_gather_pallas"], kernels
+    assert sorted(kernels) == ["knn_merge_cand", "knn_merge_cand",
+                               "ne_forces_gather_pallas"], kernels
+    scopes = [re.search(r'op_name="([^"]*)"', line).group(1)
+              for line in chunk_compiled.as_text().splitlines()
+              if re.match(r"\s*(?:ROOT )?%ne_forces_gather_pallas(?:\.\d+)? = ",
+                          line)]
+    assert len(scopes) == 1, scopes
+    assert "/ne_forces.vmem_rows/" in scopes[0], scopes
 
 
 PHASE = re.compile(r"funcsne\.(hd_refine|sigma_refresh|ld_refine|"
