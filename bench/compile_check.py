@@ -4,9 +4,10 @@
   JAX_PLATFORMS=cpu python bench/compile_check.py [--workload <cell> ...]
 
 No chip is needed: the TPU compiler compiles for a described
-``v5e:2x2`` topology, one chip of it.  For every program the window (and
-its set-up) runs, this prints the Mosaic kernels in it and
-``memory_analysis()``: argument, output and temporary bytes on the
+``v5e:2x2`` topology, one chip of it, or as many as a ``mesh_chunked``
+cell asks for.  For every program the window (and its set-up) runs,
+this prints the Mosaic kernels and the collectives in it and
+``memory_analysis()``: argument, output and temporary bytes on each
 chip.  A program that does not fit, or a kernel Mosaic refuses, fails
 here as it would on the chip.  Kernels are compiled as the chip runs
 them (``backend="pallas"``; on a TPU ``"auto"`` resolves to it).
@@ -25,11 +26,18 @@ sys.path.insert(0, str(ROOT))
 sys.path.insert(0, str(ROOT / "src"))
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
+from bench import trace  # noqa: E402
+
 
 def kernels(compiled) -> list:
     return sorted(set(re.findall(
         r"%([A-Za-z_]\w*?)(?:\.\d+)? = [^\n]*"
         r'custom_call_target="tpu_custom_call"', compiled.as_text())))
+
+
+def collectives(compiled) -> list:
+    ops = {trace.opcode(line) for line in compiled.as_text().splitlines()}
+    return sorted(op for op in ops if trace.COLLECTIVE.match(op))
 
 
 def memory(compiled) -> dict:
@@ -39,9 +47,12 @@ def memory(compiled) -> dict:
              "temp_size_in_bytes", "generated_code_size_in_bytes")}
 
 
-def programs(jax, funcsne, spec: dict, one_chip):
-    """(name, jitted fn, abstract args) of each program of the cell."""
+def programs(jax, funcsne, spec: dict, devices):
+    """(name, jitted fn, abstract args) of each program of the cell, on
+    the first of ``devices`` or, for a mesh, on as many as it asks for."""
     import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
     from bench import generator
     from bench.reference import knn as knn_ref
 
@@ -50,9 +61,9 @@ def programs(jax, funcsne, spec: dict, one_chip):
     cfg = funcsne.FuncSNEConfig(n_points=c["n"], dim_hd=c["dim_hd"],
                                 dim_ld=c["dim_ld"], **fs)
 
-    def place(tree):
+    def place(tree, sharding=SingleDeviceSharding(devices[0])):
         return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
-            s.shape, s.dtype, sharding=one_chip), tree)
+            s.shape, s.dtype, sharding=sharding), tree)
 
     key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
     x = jax.ShapeDtypeStruct((c["n"], c["dim_hd"]), jnp.float32)
@@ -62,6 +73,15 @@ def programs(jax, funcsne, spec: dict, one_chip):
     hp = jax.eval_shape(lambda: funcsne.HParams(**{
         k: jnp.float32(v) for k, v in generator.base_hparams(c["n"],
                                                            {}).items()}))
+    if tr["kind"] == "mesh_chunked":
+        prog, init, x_sharding, st_sharding = generator.mesh_program(
+            jax, funcsne, cfg, tr, list(devices),
+            int(spec["cell"]["chips"]))
+        return [("init_state", init,
+                 (place(key, st_sharding), place(x, x_sharding))),
+                ("make_distributed_step", prog,
+                 (place(st, st_sharding), place(x, x_sharding),
+                  place(hp, st_sharding)))]
     out = [("init_state", init, place((key, x)))]
     if tr["kind"] == "chunked":
         prog = funcsne.make_chunked_step(
@@ -92,7 +112,6 @@ def main(argv=None) -> int:
 
     import jax
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
 
     from repro.core import funcsne
 
@@ -100,13 +119,14 @@ def main(argv=None) -> int:
     jax.config.update("jax_enable_compilation_cache", False)
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
-    one_chip = SingleDeviceSharding(topo.devices[0])
     report = {}
     for name in names:
         spec = common.find_cell(name)
-        for prog_name, fn, args_ in programs(jax, funcsne, spec, one_chip):
+        for prog_name, fn, args_ in programs(jax, funcsne, spec,
+                                             topo.devices):
             compiled = fn.lower(*args_).compile()
-            row = {"kernels": kernels(compiled), **memory(compiled)}
+            row = {"kernels": kernels(compiled),
+                   "collectives": collectives(compiled), **memory(compiled)}
             report[f"{name}/{prog_name}"] = row
             print(f"{name}/{prog_name}: {json.dumps(row)}", flush=True)
     print(json.dumps(report))

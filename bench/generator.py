@@ -16,6 +16,11 @@ below and gives its parameters; nothing else about a mix lives in code.
   phases in the file's order, ``frames_per_phase`` frames each, and
   cycles.  (A drag order drawn from the seed changed how soon recall
   rises by up to a third: every seed gets the same drags.)
+- ``mesh_chunked``: ``chunked`` on a mesh of the cell's ``chips``, as
+  ``runtime/coordinator.fit_elastic`` drives it: the mesh from
+  ``elastic.remesh`` at the mix's ``model`` width, X sharded by
+  features over ``model``, the state replicated, and the program
+  ``make_distributed_step`` (see :func:`mesh_program`).
 
 The seed draws the data.  A mix with ``program_seed`` gives the
 program's own random stream (initial embedding, HD refinement gate,
@@ -341,7 +346,59 @@ class Frames(Cell):
         return hp
 
 
-KINDS = {"chunked": Chunked, "frames": Frames}
+def mesh_program(jax, funcsne, cfg, traffic: dict, devices, chips: int):
+    """(step program, init program, X sharding, state sharding) of a
+    ``mesh_chunked`` mix on the first ``chips`` of ``devices``: the mesh,
+    X's placement and the step as ``fit_elastic`` builds them.  The init
+    is ``init_state`` as one program in which every device computes the
+    whole state from the whole X (a Mosaic kernel is not partitioned but
+    under ``shard_map``), so its outputs are replicated.  A mesh of fewer
+    than ``chips`` devices is an error: a cell never runs on fewer chips
+    than it asks for."""
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+    from repro.runtime import elastic
+    mesh = elastic.remesh(chips, model=int(traffic["model"]),
+                          devices=devices[:chips], divides=(cfg.dim_hd,))
+    if mesh.devices.size != chips:
+        raise RuntimeError(f"the mesh {dict(mesh.shape)} uses "
+                           f"{mesh.devices.size} of the {chips} chips the "
+                           f"cell asks for")
+    prog, _ = funcsne.make_distributed_step(
+        cfg, mesh, chunk=int(traffic["iters_per_dispatch"]),
+        schedule=funcsne.default_schedule, n_iter=int(traffic["n_iter"]))
+    init = jax.jit(jax.shard_map(
+        lambda key, X: funcsne.init_state(key, X, cfg, validate=False),
+        mesh=mesh, in_specs=(P(), P()), out_specs=P(), check_vma=False))
+    return prog, init, NamedSharding(mesh, P(None, "model")), \
+        NamedSharding(mesh, P())
+
+
+class MeshChunked(Chunked):
+    """The ``chunked`` window over :func:`mesh_program` on the first
+    ``chips`` devices; :meth:`Cell.init` runs the mesh's init program."""
+
+    def __init__(self, jax, funcsne, spec: dict, seed: int, annotate):
+        super().__init__(jax, funcsne, spec, seed, annotate)
+        self.chips = int(spec["cell"]["chips"])
+
+    def setup(self):
+        jax = self.jax
+        tr = self.traffic
+        self.T = int(tr["iters_per_dispatch"])
+        self.n_iter = int(tr["n_iter"])
+        self.hp = base_hparams(self.n, tr.get("hparams", {}))
+        self.prog, self._init, x_sharding, _ = mesh_program(
+            jax, self.funcsne, self.cfg, tr, jax.devices(), self.chips)
+        self.X = jax.device_put(self.X, x_sharding)
+        # warm: the init program and one dispatch of the chunk
+        st = self.init()
+        st, _, m = self.prog(st, self.X, self.hp_device(self.hp))
+        jax.block_until_ready((st, m))
+        del st, m
+
+
+KINDS = {"chunked": Chunked, "frames": Frames, "mesh_chunked": MeshChunked}
 
 
 def make(jax, funcsne, spec: dict, seed: int, annotate) -> Cell:

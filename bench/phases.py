@@ -37,8 +37,6 @@ from bench import trace as trace_lib
 PHASES = ("hd_refine", "sigma_refresh", "ld_refine", "forces_update")
 SCOPE = re.compile(r"(?:^|/)funcsne\.(" + "|".join(PHASES) + r")(?=/|$)")
 OP_NAME = re.compile(r'op_name="([^"]*)"')
-INSTRUCTION = re.compile(
-    r"^\s*(?:ROOT\s+)?%([^\s=]+) = (.*?) ([a-z][\w-]*)\(")
 LAYOUT = re.compile(r"\{[^{}]*\}")
 
 
@@ -53,7 +51,7 @@ def phase_of(text: str):
 def signature(text: str):
     """(name, result shape without layouts, opcode) of one instruction's
     text, or None when it is not an instruction."""
-    m = INSTRUCTION.match(text)
+    m = trace_lib.INSTRUCTION.match(text)
     return (m.group(1), LAYOUT.sub("", m.group(2)), m.group(3)) if m \
         else None
 
@@ -70,28 +68,37 @@ def phase_map(hlo_text: str) -> dict:
 
 
 @functools.lru_cache(maxsize=4)
-def window_program(config_json: str, traffic: str) -> dict:
+def window_program(config_json: str, traffic: str, chips: int = 1) -> dict:
     """:func:`phase_map` of the step program the window of a cell with
-    this configuration and traffic mix runs, as ``bench/generator.py``
-    builds it."""
+    this configuration, traffic mix and number of chips runs, as
+    ``bench/generator.py`` builds it.  A mesh program's text is one
+    device's part of it, as each device's trace events name it."""
     import jax
     import jax.numpy as jnp
     from repro.core import funcsne
+
+    from bench import generator
     c = json.loads(config_json)
     tr = common.load_json(common.BENCH / "traffic" / f"{traffic}.json")
     cfg = funcsne.FuncSNEConfig(n_points=c["n"], dim_hd=c["dim_hd"],
                                 dim_ld=c["dim_ld"], **c["funcsne"])
-    if tr["kind"] == "chunked":
-        prog = funcsne.make_chunked_step(
-            cfg, int(tr["iters_per_dispatch"]),
-            schedule=funcsne.default_schedule, n_iter=int(tr["n_iter"]))
-    else:
-        prog = funcsne.make_step(cfg)
     X = jax.ShapeDtypeStruct((c["n"], c["dim_hd"]), jnp.float32)
     st = jax.eval_shape(lambda k, X: funcsne.init_state(
         k, X, cfg, validate=False), jax.random.PRNGKey(0), X)
     hp = funcsne.HParams(*[jax.ShapeDtypeStruct((), jnp.float32)]
                          * len(funcsne.HParams._fields))
+    if tr["kind"] == "chunked":
+        prog = funcsne.make_chunked_step(
+            cfg, int(tr["iters_per_dispatch"]),
+            schedule=funcsne.default_schedule, n_iter=int(tr["n_iter"]))
+    elif tr["kind"] == "mesh_chunked":
+        prog, _, x_sharding, st_sharding = generator.mesh_program(
+            jax, funcsne, cfg, tr, jax.devices(), chips)
+        X = jax.ShapeDtypeStruct(X.shape, X.dtype, sharding=x_sharding)
+        st = jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=st_sharding), st)
+    else:
+        prog = funcsne.make_step(cfg)
     return phase_map(prog.lower(st, X, hp).compile().as_text())
 
 
@@ -117,7 +124,7 @@ def run_events(run, traffic: str, holes=()) -> list:
     if not any(e > lo and s < hi for _, s, e in run.trace.ops.get(0, [])):
         return []
     program = window_program(json.dumps(run.config, sort_keys=True),
-                             traffic)
+                             traffic, run.chips)
     return events(run.trace, program, holes=holes)
 
 

@@ -69,6 +69,7 @@ class Run:
 
     def __init__(self, spec, trace, summary, result, peaks):
         self.config = spec["config"]
+        self.chips = int(spec["cell"]["chips"])
         self.trace, self.summary = trace, summary
         self.iterations = result["iterations"]
         self.peaks = peaks

@@ -137,6 +137,7 @@ READERS = {"hd_refine_ms_per_fire.batch": 7.5,
 
 class _Run:
     config = {"n": 8}
+    chips = 1
 
     def __init__(self, trace, iterations=3):
         self.trace, self.iterations = trace, iterations
@@ -151,7 +152,7 @@ def program(monkeypatch):
     """The window program the readers compile, replaced by the steps'."""
     asked, table = [], {"program": _program()}
 
-    def window_program(config, traffic):
+    def window_program(config, traffic, chips):
         asked.append(traffic)
         return table["program"]
     monkeypatch.setattr(phases, "window_program", window_program)

@@ -97,3 +97,69 @@ def test_roofline_share_reports_its_bound():
     assert r == {"value": pytest.approx(50.0), "bound": "ops"}
     r = trace_lib.roofline_share(2.0, ops=1.0, nbytes=10.0, peaks=peaks)
     assert r == {"value": pytest.approx(50.0), "bound": "bytes"}
+
+
+def _collective(name, op):
+    """A collective's op event named as a TPU trace names it."""
+    return f"%{name} = f32[8]{{0}} {op}(f32[8]{{0}} %a), " \
+        "replica_groups={{0,1,2,3}}"
+
+
+def test_collectives_pair_async_halves_and_merge_overlaps():
+    # window [0, 100) ms on device 0: an all-gather in flight [10, 30)
+    # (start [10, 11), done [28, 30)) overlapping another [20, 40); a
+    # sync all-reduce named after the psum it lowers [50, 55) overlapping
+    # a reduce-scatter [52, 60); a fusion, which is no collective
+    ops = {0: [(_collective("all-gather-start.1", "all-gather-start"),
+                10 * MS, 11 * MS),
+               (_collective("all-gather-start.2", "all-gather-start"),
+                20 * MS, 21 * MS),
+               (_collective("all-gather-done.2", "all-gather-done"),
+                38 * MS, 40 * MS),
+               (_collective("all-gather-done.1", "all-gather-done"),
+                28 * MS, 30 * MS),
+               (_collective("psum.22", "all-reduce"), 50 * MS, 55 * MS),
+               (_collective("reduce-scatter.3", "reduce-scatter"),
+                52 * MS, 60 * MS),
+               ("fusion.3", 60 * MS, 70 * MS)]}
+    tr = trace_lib.Trace(ops, [("bench.window", 0, 100 * MS)])
+    count, iv = tr.collectives(0)
+    assert count == 4
+    assert iv == [[10 * MS, 40 * MS], [50 * MS, 60 * MS]]
+
+
+def test_collectives_pair_bare_names_in_order():
+    # names without instruction text: a done pairs with the oldest open
+    # start of its kind; a start with no done counts alone
+    ops = {0: [("collective-permute-start.1", 0, 1 * MS),
+               ("collective-permute-start.2", 2 * MS, 3 * MS),
+               ("collective-permute-done.1", 5 * MS, 6 * MS),
+               ("all-to-all.4", 7 * MS, 8 * MS)]}
+    tr = trace_lib.Trace(ops, [("bench.window", 0, 100 * MS)])
+    count, iv = tr.collectives(0)
+    assert count == 3
+    assert iv == [[0, 6 * MS], [7 * MS, 8 * MS]]
+
+
+def test_collectives_keep_to_their_device_and_window():
+    ops = {0: [(_collective("all-reduce.1", "all-reduce"), 90 * MS,
+                110 * MS),
+               (_collective("all-reduce.2", "all-reduce"), 120 * MS,
+                130 * MS),
+               (_collective("all-gather-start.3", "all-gather-start"),
+                -20 * MS, -19 * MS),
+               (_collective("all-gather-done.3", "all-gather-done"),
+                4 * MS, 5 * MS)],
+           1: [(_collective("all-reduce.1", "all-reduce"), 10 * MS,
+                20 * MS)]}
+    tr = trace_lib.Trace(ops, [("bench.window", 0, 100 * MS)])
+    # device 0: the all-reduce across the window's end counts up to it,
+    # the one after the window not at all, and the all-gather from
+    # before the window from its start
+    count, iv = tr.collectives(0)
+    assert count == 2
+    assert iv == [[0, 5 * MS], [90 * MS, 100 * MS]]
+    assert tr.collectives(1) == (1, [[10 * MS, 20 * MS]])
+    assert tr.collectives(2) == (0, [])
+    # busy time counts collectives as any op: 11 ms and 10 ms
+    assert tr.summary()["busy_s"] == pytest.approx((0.011 + 0.010) / 2)

@@ -17,6 +17,7 @@ event's name is the whole HLO instruction text,
 """
 from __future__ import annotations
 
+import collections
 import re
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
@@ -24,6 +25,11 @@ OPS_LINE = "XLA Ops"
 SPAN_PREFIX = "bench."
 TOP = 10
 KERNEL_TARGET = 'custom_call_target="tpu_custom_call"'
+# one HLO instruction's text: name, result shape, opcode
+INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT\s+)?%([^\s=]+) = (.*?) ([a-z][\w-]*)\(")
+COLLECTIVE = re.compile(r"^(all-reduce|all-gather|reduce-scatter|"
+                        r"collective-permute|all-to-all)(-start|-done)?$")
 
 
 def op_name(event_name: str) -> str:
@@ -35,6 +41,13 @@ def op_name(event_name: str) -> str:
 def base_name(event_name: str) -> str:
     """Instruction name without its ``.N`` uniquifier."""
     return re.sub(r"\.\d+$", "", op_name(event_name))
+
+
+def opcode(event_name: str) -> str:
+    """The HLO opcode of an op event named by its instruction's text
+    (``all-gather-start``), else its base name."""
+    m = INSTRUCTION.match(event_name)
+    return m.group(3) if m else base_name(event_name)
 
 
 def union(intervals, lo=None, hi=None) -> list:
@@ -137,6 +150,30 @@ class Trace:
         evs = [(s, e) for n, s, e in self.ops.get(device, [])
                if base_name(n).startswith(prefix) and e > lo and s < hi]
         return len(evs), union(evs, lo, hi)
+
+    def collectives(self, device) -> tuple:
+        """(count, merged intervals) of the collective ops in the window,
+        found by opcode.  An asynchronous one spans its ``-start`` event,
+        its ``-done`` event and the time between, while the transfer is
+        in flight: a ``-done`` closes the oldest open ``-start`` of its
+        kind, and either half without the other counts alone."""
+        lo, hi = self.window()
+        spans, open_ = [], collections.defaultdict(collections.deque)
+        for n, s, e in sorted(self.ops.get(device, []),
+                              key=lambda ev: ev[1]):
+            m = COLLECTIVE.match(opcode(n))
+            if not m:
+                continue
+            kind, half = m.groups()
+            if half == "-start":
+                open_[kind].append((s, e))
+                continue
+            if half == "-done" and open_[kind]:
+                s = open_[kind].popleft()[0]
+            spans.append((s, e))
+        spans += [iv for starts in open_.values() for iv in starts]
+        spans = [(s, e) for s, e in spans if e > lo and s < hi]
+        return len(spans), union(spans, lo, hi)
 
     def summary(self) -> dict:
         """Window length, busy seconds (mean over devices), idle share,
