@@ -41,8 +41,16 @@ drag phase's) and copies each result (``post``).  The numbers compared:
 
 ``control_posts`` puts the reference, computed in bfloat16, in the
 program's place: the check must refuse it.
+
+The reference works on blocks of rows on a pool of threads
+(``reference/step.py``); every reduction over rows is one call over all
+of them, so the numbers do not depend on the blocks or the pool.
+``readings`` adds the wall time of each of its parts to ``seconds``.
 """
 from __future__ import annotations
+
+import contextlib
+import time
 
 import numpy as np
 
@@ -60,6 +68,16 @@ UPDATE_ULPS = 4.0
 WARM_START = 12.0
 
 
+@contextlib.contextmanager
+def timed(seconds: dict, part: str):
+    """Add the wall time of the ``with`` block to ``seconds[part]``."""
+    t = time.perf_counter()
+    try:
+        yield
+    finally:
+        seconds[part] = seconds.get(part, 0.0) + time.perf_counter() - t
+
+
 def host_state(jax, st, fields) -> dict:
     """Copy the named fields of a FuncSNEState (plus its raw key) to the
     host."""
@@ -72,21 +90,28 @@ def host_state(jax, st, fields) -> dict:
 
 
 def _list_faults(idx, n) -> int:
-    bad = (idx < 0) | (idx >= n)
-    bad |= idx == np.arange(idx.shape[0])[:, None]
-    s = np.sort(idx, axis=1)
-    dup = np.zeros(idx.shape, bool)
-    dup[:, 1:] = s[:, 1:] == s[:, :-1]
-    return int(bad.sum() + dup.sum())
+    def block(s, e):
+        rows = idx[s:e]
+        bad = (rows < 0) | (rows >= n) | (rows == np.arange(s, e)[:, None])
+        srt = np.sort(rows, axis=1)
+        return int(bad.sum() + np.sum(srt[:, 1:] == srt[:, :-1]))
+
+    return sum(step_ref.by_rows(block, idx.shape[0]))
 
 
 def _rel_gap(got, ref, mag=None) -> float:
     """max |got - ref| / max(mag, median mag); ``mag`` defaults to the
     reference itself."""
     mag = np.abs(ref) if mag is None else mag
-    scale = np.maximum(mag, np.median(mag))
-    scale = np.where(scale > 0, scale, 1.0)
-    gap = float(np.max(np.abs(np.asarray(got, np.float64) - ref) / scale))
+    median = np.median(mag)
+
+    def block(s, e):
+        scale = np.maximum(mag[s:e], median)
+        scale = np.where(scale > 0, scale, 1.0)
+        return np.max(np.abs(np.asarray(got[s:e], np.float64) - ref[s:e])
+                      / scale)
+
+    gap = float(np.max(step_ref.by_rows(block, len(ref))))
     return gap if np.isfinite(gap) else float("inf")
 
 
@@ -104,18 +129,24 @@ class Reference:
     """What the reference derives once from ``pre`` and the first step,
     shared by every phase's step and by the control."""
 
-    def __init__(self, pre: dict, post0: dict, X, fs: dict):
+    def __init__(self, pre: dict, post0: dict, X, fs: dict,
+                 seconds: dict | None = None):
         self.pre, self.fs = pre, fs
+        seconds = {} if seconds is None else seconds
         self.X = np.asarray(X, np.float32)
         n = self.X.shape[0]
         r64 = step_ref.rounder("float64")
-        self.hd_union = lists_ref.hd_union(pre, fs)
-        self.d_hd_union = lists_ref.union_sqdist(self.X, self.hd_union, r64)
-        self.ld_union = lists_ref.ld_union(pre, post0, fs)
-        self.d_ld_union = lists_ref.union_sqdist(pre["Y"], self.ld_union,
-                                                 r64)
-        self.hd_d = step_ref.sqdist(
-            self.X, np.clip(post0["hd_idx"].astype(np.int64), 0, n - 1), r64)
+        with timed(seconds, "unions"):
+            self.hd_union = lists_ref.hd_union(pre, fs)
+            self.ld_union = lists_ref.ld_union(pre, post0, fs)
+        with timed(seconds, "union_sqdist"):
+            self.d_hd_union = lists_ref.union_sqdist(self.X, self.hd_union,
+                                                     r64)
+            self.d_ld_union = lists_ref.union_sqdist(pre["Y"],
+                                                     self.ld_union, r64)
+        with timed(seconds, "hd_d"):
+            self.hd_d = step_ref.sqdist(self.X, np.clip(
+                post0["hd_idx"].astype(np.int64), 0, n - 1), r64)
         # rows the step re-solves: all flagged ones, at a refresh step
         changed = np.any(post0["hd_idx"] != pre["hd_idx"], axis=1)
         flagged = pre["new_flag"].astype(bool) | changed
@@ -146,47 +177,61 @@ class Reference:
         near = (star == 0) | (octaves <= WARM_START)
         d2 = self.hd_d[rows][near]
         t = sigma_ref.target(d2, perplexity)
-        h = sigma_ref.entropy(d2, beta[rows][near].astype(np.float64))
+        b = beta[rows][near].astype(np.float64)
+        h = np.concatenate(step_ref.by_rows(
+            lambda s, e: sigma_ref.entropy(d2[s:e], b[s:e]), len(d2)))
         gap = float(np.max(np.abs(h - t) / t)) if near.any() else 0.0
         return (gap if np.isfinite(gap) else float("inf"),
                 float(1.0 - near.mean()))
 
 
-def readings(pre: dict, posts: list, X, fs: dict) -> tuple[dict, dict]:
+def readings(pre: dict, posts: list, X, fs: dict,
+             seconds: dict | None = None) -> tuple[dict, dict]:
     """(the compared numbers, information beside them) of the steps
-    ``pre -> post`` for each ``(post, hp)`` in ``posts``."""
-    ref = Reference(pre, posts[0][0], X, fs)
+    ``pre -> post`` for each ``(post, hp)`` in ``posts``; the seconds of
+    each part are added to ``seconds``."""
+    seconds = {} if seconds is None else seconds
+    ref = Reference(pre, posts[0][0], X, fs, seconds)
     n = ref.X.shape[0]
     post0 = posts[0][0]
     hd_idx = post0["hd_idx"].astype(np.int64)
     ld_idx = post0["ld_idx"].astype(np.int64)
     hd_d = post0["hd_d"].astype(np.float64)
 
-    lists = _list_faults(hd_idx, n) + _list_faults(ld_idx, n)
-    lists += int(np.sum(~np.isfinite(hd_d)))
-    lists += int(np.sum(np.diff(hd_d, axis=1) < 0))
-    for post, _ in posts[1:]:
-        lists += int(np.sum(post["hd_idx"] != post0["hd_idx"]))
-        lists += int(np.sum(post["ld_idx"] != post0["ld_idx"]))
-
-    r64 = step_ref.rounder("float64")
-    ref_ld = step_ref.sqdist(pre["Y"], np.clip(ld_idx, 0, n - 1), r64)
+    with timed(seconds, "lists"):
+        lists = _list_faults(hd_idx, n) + _list_faults(ld_idx, n)
+        lists += int(np.sum(~np.isfinite(hd_d)))
+        lists += int(np.sum(np.diff(hd_d, axis=1) < 0))
+        for post, _ in posts[1:]:
+            lists += int(np.sum(post["hd_idx"] != post0["hd_idx"]))
+            lists += int(np.sum(post["ld_idx"] != post0["ld_idx"]))
+    with timed(seconds, "merge_faults"):
+        hd_merge = lists_ref.merge_faults(hd_idx, ref.hd_union,
+                                          ref.d_hd_union)
+        ld_merge = lists_ref.merge_faults(ld_idx, ref.ld_union,
+                                          ref.d_ld_union)
+    with timed(seconds, "distances"):
+        r64 = step_ref.rounder("float64")
+        ref_ld = step_ref.sqdist(pre["Y"], np.clip(ld_idx, 0, n - 1), r64)
+        hd_d_err = _rel_gap(hd_d, ref.hd_d)
+        ld_d_err = _rel_gap(post0["ld_d"], ref_ld)
     update, force, sigma, far = 0, 0.0, 0.0, 0.0
     for post, hp in posts:
-        update += _update_faults(pre, post)
-        step = step_ref.one_step(pre, post, ref.hd_d, hp, fs)
-        force = max(force, _rel_gap(post["vel"], step["vel"], step["scale"]))
-        gap, share = ref.sigma(post, hp["perplexity"])
+        with timed(seconds, "one_step"):
+            update += _update_faults(pre, post)
+            step = step_ref.one_step(pre, post, ref.hd_d, hp, fs)
+            force = max(force, _rel_gap(post["vel"], step["vel"],
+                                        step["scale"]))
+        with timed(seconds, "sigma"):
+            gap, share = ref.sigma(post, hp["perplexity"])
         sigma, far = max(sigma, gap), max(far, share)
     numbers = {
         "list_faults": lists,
-        "hd_merge_faults": lists_ref.merge_faults(hd_idx, ref.hd_union,
-                                                  ref.d_hd_union),
-        "ld_merge_faults": lists_ref.merge_faults(ld_idx, ref.ld_union,
-                                                  ref.d_ld_union),
+        "hd_merge_faults": hd_merge,
+        "ld_merge_faults": ld_merge,
         "update_faults": update,
-        "hd_d_err": _rel_gap(hd_d, ref.hd_d),
-        "ld_d_err": _rel_gap(post0["ld_d"], ref_ld),
+        "hd_d_err": hd_d_err,
+        "ld_d_err": ld_d_err,
         "sigma_err": sigma,
         "force_err": force}
     info = {"step": int(pre["step"]),

@@ -42,7 +42,7 @@ import time
 
 import numpy as np
 
-from bench.check import STATE_POST, STATE_PRE, host_state
+from bench.check import STATE_POST, STATE_PRE, host_state, timed
 from bench.data import generators
 from bench.reference import knn as knn_ref
 from bench.reference import lists as lists_ref
@@ -136,29 +136,38 @@ class Cell:
             return None
         return int(mem.argument_size_in_bytes + mem.temp_size_in_bytes)
 
-    def check_steps(self):
+    def check_steps(self, seconds: dict | None = None):
         """(pre, [(post, hp)]): see ``bench/check.py``.  The window's
         program runs on to the next bandwidth refresh; every row is then
         marked as having new neighbours and the improvement share set to
         1, and one step is made from that state under each of
-        ``check_hps``."""
+        ``check_hps``.  The seconds of the device steps to the refresh
+        (``to_refresh``), of the check's steps (``steps``), of the copies
+        to the host (``readback``) and of ``bytes_of`` are added to
+        ``seconds``."""
+        seconds = {} if seconds is None else seconds
         jax, jnp = self.jax, self.jax.numpy
         st, self.st = self.st, None
-        while int(st.step) % self.cfg.sigma_refresh_every:
-            st = self.one(st, self.next_hp())
-        st = st._replace(
-            new_flag=jnp.ones_like(st.new_flag),
-            ema_new_frac=jnp.ones_like(st.ema_new_frac))
-        pre = host_state(jax, st, STATE_PRE)
+        with timed(seconds, "to_refresh"):
+            while int(st.step) % self.cfg.sigma_refresh_every:
+                st = self.one(st, self.next_hp())
+            st = jax.block_until_ready(st._replace(
+                new_flag=jnp.ones_like(st.new_flag),
+                ema_new_frac=jnp.ones_like(st.ema_new_frac)))
+        with timed(seconds, "readback"):
+            pre = host_state(jax, st, STATE_PRE)
         hps = self.check_hps()
-        self.program_bytes = self.bytes_of(st, hps[0])
+        with timed(seconds, "bytes_of"):
+            self.program_bytes = self.bytes_of(st, hps[0])
         posts = []
         for i, hp in enumerate(hps):
             src = st if i == len(hps) - 1 else \
                 jax.tree_util.tree_map(jnp.copy, st)
-            out = self.one(src, hp)
-            posts.append((host_state(jax, out, STATE_POST),
-                          self.reference_hp(hp, int(pre["step"]))))
+            with timed(seconds, "steps"):
+                out = jax.block_until_ready(self.one(src, hp))
+            with timed(seconds, "readback"):
+                posts.append((host_state(jax, out, STATE_POST),
+                              self.reference_hp(hp, int(pre["step"]))))
             del out
         return pre, posts
 

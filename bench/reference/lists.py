@@ -18,8 +18,6 @@ left out while the list keeps a farther one.
 """
 from __future__ import annotations
 
-from concurrent.futures import ThreadPoolExecutor
-
 import numpy as np
 
 from bench.reference import rng as rng_ref
@@ -72,9 +70,10 @@ def hd_union(pre: dict, fs: dict) -> np.ndarray:
     if not gate_fires(pre, fs):
         return cur
     ld = pre["ld_idx"].astype(np.int64)
-    cand = rng_ref.candidates(_salt(pre, rng_ref.TAG_HD), hd_sources(fs),
-                              (cur, ld), (cur, ld), cur.shape[0])
-    return _union(cur, cand, pre["active"])
+    salt, sources = _salt(pre, rng_ref.TAG_HD), hd_sources(fs)
+    return _union(cur, lambda s, e: rng_ref.candidates(
+        salt, sources, (cur, ld), (cur, ld), cur.shape[0], s, e),
+        pre["active"])
 
 
 def ld_union(pre: dict, post: dict, fs: dict) -> np.ndarray:
@@ -82,28 +81,28 @@ def ld_union(pre: dict, post: dict, fs: dict) -> np.ndarray:
     those of the step's own new HD lists."""
     cur = pre["ld_idx"].astype(np.int64)
     hd = post["hd_idx"].astype(np.int64)
-    cand = rng_ref.candidates(_salt(pre, rng_ref.TAG_LD), ld_sources(fs),
-                              (cur, hd), (cur,), cur.shape[0])
-    return _union(cur, cand, pre["active"])
+    salt, sources = _salt(pre, rng_ref.TAG_LD), ld_sources(fs)
+    return _union(cur, lambda s, e: rng_ref.candidates(
+        salt, sources, (cur, hd), (cur,), cur.shape[0], s, e),
+        pre["active"])
 
 
-def _union(cur, cand, active) -> np.ndarray:
-    n, c = cand.shape
-    out = np.concatenate([cur, cand], axis=1)
-    tri = np.tri(c, c, -1, dtype=bool)[None]
+def _union(cur, draw, active) -> np.ndarray:
+    """Each row's list ``cur`` and its candidates ``draw(s, e)`` (rows
+    ``[s, e)``), -1 where a candidate is invalid."""
+    n = cur.shape[0]
 
-    def block(s):
-        e = min(s + step_ref.ROW_BLOCK, n)
-        cb, rows = cand[s:e], np.arange(s, e)[:, None]
+    def block(s, e):
+        cb, rows = draw(s, e), np.arange(s, e)[:, None]
+        c = cb.shape[1]
+        tri = np.tri(c, c, -1, dtype=bool)[None]
         bad = (cb < 0) | (cb >= n) | (cb == rows)
         bad |= np.any(cb[:, :, None] == cur[s:e, None, :], axis=-1)
         bad |= np.any((cb[:, :, None] == cb[:, None, :]) & tri, axis=-1)
         bad |= ~active[np.clip(cb, 0, n - 1)]
-        out[s:e, cur.shape[1]:] = np.where(bad, -1, cb)
+        return np.concatenate([cur[s:e], np.where(bad, -1, cb)], axis=1)
 
-    with ThreadPoolExecutor(step_ref.THREADS) as pool:
-        list(pool.map(block, range(0, n, step_ref.ROW_BLOCK)))
-    return out
+    return np.concatenate(step_ref.by_rows(block, n))
 
 
 def union_sqdist(A, union, r) -> np.ndarray:
@@ -125,17 +124,23 @@ def merge_faults(new, union, d_union) -> int:
     d_kept = np.full((n, k), np.inf)
     found = np.zeros((n, k), bool)
     left_out = np.zeros(union.shape, bool)
-    for j in range(k):
-        hit = union == new[:, j:j + 1]
-        found[:, j] = hit.any(axis=1)
-        d_kept[:, j] = np.where(found[:, j],
-                                d_union[np.arange(n), hit.argmax(axis=1)],
-                                np.inf)
-    for j in range(union.shape[1]):
-        left_out[:, j] = (union[:, j] >= 0) & \
-            ~np.any(new == union[:, j:j + 1], axis=1)
-    worst = np.max(np.where(found, d_kept, -np.inf), axis=1)
-    scale = np.maximum(worst, np.median(d_kept[found]) if found.any()
-                       else 0.0)
-    missed = left_out & (d_union < (worst - TIE * scale)[:, None])
-    return int(np.sum(~found) + np.sum(missed))
+
+    def match(s, e):
+        hit = union[s:e, None, :] == new[s:e, :, None]
+        found[s:e] = hit.any(axis=2)
+        d_kept[s:e] = np.where(found[s:e], np.take_along_axis(
+            d_union[s:e], hit.argmax(axis=2), axis=1), np.inf)
+        left_out[s:e] = (union[s:e] >= 0) & ~hit.any(axis=1)
+
+    step_ref.by_rows(match, n)
+    # the median over every row's kept distances
+    median = np.median(d_kept[found]) if found.any() else 0.0
+
+    def faults(s, e):
+        worst = np.max(np.where(found[s:e], d_kept[s:e], -np.inf), axis=1)
+        scale = np.maximum(worst, median)
+        missed = left_out[s:e] & \
+            (d_union[s:e] < (worst - TIE * scale)[:, None])
+        return int(np.sum(~found[s:e]) + np.sum(missed))
+
+    return sum(step_ref.by_rows(faults, n))

@@ -62,16 +62,18 @@ def counter_uniform01(h) -> np.float32:
     return np.float32(_u32(h) >> np.uint32(8)) * np.float32(1.0 / (1 << 24))
 
 
-def candidates(salt, sources, firsts, seconds, n_total: int) -> np.ndarray:
-    """(n, C) candidates of every row: slot ``g`` of row ``r`` draws
-    ``hash3(salt, r, 2g)`` and, for a two-hop pick, ``hash3(salt, r,
-    2g + 1)``.  ``sources`` lists the candidate groups in slot order:
-    ``("uniform", c)`` over [0, n_total), ``("one_hop", f, c)`` entries of
-    the row's own list ``firsts[f]``, ``("two_hop", f, s, c)`` picks
-    ``seconds[s][firsts[f][r, a], b]`` (an empty first hop falls back to
-    the row itself)."""
-    n = firsts[0].shape[0]
-    rows = np.arange(n, dtype=np.int64)[:, None]
+def candidates(salt, sources, firsts, seconds, n_total: int, start: int = 0,
+               stop: int | None = None) -> np.ndarray:
+    """(rows, C) candidates of rows ``[start, stop)`` (all by default):
+    slot ``g`` of row ``r`` draws ``hash3(salt, r, 2g)`` and, for a
+    two-hop pick, ``hash3(salt, r, 2g + 1)``.  ``sources`` lists the
+    candidate groups in slot order: ``("uniform", c)`` over [0, n_total),
+    ``("one_hop", f, c)`` entries of the row's own list ``firsts[f]``,
+    ``("two_hop", f, s, c)`` picks ``seconds[s][firsts[f][r, a], b]`` (an
+    empty first hop falls back to the row itself)."""
+    stop = firsts[0].shape[0] if stop is None else stop
+    rows = np.arange(start, stop, dtype=np.int64)[:, None]
+    firsts = [f[start:stop] for f in firsts]
     parts, g = [], 0
     for src in sources:
         kind, c = src[0], int(src[-1])
@@ -97,5 +99,5 @@ def candidates(salt, sources, firsts, seconds, n_total: int) -> np.ndarray:
         parts.append(np.asarray(cand, np.int64))
         g += c
     if not parts:
-        return np.zeros((n, 0), np.int64)
+        return np.zeros((rows.shape[0], 0), np.int64)
     return np.concatenate(parts, axis=1)

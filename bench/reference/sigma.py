@@ -10,6 +10,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from bench.reference import step as step_ref
+
 # bisection steps on log(beta) over [LOG_LO, LOG_HI]
 ITERATIONS = 50
 LOG_LO, LOG_HI = -60.0, 60.0
@@ -17,11 +19,20 @@ LOG_LO, LOG_HI = -60.0, 60.0
 
 def entropy(d2, beta, r=np.asarray) -> np.ndarray:
     """Entropy (nats) of each row's affinities at bandwidth ``beta``."""
-    z = r(d2 - d2.min(axis=1, keepdims=True))
+    return _entropy(_shifted(d2, r), beta, r)
+
+
+def _shifted(d2, r):
+    """Each row's distances less the row's nearest."""
+    return r(d2 - d2.min(axis=1, keepdims=True))
+
+
+def _entropy(z, beta, r) -> np.ndarray:
     e = r(np.exp(r(-np.asarray(beta)[:, None] * z)))
     p = r(e / r(e.sum(axis=1, keepdims=True)))
-    logp = np.log(np.where(p > 0, p, 1.0))
-    return -np.sum(r(np.where(p > 0, r(p * logp), 0.0)), axis=1)
+    pos = p > 0
+    logp = np.log(np.where(pos, p, 1.0))
+    return -np.sum(r(np.where(pos, r(p * logp), 0.0)), axis=1)
 
 
 def target(d2, perplexity) -> np.ndarray:
@@ -33,13 +44,20 @@ def target(d2, perplexity) -> np.ndarray:
 
 def solve(d2, perplexity, r=np.asarray) -> np.ndarray:
     """beta of every row by bisection on log(beta); 0 where the target is
-    log of the list's length."""
+    log of the list's length.  Rows are independent, so each block of
+    rows makes its own passes."""
+    return np.concatenate(step_ref.by_rows(
+        lambda s, e: _solve(d2[s:e], perplexity, r), d2.shape[0]))
+
+
+def _solve(d2, perplexity, r) -> np.ndarray:
     t = target(d2, perplexity)
+    z = _shifted(d2, r)
     lo = np.full(d2.shape[0], LOG_LO)
     hi = np.full(d2.shape[0], LOG_HI)
     for _ in range(ITERATIONS):
         mid = r(0.5 * (lo + hi))
-        flat = entropy(d2, r(np.exp(mid)), r) > t
+        flat = _entropy(z, r(np.exp(mid)), r) > t
         lo, hi = np.where(flat, mid, lo), np.where(flat, hi, mid)
     beta = r(np.exp(r(0.5 * (lo + hi))))
     return np.where(t < np.log(np.sum(np.isfinite(d2), axis=1)), beta, 0.0)

@@ -15,6 +15,7 @@ intermediate to bfloat16: the control that a check must refuse.
 """
 from __future__ import annotations
 
+import os
 from concurrent.futures import ThreadPoolExecutor
 
 import ml_dtypes
@@ -23,9 +24,31 @@ import numpy as np
 from bench.reference import rng as rng_ref
 
 ROW_BLOCK = 8192
-# NumPy releases the GIL in gathers and reductions: blocks of rows run
-# on a few threads
-THREADS = 4
+# NumPy releases the GIL in its ufuncs, gathers and reductions, so blocks
+# of rows run on threads, one per host core up to this many
+MAX_THREADS = 32
+
+
+def threads() -> int:
+    """Threads that work on blocks of rows: one per host core, at most
+    ``MAX_THREADS``."""
+    return min(os.cpu_count() or 1, MAX_THREADS)
+
+
+def pool_map(fn, items) -> list:
+    """``fn`` of each item on ``threads()`` threads, results in order."""
+    with ThreadPoolExecutor(threads()) as pool:
+        return list(pool.map(fn, items))
+
+
+def by_rows(fn, n: int, rows: int | None = None) -> list:
+    """``fn(s, e)`` for each block ``[s, e)`` of ``rows`` (by default
+    ``ROW_BLOCK``) rows of ``n`` (one empty block where ``n`` is 0),
+    results in block order.  Work that is independent by row gives the
+    same bits in any block."""
+    rows = rows or ROW_BLOCK
+    return pool_map(lambda s: fn(s, min(s + rows, n)),
+                    range(0, max(n, 1), rows))
 
 
 def rounder(precision: str):
@@ -58,20 +81,21 @@ def sqdist(A, idx, r):
     A = r(A)
     out = np.empty(idx.shape, np.float64)
 
-    def block(s):
-        e = min(s + ROW_BLOCK, idx.shape[0])
+    def block(s, e):
         diff = r(A[idx[s:e]] - A[s:e, None, :])
         out[s:e] = r(np.sum(r(diff * diff), axis=-1))
 
-    with ThreadPoolExecutor(THREADS) as pool:
-        list(pool.map(block, range(0, idx.shape[0], ROW_BLOCK)))
+    # a block gathers as many entries as ROW_BLOCK rows of 32 scalars
+    per_row = max(1, idx.shape[1] * A.shape[1])
+    by_rows(block, idx.shape[0], max(1, ROW_BLOCK * 32 // per_row))
     return out
 
 
-def _segment(Y, idx, coef, alpha, mode, r):
+def _segment(Y, own, idx, coef, alpha, mode, r):
     """Per-edge forces of one neighbour segment (variable-tail kernel
-    w = (1 + d2/alpha)^-alpha): returns (agg, edge, wsum, sum |edge|)."""
-    delta = r(Y[idx] - Y[:, None, :])
+    w = (1 + d2/alpha)^-alpha) of the rows ``own`` (rows of ``Y``) with
+    neighbours ``idx``: returns (agg, edge, wsum, sum |edge|)."""
+    delta = r(Y[idx] - own[:, None, :])
     d2 = r(np.sum(r(delta * delta), axis=-1))
     base = r(1.0 + r(d2 / alpha))
     if mode == "attraction":
@@ -87,12 +111,19 @@ def _segment(Y, idx, coef, alpha, mode, r):
     return r(np.sum(edge, axis=1)), edge, wsum, np.sum(np.abs(edge), 1)
 
 
-def _scatter(idx, edge):
-    """out[idx[i, k]] += edge[i, k] (where the symmetric reactions go)."""
-    flat = idx.reshape(-1)
-    return np.stack([np.bincount(flat, weights=edge[..., j].reshape(-1),
-                                 minlength=idx.shape[0])
-                     for j in range(edge.shape[-1])], axis=1)
+def _scatter(*pairs) -> list:
+    """For each ``(idx, edge)``: out[idx[i, k]] += edge[i, k] (where the
+    symmetric reactions go).  Each column is one ``bincount`` over all
+    rows; the columns run on threads."""
+    def column(job):
+        idx, edge, j = job
+        return np.bincount(idx.reshape(-1), weights=edge[..., j].reshape(-1),
+                           minlength=idx.shape[0])
+
+    d = pairs[0][1].shape[-1]
+    sums = pool_map(column, [(idx, edge, j) for idx, edge in pairs
+                             for j in range(d)])
+    return [np.stack(sums[i:i + d], axis=1) for i in range(0, len(sums), d)]
 
 
 def one_step(pre: dict, post: dict, hd_d, hp: dict, fs: dict,
@@ -111,30 +142,35 @@ def one_step(pre: dict, post: dict, hd_d, hp: dict, fs: dict,
     r = rounder(precision)
     Y = r(pre["Y"])
     n, d = Y.shape
-    rows = np.arange(n, dtype=np.int64)
     hd_idx = np.clip(post["hd_idx"].astype(np.int64), 0, n - 1)
     ld_idx = np.clip(post["ld_idx"].astype(np.int64), 0, n - 1)
     alpha = float(hp["alpha"])
-
-    # attraction coefficients p_{j|i} / (2 n) over the HD list (Eq. 1)
-    dmin = hd_d.min(axis=1, keepdims=True)
-    e = r(np.exp(r(-r(post["beta"])[:, None] * r(hd_d - dmin))))
-    p = r(e / np.maximum(r(np.sum(e, axis=1, keepdims=True)), 1e-30))
-    coef_a = r(p / (2.0 * n))
-
-    # negative samples: the program's counter draws
     salt = rng_ref.hash3(rng_ref.key_salt(pre["key"]), int(pre["step"]),
                          rng_ref.TAG_NEG)
     n_neg = int(fs["n_negatives"])
-    neg = rng_ref.counter_randint(salt, rows[:, None],
-                                  np.arange(n_neg)[None, :], n)
-    neg = np.where(neg == rows[:, None], (neg + 1) % n, neg)
 
-    agg_a, edge_a, _, abs_a = _segment(Y, hd_idx, coef_a, alpha,
-                                       "attraction", r)
-    agg_r, edge_r, wsum_r, abs_r = _segment(Y, ld_idx, 0.5, alpha,
-                                            "repulsion", r)
-    agg_n, _, wsum_n, abs_n = _segment(Y, neg, 1.0, alpha, "repulsion", r)
+    def rows(s, e):
+        # attraction coefficients p_{j|i} / (2 n) over the HD list (Eq. 1)
+        hd_b = hd_d[s:e]
+        dmin = hd_b.min(axis=1, keepdims=True)
+        ex = r(np.exp(r(-r(post["beta"][s:e])[:, None] * r(hd_b - dmin))))
+        p = r(ex / np.maximum(r(np.sum(ex, axis=1, keepdims=True)), 1e-30))
+        coef_a = r(p / (2.0 * n))
+        # negative samples: the program's counter draws
+        own = np.arange(s, e, dtype=np.int64)[:, None]
+        neg = rng_ref.counter_randint(salt, own, np.arange(n_neg)[None, :],
+                                      n)
+        neg = np.where(neg == own, (neg + 1) % n, neg)
+        agg_a, edge_a, _, abs_a = _segment(Y, Y[s:e], hd_idx[s:e], coef_a,
+                                           alpha, "attraction", r)
+        rep = _segment(Y, Y[s:e], ld_idx[s:e], 0.5, alpha, "repulsion", r)
+        agg_n, _, wsum_n, abs_n = _segment(Y, Y[s:e], neg, 1.0, alpha,
+                                           "repulsion", r)
+        return (agg_a, edge_a, abs_a) + rep + (agg_n, wsum_n, abs_n)
+
+    (agg_a, edge_a, abs_a, agg_r, edge_r, wsum_r, abs_r,
+     agg_n, wsum_n, abs_n) = (np.concatenate(part)
+                              for part in zip(*by_rows(rows, n)))
 
     scale_neg = max(n - 1.0 - int(fs["k_ld"]), 1.0) / n_neg
     z_est = max(float(r(2.0 * np.sum(wsum_r) + scale_neg * np.sum(wsum_n))),
@@ -145,13 +181,14 @@ def one_step(pre: dict, post: dict, hd_d, hp: dict, fs: dict,
     attr_s = float(hp["attraction"]) * float(hp["exaggeration"])
     rep_s = float(hp["repulsion"]) / zhat
 
+    react_a, react_r, mag_a, mag_r = _scatter(
+        (hd_idx, r(attr_s * edge_a)), (ld_idx, r(rep_s * edge_r)),
+        (hd_idx, np.abs(edge_a)), (ld_idx, np.abs(edge_r)))
     buf = r(attr_s * agg_a + rep_s * r(agg_r + scale_neg * agg_n))
-    buf = buf - _scatter(hd_idx, r(attr_s * edge_a)) \
-        - _scatter(ld_idx, r(rep_s * edge_r))
+    buf = buf - react_a - react_r
     dY = r(4.0 * r(buf))
-    mag = 4.0 * (attr_s * (abs_a + _scatter(hd_idx, np.abs(edge_a)))
-                 + rep_s * (abs_r + scale_neg * abs_n
-                            + _scatter(ld_idx, np.abs(edge_r))))
+    mag = 4.0 * (attr_s * (abs_a + mag_a)
+                 + rep_s * (abs_r + scale_neg * abs_n + mag_r))
 
     vel0, gains0 = r(pre["vel"]), r(pre["gains"])
     same = np.sign(dY) == np.sign(vel0)

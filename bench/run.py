@@ -14,7 +14,10 @@ The window then runs for ``--seconds``.  With ``--trace 1`` the window
 is traced by the profiler and the per-layer metrics replace the
 end-to-end ones.  After the window the output check runs (see
 ``bench/check.py``) and prints each compared number beside its limit,
-as the last lines of standard error and as the last key of the result.
+as the last lines of standard error and as the last key of the result;
+the check's seconds (``check_s``) and their split by part
+(``check_split``: device steps, copies to the host, ``bytes_of``, and
+each part of the reference) go to standard error before them.
 ``--control 1`` also prints the numbers of the check's control, from
 which, with the program's own, the limits in
 ``bench/limits/<cell>.json`` are set.
@@ -192,10 +195,12 @@ def main(argv=None, *, require_tpu: bool = True, overrides=None) -> int:
 
     # the check: steps of the window's program from its end state
     t_check = time.perf_counter()
-    X = jax.device_get(cell.X)
-    pre, posts = cell.check_steps()
+    split = {}
+    with check.timed(split, "readback"):
+        X = jax.device_get(cell.X)
+    pre, posts = cell.check_steps(split)
     device["program_bytes"] = cell.program_bytes
-    numbers, info = check.readings(pre, posts, X, cell.fs)
+    numbers, info = check.readings(pre, posts, X, cell.fs, split)
     check_s = time.perf_counter() - t_check
     numbers.update(result.get("numbers", {}))
     correct, table = check.judge(numbers, spec["limits"])
@@ -211,7 +216,9 @@ def main(argv=None, *, require_tpu: bool = True, overrides=None) -> int:
         correct = False
 
     err(f"setup_s={setup_s:.3f} window_s={result['window_s']:.3f} "
-        f"check_s={check_s:.3f} attempted={result['attempted']} "
+        f"check_s={check_s:.3f} check_split="
+        f"{json.dumps({k: round(v, 3) for k, v in split.items()})} "
+        f"attempted={result['attempted']} "
         f"compiles_in_window="
         f"{len(compiles)} check={json.dumps(info)} "
         f"info={json.dumps(result.get('info', {}))}")

@@ -1,13 +1,17 @@
 """The output check refuses its control and every fault the cells can
 have, and passes the program, at a size a CPU test run holds."""
 import json
+import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import check, common, run
+from bench import check, common, generator, run
+from bench.reference import lists as lists_ref
+from bench.reference import sigma as sigma_ref
+from bench.reference import step as step_ref
 from repro.core import funcsne
 
 TINY = {"atlas-262k.batch": 1024, "mnist-70k.frames": 1000}
@@ -145,3 +149,117 @@ def test_sigma_reference_hits_its_target():
     # above the list's length: uniform weights
     assert np.all(sigma.solve(d2, 40.0) == 0.0)
     assert np.allclose(sigma.target(d2, 40.0), np.log(32.0))
+
+
+def _synthetic(n: int = 3072, seed: int = 11):
+    """(pre, posts, X, fs) of a check at a refresh step: two phases whose
+    lists, distances, bandwidths and velocities are the reference's own
+    in float32 (so the gaps are at rounding), with faults planted in
+    three rows: an HD entry and an LD entry that are no candidates, and
+    an embedding entry that is not Y + vel."""
+    fs = common.load_json(common.BENCH / "configs" / "atlas-262k.json")[
+        "funcsne"]
+    r, r64 = np.random.default_rng(seed), step_ref.rounder("float64")
+    f32 = np.float32
+    X = r.standard_normal((n, 50)).astype(f32)
+    Y = r.standard_normal((n, 2)).astype(f32)
+
+    def distinct(k):
+        """k different ids per row, none the row itself."""
+        base = r.choice(n - 1, k, replace=False)
+        shift = r.integers(0, n - 1, (n, 1))
+        return ((np.arange(n)[:, None] + 1 + (base + shift) % (n - 1))
+                % n).astype(np.int32)
+
+    pre = {"Y": Y, "vel": (0.01 * r.standard_normal((n, 2))).astype(f32),
+           "gains": np.ones((n, 2), f32), "zhat": f32(1e5),
+           "step": np.int32(40), "hd_idx": distinct(fs["k_hd"]),
+           "hd_d": np.zeros((n, fs["k_hd"]), f32),
+           "ld_idx": distinct(fs["k_ld"]),
+           "beta": np.full(n, 0.5, f32), "new_flag": np.ones(n, np.int32),
+           "active": np.ones(n, bool), "ema_new_frac": f32(1.0),
+           "key": np.array([0, 2 ** 31 + 5], np.uint32)}
+    hd_union = lists_ref.hd_union(pre, fs)
+    hd_idx = lists_ref.best(hd_union,
+                            lists_ref.union_sqdist(X, hd_union, r64),
+                            fs["k_hd"])
+    ld_union = lists_ref.ld_union(pre, {"hd_idx": hd_idx}, fs)
+    ld_idx = lists_ref.best(ld_union,
+                            lists_ref.union_sqdist(Y, ld_union, r64),
+                            fs["k_ld"])
+    # no candidate of their row
+    hd_idx[3, 0] = np.setdiff1d(np.arange(4, n), hd_union[3])[0]
+    ld_idx[11, 2] = np.setdiff1d(np.arange(12, n), ld_union[11])[0]
+    hd_idx[3] = hd_idx[3][np.argsort(step_ref.sqdist(X, hd_idx, r64)[3],
+                                     kind="stable")]
+    hd_d = step_ref.sqdist(X, hd_idx, r64)
+    lists = {"hd_idx": hd_idx.astype(np.int32),
+             "hd_d": hd_d.astype(f32), "ld_idx": ld_idx.astype(np.int32),
+             "ld_d": step_ref.sqdist(Y, ld_idx, r64).astype(f32)}
+    base = generator.base_hparams(n, {})
+    posts = []
+    for hp in (step_ref.schedule(base, 40, 750),
+               dict(base, perplexity=f32(12.0))):
+        post = dict(lists, beta=sigma_ref.solve(
+            hd_d, hp["perplexity"]).astype(f32))
+        post["vel"] = step_ref.one_step(pre, post, hd_d, hp, fs)[
+            "vel"].astype(f32)
+        post["Y"] = (Y + post["vel"]).astype(f32)
+        posts.append((post, hp))
+    posts[0][0]["Y"][5, 0] += f32(1.0)
+    return pre, posts, X, fs
+
+
+def _numbers(pre, posts, X, fs):
+    """The check's numbers and information, its control's numbers, and
+    the arrays of the reference they come from: the control's outputs
+    (bfloat16) and the float64 step and bandwidths of the first phase."""
+    ctl = check.control_posts(pre, posts, X, fs)
+    post, hp = posts[0]
+    ref = check.Reference(pre, post, X, fs)
+    arrays = [a for c, _ in ctl for a in c.values()]
+    arrays += list(step_ref.one_step(pre, post, ref.hd_d, hp, fs).values())
+    arrays += [ref.beta(hp["perplexity"]), ref.d_hd_union, ref.d_ld_union]
+    return (check.readings(pre, posts, X, fs),
+            check.readings(pre, ctl, X, fs)[0], arrays)
+
+
+@pytest.fixture(scope="module")
+def whole_array():
+    """The synthetic state and its numbers with one block that holds
+    every row on one thread: the computation of a plain loop-free
+    reference."""
+    state = _synthetic()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(step_ref, "ROW_BLOCK", len(state[2]))
+        mp.setattr(step_ref, "threads", lambda: 1)
+        return state, _numbers(*state)
+
+
+@pytest.mark.parametrize("block", [512, 700, None])
+@pytest.mark.parametrize("pool", [1, 8])
+def test_blocks_and_threads_give_the_same_numbers(monkeypatch, whole_array,
+                                                  pool, block):
+    """The reference in row blocks (512 divides n, 700 does not; None is
+    one block of every row) on a pool of 1 or 8 threads gives the
+    numbers of one whole-array block, bit for bit."""
+    state, (want, want_ctl, want_arrays) = whole_array
+    numbers = want[0]
+    assert numbers["hd_merge_faults"] > 0 and numbers["ld_merge_faults"] > 0
+    assert numbers["update_faults"] > 0 and numbers["list_faults"] == 0
+    assert 0 < numbers["force_err"] < 1e-5
+    monkeypatch.setattr(step_ref, "ROW_BLOCK", block or len(state[2]))
+    monkeypatch.setattr(step_ref, "threads", lambda: pool)
+    got, got_ctl, got_arrays = _numbers(*state)
+    assert got == want
+    assert got_ctl == want_ctl
+    for a, b in zip(got_arrays, want_arrays, strict=True):
+        a, b = np.asarray(a), np.asarray(b)
+        assert a.dtype == b.dtype and np.array_equal(a, b)
+
+
+def test_pool_is_capped(monkeypatch):
+    monkeypatch.setattr(os, "cpu_count", lambda: 10 ** 6)
+    assert step_ref.threads() == step_ref.MAX_THREADS == 32
+    monkeypatch.setattr(os, "cpu_count", lambda: None)
+    assert step_ref.threads() == 1
