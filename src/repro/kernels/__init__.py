@@ -19,10 +19,13 @@ already-gathered (B, C, M) / (B, K, d) operands, and the gather-fused form
 (``*_gather``) takes *indices* and DMAs only the needed rows in-kernel
 (source matrix stays in HBM/ANY, read as lane-padded rows; index slabs
 staged into SMEM by the pipeline).  At d <= 4, when the embedding fits
-a VMEM budget, ``ne_forces_gather``'s edge mode holds it packed in VMEM
-for the launch and reads rows on-core instead (``ops.row_source``).  It
-additionally offers a scatter-fused output mode (``scatter_fused=True``): per-edge
-forces and their symmetric reactions are index-binned in-kernel into
+a VMEM budget, ``ne_forces_gather``'s edge mode and the candidate-fused
+merge hold it packed in VMEM for the launch and read rows on-core
+instead (``pairwise_sqdist.kernel.row_source``); the merge also holds
+its activity flags there (``knn_merge.ops.merge_sources``).
+``ne_forces_gather`` additionally offers a scatter-fused output mode
+(``scatter_fused=True``): per-edge forces and their symmetric
+reactions are index-binned in-kernel into
 per-segment (N, d) displacement fields (reference on
 ``jax.ops.segment_sum``), so the per-edge tensors never round-trip
 through HBM.  The gather-fused forms are the per-iteration default

@@ -44,6 +44,8 @@ slab and clamped+masked final M chunk), with the accumulator landing in a
 (B/block_b, M/block_m) with ``dimension_semantics=("parallel",
 "arbitrary")``: row blocks are independent, the M axis sequentially
 revisits the block's accumulator and runs the merge on its final chunk.
+At small widths the candidate-fused kernel below scores from a packed
+table resident in VMEM instead (``score_packed_block``), in one M chunk.
 """
 from __future__ import annotations
 
@@ -55,10 +57,12 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.compat import tpu_compiler_params
-from repro.kernels.pairwise_sqdist.kernel import (LANES, _round_up, pad_lanes,
+from repro.kernels.pairwise_sqdist.kernel import (LANES, ROW_SHIFT,
+                                                  VMEM_ROWS_LIMIT, _round_up,
+                                                  pack_rows, pad_lanes,
                                                   plan_row_gather,
                                                   score_gather_block,
-                                                  smem_ids)
+                                                  smem_ids, unpack_rows)
 
 _SENTINEL = jnp.iinfo(jnp.int32).max
 
@@ -127,15 +131,20 @@ def merge_select(qid_col, cur_idx, cur_d, cand, cand_d, ext_valid):
     return new_idx.astype(i32), new_d, improved
 
 
-def for_row_slices(block_b: int, fn, rows: int = 8):
+def slice_rows(block_b: int) -> int:
+    """Rows of one merge-epilogue slice: one f32 sublane tile, or the
+    whole block where it is not a multiple of one."""
+    return block_b if block_b % 8 else 8
+
+
+def for_row_slices(block_b: int, fn):
     """Run ``fn(pl.ds(base, rows))`` over the row slices of a block.
 
     The merge's rank compares build (rows, K+C, K+C) intermediates; over a
     whole 128-row block they would outgrow VMEM, so the epilogue walks
-    the block one f32 sublane tile at a time.
+    the block one f32 sublane tile at a time (``slice_rows``).
     """
-    if block_b % rows:
-        rows = block_b
+    rows = slice_rows(block_b)
 
     def body(p, _):
         fn(pl.ds(pl.multiple_of(p * rows, rows), rows))
@@ -327,10 +336,23 @@ def knn_merge_pallas(
 #     lane-aligned rows, so a 4-byte chained element DMA cannot be used.
 #
 # Per-candidate ``active``-row flags come from a lane-dense (N/128, 128)
-# packing of the mask: the kernel DMAs the 128-flag row holding each
-# candidate at generation time, awaits it just before the merge, and
-# picks the candidate's lane with a one-hot select, so the whole activity
-# gather overlaps the scoring sweep.
+# packing of the mask (``pack_rows`` of the (N, 1) flags), and the
+# candidate's flag is picked from the 128-flag row holding it with a
+# one-hot lane select (``pick_flags``).  Where the rows come from is
+# static (``flag_source``, chosen by ``ops.merge_sources`` from N alone):
+#
+#   * ``'dma'``: the table stays in HBM; the kernel DMAs each candidate's
+#     flag row at generation time and awaits it just before the merge, so
+#     the activity gather overlaps the scoring sweep;
+#   * ``'vmem'``: the table is resident in VMEM for the launch, and each
+#     merge slice loads its candidates' flag rows on-core.
+#
+# Rows are scored the same two ways (``row_source``): one lane-padded HBM
+# row DMA per id through ``score_gather_block``, or, at small widths, on-
+# core loads from a packed table resident in VMEM (``score_packed_block``,
+# the force kernel's ``'vmem'`` row source).  Both stage the same
+# lane-padded rows and feed them to the same distance arithmetic, so the
+# outputs are identical.
 
 
 def _slot_plan(sources):
@@ -357,9 +379,57 @@ def _slot_plan(sources):
     return slots, n_chain, n_extra
 
 
+def pick_flags(flag_rows, cand, n_rows: int):
+    """(rows, C, 128) packed flag rows of (rows, C) candidate ids -> the
+    candidates' own flags, (rows, C) bool: lane ``clip(id) & 127``."""
+    lane = jnp.clip(cand, 0, n_rows - 1) & (LANES - 1)
+    ll = jax.lax.broadcasted_iota(jnp.int32, (1, 1, LANES), 2)
+    return jnp.sum(jnp.where(lane[:, :, None] == ll, flag_rows, 0.0),
+                   axis=2) != 0
+
+
+def score_packed_block(qid_ref, gat_ref, qid_v_ref, gat_v_ref, x_ref, acc,
+                       q_scr, c_scr, *, d: int):
+    """Score a block's gathered rows against its queries from a resident
+    packed table: ``score_gather_block``'s result without a row DMA.
+
+    qid_ref: (1, block_b) SMEM      query row ids
+    gat_ref: (block_b, G) SMEM      gathered (clipped) row ids
+    qid_v_ref / gat_v_ref           the same ids as VMEM vectors (lanes)
+    x_ref: (R, d*128) VMEM          ``pack_rows`` table
+    acc: (block_b, G) VMEM          squared-distance accumulator
+    q_scr: (sub_b, d*128) / c_scr: (sub_b, G, d*128) VMEM staging
+    """
+    block_b, G = acc.shape
+    sub_b = q_scr.shape[0]
+
+    def body(p, _):
+        base = pl.multiple_of(p * sub_b, sub_b)
+
+        def row(lr, _):
+            r = base + lr
+            q_scr[pl.ds(lr, 1)] = x_ref[pl.ds(qid_ref[0, r] >> ROW_SHIFT, 1)]
+            for k in range(G):
+                c_scr[lr, pl.ds(k, 1)] = x_ref[
+                    pl.ds(gat_ref[r, k] >> ROW_SHIFT, 1)]
+            return _
+
+        jax.lax.fori_loop(0, sub_b, row, None)
+        q_lane = qid_v_ref[pl.ds(base, sub_b)] & (LANES - 1)
+        c_lane = gat_v_ref[pl.ds(base, sub_b)] & (LANES - 1)
+        q = unpack_rows(q_scr[...], q_lane, d)              # (sub_b, 128)
+        c = unpack_rows(c_scr[...], c_lane[:, :, None], d)  # (sub_b, G, 128)
+        diff = q[:, None, :] - c
+        acc[pl.ds(base, sub_b)] = jnp.sum(diff * diff, axis=-1)
+        return _
+
+    jax.lax.fori_loop(0, block_b // sub_b, body, None)
+
+
 def _make_cand_kernel(*, sources, n_first, first_widths, have_chain,
                       have_extra, have_active, rescore, k_cur, n_rows,
-                      m_size, block_m, sub_b, persistent_q):
+                      m_size, block_m, sub_b, persistent_q, d, vmem_rows,
+                      vmem_flags):
     """Build the kernel body for one static candidate-fused config."""
     from repro.core import knn as knn_lib   # deferred: core imports kernels
 
@@ -380,14 +450,22 @@ def _make_cand_kernel(*, sources, n_first, first_widths, have_chain,
         first_v = [next(it) for _ in range(n_first)]
         chain_v = next(it) if have_chain else None
         extra_v = next(it) if have_extra else None
-        act_ref = next(it) if have_active else None  # (N/128, 128) i32 ANY
-        x_ref = next(it)                            # (N, M) ANY
+        # (R, 128) packed flags: ANY (dma) or VMEM (vmem)
+        act_ref = next(it) if have_active else None
+        # (N, M) lane-padded ANY (dma) or (R, d*128) packed VMEM (vmem)
+        x_ref = next(it)
         idx_out, d_out, imp_out = next(it), next(it), next(it)
-        acc, q_scr, c_scr, q_sem, c_sem = (next(it), next(it), next(it),
-                                           next(it), next(it))
+        acc = next(it)                              # (block_b, G) VMEM
+        if vmem_rows:
+            q_scr, c_scr, gat_v = next(it), next(it), next(it)
+        else:
+            q_scr, c_scr, q_sem, c_sem = (next(it), next(it), next(it),
+                                          next(it))
         gat_smem = next(it)                         # (block_b, G) SMEM
         cand_vmem = next(it)                        # (block_b, C) VMEM
-        if have_active:
+        if have_active and vmem_flags:
+            flag_scr = next(it)                     # (rows, C, 128)
+        elif have_active:
             act_rows, act_sem = next(it), next(it)  # (block_b, C, 128)
 
         j = pl.program_id(1)
@@ -425,7 +503,7 @@ def _make_cand_kernel(*, sources, n_first, first_widths, have_chain,
                     else:                     # extra
                         v = extra_s[r, ent["e"]]
                     gat_smem[r, koff + g] = jnp.clip(v, 0, n_rows - 1)
-                    if have_active:
+                    if have_active and not vmem_flags:
                         act_copy(r, g).start()
                 return _
             jax.lax.fori_loop(0, block_b, fill_row, None)
@@ -433,6 +511,8 @@ def _make_cand_kernel(*, sources, n_first, first_widths, have_chain,
             # vector pass: the same draws on VPU lanes feed the merge's
             # dedup compares (raw ids, SENTINELs preserved)
             rows_v = qid_v_ref[...]                      # (block_b, 1)
+            if vmem_rows and rescore:      # clipped ids: unpack lanes
+                gat_v[:, :k_cur] = jnp.clip(cur_idx_ref[...], 0, n_rows - 1)
             g0 = 0
             for src in sources:
                 kind, c = src[0], src[-1]
@@ -455,30 +535,47 @@ def _make_cand_kernel(*, sources, n_first, first_widths, have_chain,
                     e0 = next(e["e"] for e in slots if e["g"] == g0)
                     blk = extra_v[:, e0:e0 + c]
                 cand_vmem[:, g0:g0 + c] = blk.astype(jnp.int32)
+                if vmem_rows:
+                    gat_v[:, koff + g0:koff + g0 + c] = jnp.clip(
+                        blk.astype(jnp.int32), 0, n_rows - 1)
                 g0 += c
 
-        score_gather_block(qid_ref, gat_smem, x_ref, acc, q_scr, c_scr,
-                           q_sem, c_sem, m_size=m_size, block_m=block_m,
-                           sub_b=sub_b, persistent_q=persistent_q)
+        if vmem_rows:
+            score_packed_block(qid_ref, gat_smem, qid_v_ref, gat_v, x_ref,
+                               acc, q_scr, c_scr, d=d)
+        else:
+            score_gather_block(qid_ref, gat_smem, x_ref, acc, q_scr, c_scr,
+                               q_sem, c_sem, m_size=m_size, block_m=block_m,
+                               sub_b=sub_b, persistent_q=persistent_q)
 
         @pl.when(j == pl.num_programs(1) - 1)
         def _merge():
-            if have_active:
+            if have_active and not vmem_flags:
                 def drain(r, _):
                     for ent in slots:
                         act_copy(r, ent["g"]).wait()
                     return _
                 jax.lax.fori_loop(0, block_b, drain, None)
 
+            def flag_rows(sl):
+                """(rows, C, 128) flag rows of the slice's candidates."""
+                if not vmem_flags:
+                    return act_rows[sl]
+
+                def load(lr, _):
+                    r = sl.start + lr
+                    for ent in slots:
+                        g = ent["g"]
+                        flag_scr[lr, pl.ds(g, 1)] = act_ref[
+                            pl.ds(gat_smem[r, koff + g] >> ROW_SHIFT, 1)]
+                    return _
+                jax.lax.fori_loop(0, sl.size, load, None)
+                return flag_scr[...]
+
             def rows(sl):
                 cand = cand_vmem[sl]
                 if have_active:
-                    # the candidate's flag is lane (id mod 128) of its row
-                    lane = jnp.clip(cand, 0, n_rows - 1) & 127
-                    ll = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 128), 2)
-                    ext_valid = jnp.sum(jnp.where(lane[:, :, None] == ll,
-                                                  act_rows[sl], 0),
-                                        axis=2) != 0
+                    ext_valid = pick_flags(flag_rows(sl), cand, n_rows)
                 else:
                     # all-true, computed (a literal bool array would be a
                     # captured kernel constant)
@@ -521,7 +618,8 @@ def _chain_picks(salt, qid, sources, first_tables, second_tables):
 
 @functools.partial(
     jax.jit, static_argnames=("sources", "rescore", "block_b", "block_m",
-                              "sub_b", "persistent_q", "interpret"))
+                              "sub_b", "persistent_q", "row_source",
+                              "flag_source", "interpret"))
 def knn_merge_cand_pallas(
     x: jnp.ndarray,
     qid: jnp.ndarray,
@@ -539,6 +637,8 @@ def knn_merge_cand_pallas(
     block_m: int = 512,
     sub_b: int = None,
     persistent_q: bool = None,
+    row_source: str = "dma",
+    flag_source: str = "dma",
     interpret: bool = False,
 ):
     """Candidate-fused refinement: sample, score, dedup and merge in ONE
@@ -551,10 +651,17 @@ def knn_merge_cand_pallas(
     (tuple of (N2, K2) tables for the two-hop picks) and optional
     ``extra`` precomputed slots.  ``active`` is the global (N,) bool
     membership mask (None == all rows active): per-candidate flags are
-    DMA'd in-kernel, matching ``active[clip(cand)]`` on the ref.
+    read in-kernel, matching ``active[clip(cand)]`` on the ref.
+    ``row_source`` / ``flag_source`` (static 'dma' or 'vmem', see the
+    block comment above) say where rows and flags are read from; every
+    combination gives the same results.
     """
-    x = pad_lanes(x)
-    N, M = x.shape
+    assert row_source in ("dma", "vmem"), row_source
+    assert flag_source in ("dma", "vmem"), flag_source
+    N, d = x.shape
+    vmem_rows = row_source == "vmem"
+    x = pack_rows(x) if vmem_rows else pad_lanes(x)
+    M = x.shape[1]
     B, K = cur_idx.shape
     # zero-width sources are legal in the grammar but contribute no
     # slots; drop them here so the static slot plan and the vector-pass
@@ -570,6 +677,7 @@ def knn_merge_cand_pallas(
         assert extra is not None and extra.shape == (B, n_extra), \
             (n_extra, None if extra is None else extra.shape)
     have_active = active is not None
+    vmem_flags = have_active and flag_source == "vmem"
     G = C + (K if rescore else 0)
 
     qid = qid.astype(jnp.int32)
@@ -586,13 +694,15 @@ def knn_merge_cand_pallas(
     if have_extra:
         extra = extra.astype(jnp.int32)
     if have_active:
-        act = pad_lanes(active.astype(jnp.int32)[None, :]).reshape(-1, LANES)
+        act = pack_rows(active[:, None])
 
+    # a packed table is scored whole: one M chunk of the packed width
     block_b, block_m, sub_b, persistent_q, n_mchunks, q_scr_shape = \
         plan_row_gather(B, M, G, x.dtype.itemsize, block_b=block_b,
-                        block_m=block_m, sub_b=sub_b,
-                        persistent_q=persistent_q,
-                        row_bytes=C * LANES * 4 if have_active else 0)
+                        block_m=M if vmem_rows else block_m, sub_b=sub_b,
+                        persistent_q=False if vmem_rows else persistent_q,
+                        row_bytes=C * LANES * 4
+                        if have_active and not vmem_flags else 0)
     Bp = _round_up(B, block_b)
     if Bp != B:
         pad = Bp - B
@@ -633,31 +743,49 @@ def knn_merge_cand_pallas(
     for t in slabs:
         operands.append(t)
         in_specs.append(blk(t.shape[1]))
+
+    def table(t, resident):
+        # a resident table is one block fetched once and held for the
+        # launch; otherwise it stays in HBM for in-kernel row DMAs
+        if resident:
+            return pl.BlockSpec(t.shape, lambda i, j: (0, 0),
+                                pipeline_mode=pl.Buffered(1))
+        return pl.BlockSpec(memory_space=pl.ANY)
+
     if have_active:
         operands.append(act)
-        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
+        in_specs.append(table(act, vmem_flags))
     operands.append(x)
-    in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
+    in_specs.append(table(x, vmem_rows))
 
-    scratch = [
-        pltpu.VMEM((block_b, G), jnp.float32),
-        pltpu.VMEM(q_scr_shape, x.dtype),
-        pltpu.VMEM((2, sub_b, G, block_m), x.dtype),
-        pltpu.SemaphoreType.DMA((n_mchunks,)),
-        pltpu.SemaphoreType.DMA((2,)),
-        pltpu.SMEM((block_b, G), jnp.int32),
-        pltpu.VMEM((block_b, C), jnp.int32),
-    ]
-    if have_active:
-        scratch += [pltpu.VMEM((block_b, C, LANES), jnp.int32),
+    scratch = [pltpu.VMEM((block_b, G), jnp.float32)]
+    if vmem_rows:
+        scratch += [pltpu.VMEM((sub_b, M), x.dtype),
+                    pltpu.VMEM((sub_b, G, M), x.dtype),
+                    pltpu.VMEM((block_b, G), jnp.int32)]
+    else:
+        scratch += [pltpu.VMEM(q_scr_shape, x.dtype),
+                    pltpu.VMEM((2, sub_b, G, block_m), x.dtype),
+                    pltpu.SemaphoreType.DMA((n_mchunks,)),
+                    pltpu.SemaphoreType.DMA((2,))]
+    scratch += [pltpu.SMEM((block_b, G), jnp.int32),
+                pltpu.VMEM((block_b, C), jnp.int32)]
+    if vmem_flags:
+        scratch.append(pltpu.VMEM((slice_rows(block_b), C, LANES),
+                                  act.dtype))
+    elif have_active:
+        scratch += [pltpu.VMEM((block_b, C, LANES), act.dtype),
                     pltpu.SemaphoreType.DMA(())]
+    limit = {"vmem_limit_bytes": VMEM_ROWS_LIMIT} \
+        if vmem_rows or vmem_flags else {}
 
     kernel = _make_cand_kernel(
         sources=sources, n_first=len(first_tables),
         first_widths=tuple(f.shape[1] for f in first_tables),
         have_chain=have_chain, have_extra=have_extra,
         have_active=have_active, rescore=rescore, k_cur=K, n_rows=N,
-        m_size=M, block_m=block_m, sub_b=sub_b, persistent_q=persistent_q)
+        m_size=M, block_m=block_m, sub_b=sub_b, persistent_q=persistent_q,
+        d=d, vmem_rows=vmem_rows, vmem_flags=vmem_flags)
 
     grid = (Bp // block_b, n_mchunks)
     outs = pl.pallas_call(
@@ -673,7 +801,7 @@ def knn_merge_cand_pallas(
         ],
         scratch_shapes=scratch,
         compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary"), **limit),
         interpret=interpret,
     )(*operands)
     new_idx, new_d, imp = outs

@@ -10,6 +10,9 @@ Backend selection matches the other kernel packages:
 """
 from __future__ import annotations
 
+import contextlib
+
+import jax
 import jax.numpy as jnp
 
 from repro.kernels import fallback
@@ -17,6 +20,26 @@ from repro.kernels.backend import resolve
 from repro.kernels.knn_merge.kernel import (knn_merge_cand_pallas,
                                             knn_merge_pallas)
 from repro.kernels.knn_merge.ref import knn_merge_cand_ref, knn_merge_ref
+from repro.kernels.pairwise_sqdist.kernel import (VMEM_ROWS_BUDGET,
+                                                  packed_bytes, row_source)
+
+
+def merge_sources(n: int, d: int) -> tuple:
+    """(row source, flag source) of a candidate-fused launch over an
+    (n, d) source matrix, each ``'vmem'`` (resident packed table) or
+    ``'dma'`` (one HBM DMA per id).  A function of the static shape alone.
+
+    The launch's resident tables share ``VMEM_ROWS_BUDGET``.  The row
+    table is placed first (``row_source``: d small, packing within the
+    budget), since it saves G+1 DMAs per query against the flag table's
+    C; the (n/128, 128) flag table takes what is left, so where both do
+    not fit the flags give way first.
+    """
+    rows = row_source(n, d)
+    used = packed_bytes(n, d) if rows == "vmem" else 0
+    flags = "vmem" if used + packed_bytes(n, 1) <= VMEM_ROWS_BUDGET \
+        else "dma"
+    return rows, flags
 
 
 def knn_merge(x, qid, cur_idx, cur_d, cand=None, *, cand_active=None,
@@ -89,13 +112,21 @@ def knn_merge(x, qid, cur_idx, cur_d, cand=None, *, cand_active=None,
             return run_ref()
         if backend in ("pallas", "interpret"):
             cur_w = cur_valid if rescore else cur_d
-            return fallback.guarded(
-                "knn_merge",
-                lambda: knn_merge_cand_pallas(
-                    x, qid, cur_idx, cur_w, salt, first_tables,
-                    second_tables, cand, active, sources=sources,
-                    rescore=rescore, interpret=(backend == "interpret")),
-                run_ref)
+            rows, flags = merge_sources(*x.shape)
+
+            def run_kernel():
+                # HLO metadata only (outside the kernel's jit, so the
+                # launch keeps its name): a trace shows which sources ran
+                flag_scope = contextlib.nullcontext() if active is None \
+                    else jax.named_scope(f"knn_merge.{flags}_flags")
+                with jax.named_scope(f"knn_merge.{rows}_rows"), flag_scope:
+                    return knn_merge_cand_pallas(
+                        x, qid, cur_idx, cur_w, salt, first_tables,
+                        second_tables, cand, active, sources=sources,
+                        rescore=rescore, row_source=rows, flag_source=flags,
+                        interpret=(backend == "interpret"))
+
+            return fallback.guarded("knn_merge", run_kernel, run_ref)
         raise ValueError(f"unknown backend {backend!r}")
 
     def run_ref():

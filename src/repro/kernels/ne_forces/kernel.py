@@ -34,7 +34,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.compat import tpu_compiler_params
-from repro.kernels.pairwise_sqdist.kernel import LANES, pad_lanes, smem_ids
+from repro.kernels.pairwise_sqdist.kernel import (LANES, ROW_SHIFT,
+                                                  VMEM_ROWS_LIMIT, pack_rows,
+                                                  pad_lanes, smem_ids,
+                                                  unpack_rows)
 
 
 def _edge_wsum(delta, coef, alpha, mode: str):
@@ -128,8 +131,8 @@ def _round_up(x: int, mult: int) -> int:
 # Segment boundaries are static config, so each segment's closed-form tail
 # power is compiled straight-line -- no per-edge mode mask is evaluated.
 #
-# Row sources (static ``row_source``, chosen by ``ops.row_source`` from
-# (N, d) alone):
+# Row sources (static ``row_source``, chosen by ``row_source`` in
+# ``pairwise_sqdist.kernel`` from (N, d) alone):
 #
 # * ``'dma'``: Mosaic DMAs only lane-aligned rows, so the embedding is read
 #   lane-padded (``pad_lanes``: d -> 128, zero columns give zero deltas)
@@ -137,57 +140,15 @@ def _round_up(x: int, mult: int) -> int:
 #   double-buffered: rows are processed in ``sub_b`` sub-blocks through
 #   2-slot VMEM staging with sub-block p+1's row DMAs started before
 #   sub-block p is computed.
-# * ``'vmem'``: at small d the whole embedding fits VMEM once packed
-#   lane-dense (``pack_rows``: 128 points per row, one 128-lane tile per
-#   coordinate -- the embedding's own HBM tiling, so packing is a pad and
-#   one cheap copy), so it stays resident for the launch.  Each id costs
-#   one on-core load of its packed row (row ``id >> 7``, addressed from
-#   SMEM) into the same staging, and a one-hot select on lane
-#   ``id & 127`` moves the point's d coordinates to lanes 0..d-1
-#   (``_unpack_rows``): the math then sees exactly the rows the DMA path
-#   stages.
+# * ``'vmem'``: at small d the embedding is packed lane-dense and held in
+#   VMEM for the launch (``pack_rows`` / ``unpack_rows``, shared with the
+#   merge kernel in ``pairwise_sqdist.kernel``): each id costs one on-core
+#   load of its packed row into single-slot staging and a one-hot lane
+#   select, so the math sees exactly the rows the DMA path stages.
 #
 # Either way the math runs on lane-padded rows and outputs keep their true
 # width d.  Index slabs are staged into SMEM by the pipeline
 # (O(block_b * K), never O(B)).
-
-
-_ROW_SHIFT = LANES.bit_length() - 1     # point id -> its packed row
-
-
-def pack_rows(x):
-    """(N, d) -> (R, d*128) f32, R = N/128 rounded up to the 8-row tile.
-
-    Point i's coordinate c sits at row ``i >> 7``, lane
-    ``c*128 + (i & 127)``; padding rows are zero.  On a TPU an (N, d)
-    f32 array is stored in (d, 128)-point tiles already, so this is a
-    pad and one cheap copy (an interleaved (N*d/128, 128) packing is a
-    relayout that XLA takes ~20 s to compile at N=70,000).
-    """
-    n, d = x.shape
-    x = jnp.pad(x.astype(jnp.float32), ((0, -n % (8 * LANES)), (0, 0)))
-    return x.reshape(-1, LANES, d).transpose(0, 2, 1).reshape(-1, d * LANES)
-
-
-def packed_bytes(n: int, d: int) -> int:
-    """Bytes of :func:`pack_rows` of an (n, d) embedding."""
-    return _round_up(n, 8 * LANES) * d * 4
-
-
-def _unpack_rows(rows, lane, d: int):
-    """Packed rows (..., d*128) of points at lane ``lane`` (..., 1) of
-    each 128-lane tile -> (..., 128) with the point's d coordinates at
-    lanes 0..d-1, zeros elsewhere.  One-hot selects: every coordinate
-    comes out exactly."""
-    shape = rows.shape[:-1] + (LANES,)
-    ll = jax.lax.broadcasted_iota(jnp.int32, shape, len(shape) - 1)
-    hit = ll == lane
-    out = jnp.zeros(shape, rows.dtype)
-    for c in range(d):
-        v = jnp.sum(jnp.where(hit, rows[..., c * LANES:(c + 1) * LANES],
-                              0.0), axis=-1, keepdims=True)
-        out = jnp.where(ll == c, v, out)
-    return out
 
 
 def _dma_query_and_neighbour_rows(x_ref, qid_ref, nbr_ref, q_scr, n_scr, sem):
@@ -257,18 +218,18 @@ def _ne_forces_gather_kernel(qid_ref, nbr_ref, alpha_ref, coef_ref, *refs,
 
             def row(lr, _):
                 r = base + lr
-                q_scr[pl.ds(lr, 1)] = x_ref[pl.ds(qid_ref[0, r] >> _ROW_SHIFT,
+                q_scr[pl.ds(lr, 1)] = x_ref[pl.ds(qid_ref[0, r] >> ROW_SHIFT,
                                                   1)]
                 for k in range(K):
                     n_scr[lr, pl.ds(k, 1)] = x_ref[
-                        pl.ds(nbr_ref[r, k] >> _ROW_SHIFT, 1)]
+                        pl.ds(nbr_ref[r, k] >> ROW_SHIFT, 1)]
                 return _
 
             jax.lax.fori_loop(0, sub_b, row, None)
             q_lane = qid_v_ref[pl.ds(base, sub_b)] & (LANES - 1)
             n_lane = nbr_v_ref[pl.ds(base, sub_b)] & (LANES - 1)
-            return (_unpack_rows(q_scr[...], q_lane, d),
-                    _unpack_rows(n_scr[...], n_lane[:, :, None], d))
+            return (unpack_rows(q_scr[...], q_lane, d),
+                    unpack_rows(n_scr[...], n_lane[:, :, None], d))
     else:
         q_scr, n_scr, sem = refs[2 * S + E:]
 
@@ -328,12 +289,6 @@ def _pick_sub_b(block_b: int) -> int:
     if block_b <= 16 or block_b % 8:
         return block_b
     return 8
-
-
-# scoped-VMEM limit of the gather kernel's 'vmem' row source: the resident
-# packed embedding (``ops.VMEM_ROWS_BUDGET`` at most) outgrows Mosaic's
-# 16 MiB default; a TPU v5e core has 128 MiB of VMEM
-VMEM_ROWS_LIMIT = 64 * 2 ** 20
 
 
 @functools.partial(

@@ -7,10 +7,10 @@ from repro.kernels import fallback
 from repro.kernels.backend import resolve
 from repro.kernels.ne_forces.kernel import (ne_forces_gather_pallas,
                                             ne_forces_pallas,
-                                            ne_forces_scatter_pallas,
-                                            packed_bytes)
+                                            ne_forces_scatter_pallas)
 from repro.kernels.ne_forces.ref import (ne_forces_gather_ref, ne_forces_ref,
                                          ne_forces_scatter_ref)
+from repro.kernels.pairwise_sqdist.kernel import row_source
 
 
 # VMEM budget for the scatter kernel's resident per-segment (chunk_n, d)
@@ -35,24 +35,6 @@ def scatter_chunk_plan(n: int, d: int, n_segments: int) -> int:
     if max_rows >= n:
         return n
     return max(8, (max_rows // 8) * 8)      # keep sublane-tile alignment
-
-
-# Largest packed embedding the edge-mode kernel holds in VMEM for a launch
-# (``kernel.pack_rows``, within ``kernel.VMEM_ROWS_LIMIT``): d=2 up to
-# n = 2^21, d=4 up to 2^20.  Wider or larger embeddings keep one HBM row
-# DMA per id.
-VMEM_ROWS_BUDGET = 16 * 2 ** 20
-VMEM_ROWS_MAX_D = 4
-
-
-def row_source(n: int, d: int) -> str:
-    """Where the edge-mode force kernel reads neighbour rows for an (n, d)
-    embedding: ``'vmem'`` (resident packed table) when d is small and the
-    packing fits ``VMEM_ROWS_BUDGET``, else ``'dma'`` (one HBM row DMA per
-    id).  A function of the static shape alone."""
-    if d <= VMEM_ROWS_MAX_D and packed_bytes(n, d) <= VMEM_ROWS_BUDGET:
-        return "vmem"
-    return "dma"
 
 
 def ne_forces(y, nbr, coef, alpha, *, mode: str, backend: str = "auto"):

@@ -118,6 +118,78 @@ def smem_ids(ids, block_b: int, grid_axis: int = 0):
 
 
 # --------------------------------------------------------------------------
+# Resident packed tables, shared by the row-gather kernels.
+#
+# A row-gather kernel that DMAs one lane-padded row per id is bound by
+# neither bytes nor arithmetic at small widths: a v5e spends ~33-45 ns per
+# 512-byte row DMA.  At d <= ``VMEM_ROWS_MAX_D`` a whole (N, d) table fits
+# VMEM once packed lane-dense (``pack_rows``: 128 points per row, one
+# 128-lane tile per coordinate -- the table's own HBM tiling, so packing
+# is a pad and one cheap copy), so it stays resident for the launch.  Each
+# id then costs one on-core load of its packed row (row ``id >> 7``,
+# addressed from SMEM), and a one-hot select on lane ``id & 127`` moves
+# the point's d coordinates to lanes 0..d-1 (``unpack_rows``): the math
+# sees exactly the rows the DMA path stages.
+
+ROW_SHIFT = LANES.bit_length() - 1      # point id -> its packed row
+
+# Largest packed tables one launch holds in VMEM, all of its resident
+# tables together: a d=2 embedding up to n = 2^21, d=4 up to 2^20.
+# Wider or larger tables keep one HBM row DMA per id.
+VMEM_ROWS_BUDGET = 16 * 2 ** 20
+VMEM_ROWS_MAX_D = 4
+
+# scoped-VMEM limit of a launch that holds resident tables: they outgrow
+# Mosaic's 16 MiB default; a TPU v5e core has 128 MiB of VMEM
+VMEM_ROWS_LIMIT = 64 * 2 ** 20
+
+
+def pack_rows(x):
+    """(N, d) -> (R, d*128) f32, R = N/128 rounded up to the 8-row tile.
+
+    Point i's coordinate c sits at row ``i >> 7``, lane
+    ``c*128 + (i & 127)``; padding rows are zero.  On a TPU an (N, d)
+    f32 array is stored in (d, 128)-point tiles already, so this is a
+    pad and one cheap copy (an interleaved (N*d/128, 128) packing is a
+    relayout that XLA takes ~20 s to compile at N=70,000).
+    """
+    n, d = x.shape
+    x = jnp.pad(x.astype(jnp.float32), ((0, -n % (8 * LANES)), (0, 0)))
+    return x.reshape(-1, LANES, d).transpose(0, 2, 1).reshape(-1, d * LANES)
+
+
+def packed_bytes(n: int, d: int) -> int:
+    """Bytes of :func:`pack_rows` of an (n, d) table."""
+    return _round_up(n, 8 * LANES) * d * 4
+
+
+def unpack_rows(rows, lane, d: int):
+    """Packed rows (..., d*128) of points at lane ``lane`` (..., 1) of
+    each 128-lane tile -> (..., 128) with the point's d coordinates at
+    lanes 0..d-1, zeros elsewhere.  One-hot selects: every coordinate
+    comes out exactly."""
+    shape = rows.shape[:-1] + (LANES,)
+    ll = jax.lax.broadcasted_iota(jnp.int32, shape, len(shape) - 1)
+    hit = ll == lane
+    out = jnp.zeros(shape, rows.dtype)
+    for c in range(d):
+        v = jnp.sum(jnp.where(hit, rows[..., c * LANES:(c + 1) * LANES],
+                              0.0), axis=-1, keepdims=True)
+        out = jnp.where(ll == c, v, out)
+    return out
+
+
+def row_source(n: int, d: int) -> str:
+    """Where a row-gather kernel reads the rows of an (n, d) table:
+    ``'vmem'`` (resident packed table) when d is small and the packing
+    fits ``VMEM_ROWS_BUDGET``, else ``'dma'`` (one HBM row DMA per id).
+    A function of the static shape alone."""
+    if d <= VMEM_ROWS_MAX_D and packed_bytes(n, d) <= VMEM_ROWS_BUDGET:
+        return "vmem"
+    return "dma"
+
+
+# --------------------------------------------------------------------------
 # Gather-fused variant: the kernel takes *indices*, not gathered operands.
 #
 # The pre-gather kernel above forces XLA to materialise X[cand] as an

@@ -6,7 +6,8 @@ collection time -- so import ``given``/``settings``/``st`` from here instead
 of from ``hypothesis`` directly.
 """
 try:
-    from hypothesis import given, settings, strategies as st  # noqa: F401
+    from hypothesis import example, given, settings  # noqa: F401
+    from hypothesis import strategies as st  # noqa: F401
     HAVE_HYPOTHESIS = True
 except ImportError:  # pragma: no cover - exercised only without hypothesis
     import pytest
@@ -25,6 +26,9 @@ except ImportError:  # pragma: no cover - exercised only without hypothesis
     st = _AnyStrategy()
 
     def settings(*args, **kwargs):
+        return lambda fn: fn
+
+    def example(*args, **kwargs):
         return lambda fn: fn
 
     def given(*args, **kwargs):

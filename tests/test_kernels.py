@@ -320,6 +320,118 @@ def test_ne_forces_row_source(n, d, want):
     assert row_source(n, d) == want
 
 
+def _cand_merge_problem(n, d, b, k, seed, *, quantised):
+    """A candidate-fused merge problem with every id kind: repeated
+    queries and current ids, SENTINEL in the current list, the one-hop
+    table and the extra slots, extra ids past both ends, inactive rows."""
+    from repro.core.knn import SENTINEL
+    rng = np.random.default_rng(seed)
+    x = rng.integers(-8, 9, (n, d)) / 4.0 if quantised \
+        else rng.normal(size=(n, d))
+    x = jnp.asarray(x.astype(np.float32))
+    qid = rng.integers(0, n, b).astype(np.int32)
+    qid[1] = qid[0]
+    cur_idx = rng.integers(0, n, (b, k)).astype(np.int32)
+    cur_idx[:, 1] = cur_idx[:, 0]
+    sent = np.sort(rng.random((b, k)) < 0.2, axis=1)
+    cur_idx[sent] = SENTINEL
+    d0 = np.array(jnp.sum((x[jnp.clip(jnp.asarray(cur_idx), 0, n - 1)]
+                           - x[qid][:, None, :]) ** 2, axis=-1))
+    d0[sent] = np.inf
+    order = np.argsort(d0, axis=1, kind="stable")
+    cur_idx = np.take_along_axis(cur_idx, order, axis=1)
+    oth = rng.integers(0, n, (b, 5)).astype(np.int32)
+    oth[rng.random((b, 5)) < 0.15] = SENTINEL
+    sec = rng.integers(0, n, (n, 6)).astype(np.int32)
+    extra = rng.integers(-2, n + 3, (b, 2)).astype(np.int32)
+    extra[0] = SENTINEL
+    return dict(
+        x=x, qid=jnp.asarray(qid), cur_idx=jnp.asarray(cur_idx),
+        cur_d=jnp.asarray(np.take_along_axis(d0, order, axis=1)),
+        cur_valid=jnp.asarray((cur_idx != SENTINEL)
+                              & (rng.random((b, k)) < 0.9)),
+        firsts=(jnp.asarray(cur_idx), jnp.asarray(oth)),
+        seconds=(jnp.asarray(sec),), extra=jnp.asarray(extra),
+        active=jnp.asarray(rng.random(n) >= 0.15))
+
+
+MERGE_SOURCES = (("two_hop", 0, 0, 3), ("one_hop", 1, 2), ("uniform", 2),
+                 ("extra", 2))
+
+
+def _cand_merge(p, rescore, use_active, **kw):
+    from repro.kernels.knn_merge.kernel import knn_merge_cand_pallas
+    return knn_merge_cand_pallas(
+        p["x"], p["qid"], p["cur_idx"],
+        p["cur_valid"] if rescore else p["cur_d"], jnp.int32(77),
+        p["firsts"], p["seconds"], p["extra"],
+        p["active"] if use_active else None, sources=MERGE_SOURCES,
+        rescore=rescore, interpret=True, **kw)
+
+
+@pytest.mark.parametrize("d,n,b,bb,rescore,use_active,rows,flags", [
+    (d, n, b, bb, rescore, True, "vmem", "vmem")
+    for d in (1, 2, 3, 4)
+    for n, b, bb in ((1100, 37, 16),     # N % 128, B % bb
+                     (70, 64, 32))       # one packed row
+    for rescore in (True, False)
+] + [
+    (2, 1100, 37, 16, True, True, "vmem", "dma"),    # flags gave way
+    (2, 1100, 37, 16, False, True, "dma", "vmem"),   # the HD launch's
+    (4, 70, 64, 32, True, True, "vmem", "dma"),
+    (3, 70, 64, 32, False, True, "dma", "vmem"),
+    (2, 1100, 37, 16, True, False, "vmem", "vmem"),  # active=None
+    (3, 70, 64, 32, False, False, "vmem", "vmem"),
+])
+def test_knn_merge_cand_vmem_sources_parity(d, n, b, bb, rescore,
+                                            use_active, rows, flags):
+    """Resident packed row and flag tables give the DMA sources' outputs
+    bit for bit, and the reference's, in both modes: the LD rescore
+    launch and the HD launch."""
+    from repro.kernels.knn_merge.ref import knn_merge_cand_ref
+    kw = dict(block_b=bb, row_source=rows, flag_source=flags)
+    p = _cand_merge_problem(n, d, b, 6, seed=n + 10 * d + rescore,
+                            quantised=True)
+    got = _cand_merge(p, rescore, use_active, **kw)
+    dma = _cand_merge(p, rescore, use_active, block_b=bb)
+    want = knn_merge_cand_ref(
+        p["x"], p["qid"], p["cur_idx"], None if rescore else p["cur_d"],
+        salt=jnp.int32(77), sources=MERGE_SOURCES, first_tables=p["firsts"],
+        second_tables=p["seconds"], extra=p["extra"],
+        active=p["active"] if use_active else None,
+        cur_valid=p["cur_valid"] if rescore else None)
+    for g, dd, w, name in zip(got, dma, want, ("idx", "d", "improved")):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(dd),
+                                      err_msg=f"vs dma: {name}")
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w),
+                                      err_msg=f"vs ref: {name}")
+    # unquantised coordinates: distance sums that round, the same bits
+    p = _cand_merge_problem(n, d, b, 6, seed=n + d, quantised=False)
+    got = _cand_merge(p, rescore, use_active, **kw)
+    dma = _cand_merge(p, rescore, use_active, block_b=bb)
+    for g, dd, name in zip(got, dma, ("idx", "d", "improved")):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(dd),
+                                      err_msg=f"unquantised: {name}")
+
+
+@pytest.mark.parametrize("n,d,want", [
+    (262144, 2, ("vmem", "vmem")),      # atlas-262k, LD launch
+    (70000, 2, ("vmem", "vmem")),       # mnist-70k, LD launch
+    (1306112, 2, ("vmem", "vmem")),     # published atlas: 15.7 of 16.8 MB
+    (1397760, 2, ("vmem", "vmem")),     # both tables at the budget's edge
+    (1397761, 2, ("vmem", "dma")),      # one tile past it: flags give way
+    (2 ** 21, 2, ("vmem", "dma")),      # the row table alone at the edge
+    (2 ** 21 + 1, 2, ("dma", "vmem")),
+    (16384, 32, ("dma", "vmem")),       # backbone features
+    (262144, 50, ("dma", "vmem")),      # the HD launch
+    (2 ** 22, 50, ("dma", "vmem")),     # the flag table alone at the edge
+    (2 ** 22 + 1, 50, ("dma", "dma")),
+])
+def test_knn_merge_sources(n, d, want):
+    from repro.kernels.knn_merge.ops import merge_sources
+    assert merge_sources(n, d) == want
+
+
 def test_ne_forces_action_reaction():
     """Aggregated force equals the sum of edge forces (Newton pairs)."""
     rng = np.random.default_rng(3)

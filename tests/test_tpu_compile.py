@@ -17,6 +17,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.core import funcsne
 from repro.kernels.knn_merge.kernel import (knn_merge_cand_pallas,
                                             knn_merge_pallas)
+from repro.kernels.knn_merge.ops import merge_sources
 from repro.kernels.ne_forces.kernel import (ne_forces_gather_pallas,
                                             ne_forces_scatter_pallas)
 from repro.kernels.ne_forces.ops import row_source, scatter_chunk_plan
@@ -116,6 +117,23 @@ def test_knn_merge_cand_ld_compiles_for_v5e(spec, d):
     assert _tpu_kernels(c) == ["knn_merge_cand"]
 
 
+@pytest.mark.parametrize("n", [262144, 70000])
+def test_knn_merge_cand_ld_vmem_sources_compile_for_v5e(spec, n):
+    """The LD launch with its resident packed Y and flag tables at the
+    benchmark cells' sizes (d=2), the packing included: one Mosaic
+    launch, under its name."""
+    assert merge_sources(n, 2) == ("vmem", "vmem")
+    sources = (("two_hop", 0, 0, CFG.c_ld_non), ("one_hop", 1, CFG.c_ld_hd),
+               ("uniform", CFG.c_ld_rand))
+    c = _compile(lambda y, q, ci, cv, salt, f0, f1, act: knn_merge_cand_pallas(
+        y, q, ci, cv, salt, (f0, f1), (f0,), None, act, sources=sources,
+        rescore=True, row_source="vmem", flag_source="vmem"),
+        spec((n, 2)), _i32(spec, n), _i32(spec, n, CFG.k_ld),
+        spec((n, CFG.k_ld), jnp.bool_), _i32(spec), _i32(spec, n, CFG.k_ld),
+        _i32(spec, n, CFG.k_hd), spec((n,), jnp.bool_))
+    assert _tpu_kernels(c) == ["knn_merge_cand"]
+
+
 @pytest.mark.parametrize("d", [2, 32])
 def test_ne_forces_gather_compiles_for_v5e(spec, d):
     c = _compile(lambda y, q, nb, cf, a: ne_forces_gather_pallas(
@@ -175,6 +193,13 @@ def test_default_chunk_program_compiles_for_v5e(chunk_compiled):
     assert "ne_forces_gather_pallas" in kernels, kernels
 
 
+def _op_names(compiled, kernel):
+    """``op_name`` metadata of each launch of ``kernel`` in a module."""
+    return [re.search(r'op_name="([^"]*)"', line).group(1)
+            for line in compiled.as_text().splitlines()
+            if re.match(rf"\s*(?:ROOT )?%{kernel}(?:\.\d+)? = ", line)]
+
+
 def test_chunk_program_force_kernel_reads_vmem_rows(chunk_compiled):
     """At d=2 the step's one force launch takes the resident row source:
     exactly one ``ne_forces_gather*`` kernel, named
@@ -184,12 +209,27 @@ def test_chunk_program_force_kernel_reads_vmem_rows(chunk_compiled):
     assert forces == ["ne_forces_gather_pallas"], kernels
     assert sorted(kernels) == ["knn_merge_cand", "knn_merge_cand",
                                "ne_forces_gather_pallas"], kernels
-    scopes = [re.search(r'op_name="([^"]*)"', line).group(1)
-              for line in chunk_compiled.as_text().splitlines()
-              if re.match(r"\s*(?:ROOT )?%ne_forces_gather_pallas(?:\.\d+)? = ",
-                          line)]
+    scopes = _op_names(chunk_compiled, "ne_forces_gather_pallas")
     assert len(scopes) == 1, scopes
     assert "/ne_forces.vmem_rows/" in scopes[0], scopes
+
+
+def test_chunk_program_merge_sources(chunk_compiled):
+    """At d=2 the LD merge reads resident packed Y rows and the HD merge
+    (M=50) row DMAs; both read resident activity flags.  Each launch is
+    still named ``knn_merge_cand`` and keeps its phase scope outside the
+    source scopes."""
+    scopes = {}
+    for name in _op_names(chunk_compiled, "knn_merge_cand"):
+        phase = PHASE.findall(name)
+        assert len(phase) == 1, name
+        scopes[phase[0]] = name
+    assert sorted(scopes) == ["hd_refine", "ld_refine"], scopes
+    for phase, rows in (("ld_refine", "vmem"), ("hd_refine", "dma")):
+        name = scopes[phase]
+        assert f"/knn_merge.{rows}_rows/knn_merge.vmem_flags/" in name, name
+        assert name.index(f"funcsne.{phase}") \
+            < name.index("knn_merge."), name
 
 
 PHASE = re.compile(r"funcsne\.(hd_refine|sigma_refresh|ld_refine|"
