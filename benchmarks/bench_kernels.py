@@ -38,9 +38,7 @@ import numpy as np
 
 from benchmarks.common import row, timed
 from repro.core.funcsne import FuncSNEConfig
-from repro.core.knn import (counter_candidates, dedup_candidates, key_salt,
-                            merge_knn, sample_direct, sample_hops,
-                            sample_uniform)
+from repro.core.knn import dedup_candidates, merge_knn
 from repro.kernels.knn_merge.ref import knn_merge_ref, knn_merge_rank_ref
 from repro.kernels.ne_forces.ref import (ne_forces_gather_ref, ne_forces_ref,
                                          ne_forces_scatter_ref)
@@ -247,55 +245,6 @@ def run(ns=(2048, 16384), m=192, repeats=10):
         ratio = us_topk / max(us_rank, 1e-9)
         rows.append(row(f"kbench_select_xla_ratio_n{n}", ratio,
                         f"topk_us/merge_us={ratio:.3f} (ratio, not us)"))
-
-        # ---- candidate generation: legacy threefry sampler vs the
-        # counter-hash sampler (§Perf H17).  The A side is _hd_refine's
-        # legacy stack (fold/split + sample_hops' (n, s, K2) two-hop
-        # gather broadcasts); the B side is the jnp reference of the
-        # in-kernel generator (identical draws to the kernel, flat
-        # gathers, zero threefry).
-        hd_tab = jnp.asarray(rng.integers(0, n, (n, k_hd))
-                             .astype(np.int32))
-        ld_tab = jnp.asarray(rng.integers(0, n, (n, k_ld))
-                             .astype(np.int32))
-        key = jax.random.PRNGKey(0)
-        sources = (("two_hop", 0, 0, _DEFAULTS.c_hd_non),
-                   ("one_hop", 1, _DEFAULTS.c_hd_ld),
-                   ("two_hop", 1, 1, _DEFAULTS.c_hd_ld_non),
-                   ("uniform", _DEFAULTS.c_hd_rand))
-
-        def cand_xla(key, hd_tab, ld_tab):
-            r = jax.random.split(jax.random.fold_in(key, 7), 4)
-            return jnp.concatenate([
-                sample_hops(r[0], hd_tab, hd_tab, qid,
-                            _DEFAULTS.c_hd_non),
-                sample_direct(r[1], ld_tab, _DEFAULTS.c_hd_ld),
-                sample_hops(r[2], ld_tab, ld_tab, qid,
-                            _DEFAULTS.c_hd_ld_non),
-                sample_uniform(r[3], n, n, _DEFAULTS.c_hd_rand)], axis=1)
-
-        def cand_fused(key, hd_tab, ld_tab):
-            return counter_candidates(key_salt(key), qid, sources,
-                                      (hd_tab, ld_tab), (hd_tab, ld_tab),
-                                      n_total=n)
-
-        us_xla, us_fus = _bench_pair(cand_xla, cand_fused, key, hd_tab,
-                                     ld_tab, repeats=n_reps)
-        # TPU HBM model for the generation phase alone: the legacy path
-        # materialises the two (n, s, K2) two-hop broadcasts and
-        # round-trips the (n, C) candidate tensor the kernel re-reads;
-        # in-kernel generation fetches one chained int32 element per
-        # two-hop slot and writes nothing.
-        s2 = _DEFAULTS.c_hd_non * k_hd + _DEFAULTS.c_hd_ld_non * k_ld
-        b_xla = 4.0 * n * s2 + 2.0 * 4.0 * n * C
-        b_fus = 4.0 * n * (_DEFAULTS.c_hd_non + _DEFAULTS.c_hd_ld_non)
-        rows.append(row(f"kbench_cand_xla_n{n}", us_xla,
-                        f"modeled_tpu_hbm={_mb(b_xla)};threefry=1"))
-        rows.append(row(f"kbench_cand_fused_n{n}", us_fus,
-                        f"modeled_tpu_hbm={_mb(b_fus)};threefry=0"))
-        ratio = us_xla / max(us_fus, 1e-9)
-        rows.append(row(f"kbench_cand_xla_ratio_n{n}", ratio,
-                        f"xla_us/fused_us={ratio:.3f} (ratio, not us)"))
     return rows
 
 

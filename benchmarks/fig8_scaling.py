@@ -162,51 +162,7 @@ def _audit_rows(n, Xj, iters, T, trials=5):
     ]
 
 
-def _cand_rows(n, iters, trials=3):
-    """Full-step A/B of the candidate-generation phase (§Perf H17):
-    ``cand_fused=False`` (legacy threefry sampler + (n, s, K2) two-hop
-    broadcasts) vs ``cand_fused=True`` (counter-hash sampler; in-kernel
-    generation on the pallas path, flat jnp gathers on this host).
-    Paired/interleaved best-of like the chunked rows."""
-    X, _ = blobs(n=n, dim=32, n_centers=8, center_std=6.0, seed=0)
-    Xj = jnp.asarray(X)
-    hp = funcsne.default_hparams(n)
-    runners = {}
-    for fused in (False, True):
-        cfg = funcsne.FuncSNEConfig(n_points=n, dim_hd=32,
-                                    cand_fused=fused)
-        st0 = funcsne.init_state(jax.random.PRNGKey(0), Xj, cfg)
-        step = funcsne.make_step(cfg)
-        jax.block_until_ready(step(_copy(st0), Xj, hp).Y)    # compile
-
-        def run_one(step=step, st0=st0):
-            s = _copy(st0)
-            for _ in range(iters):
-                s = step(s, Xj, hp)
-            jax.block_until_ready(s.Y)
-            return iters
-
-        runners[fused] = run_one
-
-    best = {f: float("inf") for f in runners}
-    for t in range(trials):
-        order = (False, True) if t % 2 == 0 else (True, False)
-        for f in order:
-            steps, dt = timed(runners[f])
-            best[f] = min(best[f], dt * 1e6 / steps)
-    ratio = best[False] / max(best[True], 1e-9)
-    return [
-        row(f"fig8_cand_xla_n{n}", best[False],
-            "threefry sampler, full step"),
-        row(f"fig8_cand_fused_n{n}", best[True],
-            "counter-fused sampler, full step"),
-        row(f"fig8_cand_ratio_n{n}", ratio,
-            f"xla_us/fused_us={ratio:.3f} (ratio, not us)"),
-    ]
-
-
-def run(sizes=(512, 1024, 2048, 4096), iters=120, chunk_sizes=(1, 50),
-        cand_ns=(2048, 16384), cand_iters=6):
+def run(sizes=(512, 1024, 2048, 4096), iters=120, chunk_sizes=(1, 50)):
     rows = []
     per_iter = {}
     for n in sizes:
@@ -252,12 +208,6 @@ def run(sizes=(512, 1024, 2048, 4096), iters=120, chunk_sizes=(1, 50),
     # chunk-boundary auditor A/B (trusted recovery): worst-case
     # audit_every=1 must stay <=1% next to a full chunk dispatch
     rows += _audit_rows(n, jnp.asarray(X), iters, chunk_sizes[-1])
-
-    # candidate-phase A/B (§Perf H17): more calls at the small size so
-    # sub-ms deltas aren't swamped by dispatch noise
-    for n in cand_ns:
-        rows += _cand_rows(n, max(cand_iters,
-                                  cand_iters * max(cand_ns) // n))
     return rows
 
 
@@ -268,8 +218,8 @@ def main() -> None:
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="write {name: us_per_call} JSON to PATH")
     args = ap.parse_args()
-    kwargs = dict(sizes=(256, 512), iters=16, chunk_sizes=(1, 8),
-                  cand_ns=(256,), cand_iters=4) if args.smoke else {}
+    kwargs = dict(sizes=(256, 512), iters=16, chunk_sizes=(1, 8)) \
+        if args.smoke else {}
     results = {}
     print("name,us_per_call,derived")
     for r in run(**kwargs):

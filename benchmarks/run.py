@@ -29,8 +29,7 @@ FAST_KW = {
     "fig4_feedback_loop": dict(n=600, iters=120, probe_every=60),
     "fig6_rnx_quality": dict(n=600, iters=250),
     "fig7_knn_vs_nnd": dict(n=800, iters=200),
-    "fig8_scaling": dict(sizes=(512, 1024, 2048), iters=60,
-                         cand_ns=(2048, 16384), cand_iters=6),
+    "fig8_scaling": dict(sizes=(512, 1024, 2048), iters=60),
     "table2_one_shot": dict(n=800, iters=300),
     "fig3_alpha_fragmentation": dict(n=700, warmup=250, per_level=150),
     "bench_kernels": dict(ns=(1024, 4096), repeats=5),

@@ -146,7 +146,8 @@ def cfg_compat(cfg) -> dict:
     """Restore-compatibility fingerprint of a ``FuncSNEConfig``-like
     object: the fields a resumed run must agree on for the restored
     state to mean the same thing (array geometry) and for the random
-    streams to continue bit-identically (the fused-flag matrix).
+    streams to continue bit-identically (the step's flags; the three
+    locked ``*_fused`` fields stay so older checkpoints still restore).
     Duck-typed so the checkpoint layer never imports ``repro.core``.
     """
     return {

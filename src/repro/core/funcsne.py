@@ -44,42 +44,19 @@ Driver surface (one traced step, three dispatch granularities):
       (``repro.kernels.fallback``), and ``repro.runtime.faults`` injects
       every one of those failures deterministically in tests/CI.
 
-Config flag matrix (orthogonal, all combinations tested):
-  ``gather_fused``   True: kernels take indices and DMA rows in-kernel
-                     (§H12/H13); False: legacy pre-gather wiring
-                     (bit-equivalence anchor).
-  ``scatter_fused``  True: symmetrisation binned in-kernel into (N, d)
-                     fields (§H14; requires gather_fused); False (the
-                     default): edge-emitting epilogue + XLA scatters.
-  ``merge_fused``    True: the neighbour-selection epilogue (dedup +
-                     sorted top-K merge) runs inside the gather kernel
-                     (§H16; requires gather_fused; the HD phase falls
-                     back under feature-axis sharding); False: XLA
-                     ``dedup_candidates`` + ``merge_knn`` epilogue
-                     (bit-equivalence anchor on the 'xla' backend).
-  ``cand_fused``     True: every per-step random draw comes from the
-                     counter-based hash RNG (§H17) -- the HD/LD
-                     candidates are *generated inside* the merge kernel
-                     (chained two-hop gathers through the second-table
-                     channel) when ``merge_fused`` + ``gather_fused``
-                     supply that kernel, and by the bit-identical
-                     pure-jnp reference sampler otherwise (the 'xla'
-                     backend, ``merge_fused=False``, or the HD
-                     feature-sharding fallback); the refinement gate and
-                     the negative samples use the same counter RNG, so
-                     the step HLO carries NO threefry/random-bits ops
-                     and no (n, s, K2) two-hop gather broadcast.
-                     False: the legacy ``jax.random`` (threefry)
-                     sampler.  NB flipping this flag changes the random
-                     stream, so trajectories differ statistically (not
-                     bitwise) from the legacy path; within
-                     ``cand_fused=True`` all backend / fused-flag
-                     combinations keep their usual parity contracts.
-  ``backend``        'auto' (pallas on TPU else xla; a device that fails
-                     to initialise raises) | 'pallas' | 'interpret' |
-                     'xla'.  The scatter kernel's VMEM plan
-                     (ne_forces/ops.py: N-chunked fields) applies on the
-                     pallas/interpret paths.
+The step has one wiring.  Every per-step draw (refinement gate, HD and LD
+candidates, negatives, reverse-edge fill) comes from the counter-based
+hash RNG (§H17), so the step carries no threefry.  HD and LD refinement
+are each one ``knn_merge`` launch that generates, scores, dedups and
+merges its candidates (§H16/H17); the forces are one segmented
+``ne_forces_gather`` launch (§H12/H13).  One fork remains, chosen from
+what the step can observe: under feature-axis sharding (``ctx.feat``)
+HD refinement needs the psum'd full distances, so it generates the
+candidates with the jnp sampler, scores them with
+``pairwise_sqdist_gather`` + psum and merges them in XLA.
+``cfg.scatter_fused`` picks the force epilogue (§H14, off by default).
+``cfg.backend``: 'auto' (pallas on TPU else xla; a device that fails to
+initialise raises) | 'pallas' | 'interpret' | 'xla' (the pure-jnp refs).
 
 Distribution: inside ``shard_map`` the embedding state
 is replicated; each device owns a contiguous row slice per phase
@@ -100,11 +77,10 @@ are psum'd -- tensor parallelism for the NE.  Passing ``ctx=AxisCtx()``
         ``ne_forces_gather`` take *indices* and DMA only the needed rows
         inside the kernel (X/Y stay in HBM), instead of XLA materialising
         (n, C, M) / (n, K, d) gathered operands in HBM per launch and the
-        kernel streaming them back a second time.  Applies to HD candidate
-        scoring, the LD current-distance refresh (one fused launch scores
-        current + candidate LD neighbours), and the force phase.
-        ``cfg.gather_fused=False`` restores the legacy pre-gather wiring
-        (kept for bit-equivalence tests and A/B benches).
+        kernel streaming them back a second time.  Applies to
+        ``init_state``, HD candidate scoring under feature-axis sharding,
+        and the force phase (the merge kernel reads its rows the same
+        way).
   H13   single force launch: HD attraction + LD repulsion + negatives run
         as static segments of ONE ``ne_forces_gather`` call over the
         concatenated neighbour axis -- one read of Y and one launch where
@@ -115,8 +91,8 @@ are psum'd -- tensor parallelism for the NE.  Passing ``ctx=AxisCtx()``
         kernel into per-segment (N, d) displacement-field partials, so
         the per-edge (n, K, d) force tensors and the ``.at[tgt].add``
         scatters that consumed them vanish -- the step's last per-edge
-        HBM round-trip.  ``cfg.scatter_fused=False`` restores the
-        edge-emitting epilogue (kept for equivalence tests / A-B benches).
+        HBM round-trip.  Off by default (``cfg.scatter_fused``): the
+        edge-emitting epilogue + XLA scatters run instead.
   H15   scan-chunked driver: T iterations per dispatch via ``lax.scan``
         with a donated state carry -- host->device dispatch cost, the
         per-step hyperparameter upload (schedule evaluated from the
@@ -126,21 +102,16 @@ are psum'd -- tensor parallelism for the NE.  Passing ``ctx=AxisCtx()``
         (chunk(a) then chunk(b) == chunk(a+b)); a handful of
         ``optimization_barrier``\\ s pin scalar EMA/schedule rounding so
         the traced chunk tracks the eager host loop it replaced.
-  H17   candidate-fused sampling: candidate generation was the last
-        per-iteration phase running as plain XLA -- ``sample_hops``
-        materialised an (n, s, K2) two-hop gather broadcast in HBM, the
-        threefry split/randint chain re-ran every step, and the (n, C)
-        candidate tensor round-tripped HBM just to be re-read by the
-        merge kernel's SMEM slabs.  With ``cand_fused=True`` the
-        candidate slots are derived *inside* the kernel from state it
-        already stages: a counter-based hash RNG keyed on (step salt,
-        global row, slot) -- splittable and order/shard-invariant, with
-        a bit-exact pure-jnp reference in ``core/knn.py`` -- plus
-        chained element DMAs through the neighbour tables for the
-        two-hop sources.  The refinement gate and the negatives draw
-        from the same counter stream, so no threefry survives anywhere
-        in the step HLO.  Cached reverse edges (``rev_refresh``) ride in
-        as precomputed "extra" slots.
+  H17   candidate-fused sampling: the candidate slots are derived
+        *inside* the merge kernel from state it already stages: a
+        counter-based hash RNG keyed on (step salt, global row, slot) --
+        splittable and order/shard-invariant, with a bit-exact pure-jnp
+        reference in ``core/knn.py`` -- plus chained element DMAs
+        through the neighbour tables for the two-hop sources.  The
+        refinement gate and the negatives draw from the same counter
+        stream, so no threefry survives anywhere in the step HLO.
+        Cached reverse edges (``rev_refresh``) ride in as precomputed
+        "extra" slots.
   H16   merge-fused neighbour selection: after the gather kernel has the
         candidate distances in VMEM, the dedup (self / current-list /
         earlier-candidate / SENTINEL) and the sorted top-K insertion run
@@ -151,8 +122,7 @@ are psum'd -- tensor parallelism for the NE.  Passing ``ctx=AxisCtx()``
         Applies to HD refinement (stored sorted distances ride in) and LD
         refinement (current rows re-scored in the same sweep).  With the
         scan-chunked driver the removed epilogue would otherwise run T
-        times per dispatch.  ``cfg.merge_fused=False`` restores the XLA
-        selection epilogue (bit-equivalence anchor / A-B benches).
+        times per dispatch.
 """
 from __future__ import annotations
 
@@ -174,9 +144,8 @@ from repro.core.knn import SENTINEL
 from repro.core.resilience import EmbeddingDiverged, ResiliencePolicy
 from repro.kernels import fallback
 from repro.kernels.knn_merge.ops import knn_merge
-from repro.kernels.ne_forces.ops import ne_forces, ne_forces_gather
-from repro.kernels.pairwise_sqdist.ops import (pairwise_sqdist,
-                                               pairwise_sqdist_gather)
+from repro.kernels.ne_forces.ops import ne_forces_gather
+from repro.kernels.pairwise_sqdist.ops import pairwise_sqdist_gather
 from repro.runtime import faults
 
 
@@ -208,38 +177,32 @@ class FuncSNEConfig:
     ema_decay: float = 0.9        # for E[N_new / N]
     z_ema_decay: float = 0.9
     backend: str = "auto"         # kernels backend
-    # gather-fused hot path (§Perf H12/H13): kernels take indices and DMA
-    # rows in-kernel; False re-materialises X[cand]/Y[idx] per launch
-    # (legacy pre-gather wiring, kept for equivalence tests and A/B benches)
+    # Locked to True (checked in __post_init__): the step has one wiring
+    # and no phase reads these.  They stay only because bench/configs/*.json
+    # still pass the keys; a benchmark change drops the keys, then the
+    # fields go.
     gather_fused: bool = True
     # scatter-fused force epilogue (§Perf H14): symmetrisation edges are
     # accumulated in-kernel into (N, d) fields; False keeps the
-    # edge-emitting kernel + XLA ``.at[].add`` scatters.  Only takes
-    # effect with gather_fused (the scatter kernel is index-taking).
+    # edge-emitting kernel + XLA ``.at[].add`` scatters.
     # Off by default: on a TPU the lane-padded fields fit VMEM only in
     # N-chunks, and every chunk re-stages all rows and replays a serial
     # per-edge bin loop, so at n=65536 it would be the slowest phase.
     scatter_fused: bool = False
-    # merge-fused neighbour selection (§Perf H16): dedup + sorted top-K
-    # merge happen inside the gather kernel; False keeps the XLA
-    # selection epilogue (dedup_candidates -> distance kernel ->
-    # merge_knn's top_k).  Only takes effect with gather_fused; the HD
-    # phase falls back automatically under feature-axis sharding (the
-    # merge needs the psum'd full distances).
-    merge_fused: bool = True
-    # candidate-fused sampling (§Perf H17): every per-step draw (HD/LD
-    # candidates, refinement gate, negatives, reverse-edge fill) comes
-    # from the counter-based hash RNG; candidates are generated inside
-    # the merge kernel where merge_fused+gather_fused supply it, and by
-    # the bit-identical jnp reference sampler otherwise.  False keeps the
-    # legacy jax.random (threefry) sampler -- a different random stream,
-    # so the flag is a statistical (not bitwise) A/B.
-    cand_fused: bool = True
+    merge_fused: bool = True      # locked, see gather_fused
+    cand_fused: bool = True       # locked, see gather_fused
     # refresh cadence of the cached reverse-edge table (used when
     # c_hd_rev > 0): the n*K-edge argsort rebuild runs every rev_refresh
-    # steps instead of at every HD refinement; 1 == the legacy
-    # rebuild-per-refinement behaviour, bit-for-bit.
+    # steps instead of at every HD refinement; 1 == a rebuild at every
+    # refinement, bit-for-bit.
     rev_refresh: int = 10
+
+    def __post_init__(self):
+        for name in ("gather_fused", "merge_fused", "cand_fused"):
+            if not getattr(self, name):
+                raise ValueError(
+                    f"{name}=False selected a step path that was removed; "
+                    f"the field only accepts True")
 
     @property
     def c_hd(self) -> int:
@@ -341,15 +304,11 @@ def _take(arr, idx):
 def _row_sqdist(X, ids, cand, ctx: AxisCtx, cfg: "FuncSNEConfig"):
     """Squared HD distances rows->candidates, psum over the feature axis.
 
-    Gather-fused (default): the kernel receives indices and DMAs rows of X
-    in-kernel, so the (n_loc, C, M) gathered operand never hits HBM.  The
-    feature-axis psum semantics are unchanged -- each shard computes partial
-    squared distances over its local M slice.
+    The kernel receives indices and DMAs rows of X in-kernel, so the
+    (n_loc, C, M) gathered operand never hits HBM; each feature shard
+    computes partial squared distances over its local M slice.
     """
-    if cfg.gather_fused:
-        d = pairwise_sqdist_gather(X, ids, cand, backend=cfg.backend)
-    else:
-        d = pairwise_sqdist(X[ids], _take(X, cand), backend=cfg.backend)
+    d = pairwise_sqdist_gather(X, ids, cand, backend=cfg.backend)
     if ctx.feat is not None:
         d = jax.lax.psum(d, ctx.feat)
     return d
@@ -385,8 +344,8 @@ def _rev_update(cfg: FuncSNEConfig, st: FuncSNEState, fill):
     *since-last-refresh* (``st.rev_step``), not ``step % k``: refinement
     itself runs behind a stochastic gate, so an absolute-modulo schedule
     would silently drop every refresh whose step the gate skipped and
-    leave staleness unbounded.  ``rev_refresh=1`` == the legacy
-    per-refinement rebuild, bit-for-bit -- any later refinement
+    leave staleness unbounded.  ``rev_refresh=1`` == a rebuild at
+    every refinement, bit-for-bit -- any later refinement
     satisfies the >= 1 condition, the same ``fill`` protocol feeds
     ``reverse_neighbors``, and the cache is overwritten before use."""
     n = cfg.n_points
@@ -407,78 +366,39 @@ def _hd_refine(cfg: FuncSNEConfig, st: FuncSNEState, X, rng, ctx: AxisCtx):
     hd_d_l = jax.lax.dynamic_slice_in_dim(st.hd_d, start, n_loc)
     ld_l = jax.lax.dynamic_slice_in_dim(st.ld_idx, start, n_loc)
 
-    # §Perf H16 (and the feature-sharding fallback): the in-kernel merge
-    # is available off the feat axis only -- it needs full distances.
-    use_kernel = cfg.merge_fused and cfg.gather_fused and ctx.feat is None
-    cand = rev_l = None
-    fused_kw = {}
-    if cfg.cand_fused:
-        # §Perf H17: all draws from the counter RNG, keyed on *global*
-        # row ids -- no per-shard fold needed, the stream is
-        # shard-invariant by construction.
-        base = knn_lib.as_salt(rng)
-        salt = knn_lib.hash3(base, st.step, _TAG_HD)
-        if cfg.c_hd_rev:
-            fill = knn_lib.counter_fill(
-                knn_lib.hash3(base, st.step, _TAG_REV), n, cfg.c_hd_rev)
-            st = _rev_update(cfg, st, fill)
-            rev_l = jax.lax.dynamic_slice_in_dim(st.rev_idx, start, n_loc)
-        sources = (("two_hop", 0, 0, cfg.c_hd_non),
-                   ("one_hop", 1, cfg.c_hd_ld),
-                   ("two_hop", 1, 1, cfg.c_hd_ld_non),
-                   ("uniform", cfg.c_hd_rand),
-                   ("extra", cfg.c_hd_rev))
-        firsts, seconds = (hd_l, ld_l), (st.hd_idx, st.ld_idx)
-        if use_kernel:
-            fused_kw = dict(sources=sources, salt=salt,
-                            first_tables=firsts, second_tables=seconds,
-                            active=st.active)
-        else:
-            cand = knn_lib.counter_candidates(salt, ids, sources, firsts,
-                                              seconds, n_total=n,
-                                              extra=rev_l)
-    else:
-        rng0 = rng
-        if ctx.points is not None:
-            rng = jax.random.fold_in(rng, jax.lax.axis_index(ctx.points))
-        r = jax.random.split(rng, 5)
-        parts = []
-        if cfg.c_hd_non:
-            parts.append(knn_lib.sample_hops(r[0], hd_l, st.hd_idx, ids,
-                                             cfg.c_hd_non))
-        if cfg.c_hd_ld:
-            parts.append(knn_lib.sample_direct(r[1], ld_l, cfg.c_hd_ld))
-        if cfg.c_hd_ld_non:
-            parts.append(knn_lib.sample_hops(r[2], ld_l, st.ld_idx, ids,
-                                             cfg.c_hd_ld_non))
-        if cfg.c_hd_rand:
-            parts.append(knn_lib.sample_uniform(r[3], n_loc, n,
-                                                cfg.c_hd_rand))
-        if cfg.c_hd_rev:
-            # the cached table is carried in *replicated* state, so its
-            # fill must be identical on every shard: on a mesh derive it
-            # from the pre-fold key (single-device: r[4], the legacy key)
-            fill_key = r[4] if ctx.points is None \
-                else jax.random.split(rng0, 5)[4]
-            st = _rev_update(cfg, st,
-                             knn_lib.sample_uniform(fill_key, n, n,
-                                                    cfg.c_hd_rev))
-            parts.append(jax.lax.dynamic_slice_in_dim(st.rev_idx, start,
-                                                      n_loc))
-        cand = jnp.concatenate(parts, axis=1)
+    # §Perf H17: all draws from the counter RNG, keyed on *global* row ids
+    # -- no per-shard fold needed, the stream is shard-invariant by
+    # construction.
+    base = knn_lib.as_salt(rng)
+    salt = knn_lib.hash3(base, st.step, _TAG_HD)
+    rev_l = None
+    if cfg.c_hd_rev:
+        fill = knn_lib.counter_fill(
+            knn_lib.hash3(base, st.step, _TAG_REV), n, cfg.c_hd_rev)
+        st = _rev_update(cfg, st, fill)
+        rev_l = jax.lax.dynamic_slice_in_dim(st.rev_idx, start, n_loc)
+    sources = (("two_hop", 0, 0, cfg.c_hd_non),
+               ("one_hop", 1, cfg.c_hd_ld),
+               ("two_hop", 1, 1, cfg.c_hd_ld_non),
+               ("uniform", cfg.c_hd_rand),
+               ("extra", cfg.c_hd_rev))
+    firsts, seconds = (hd_l, ld_l), (st.hd_idx, st.ld_idx)
 
-    if use_kernel:
-        # §Perf H16 + H17: dedup + top-K merge run inside the gather
-        # kernel -- no (n, C) distance round-trip, no (n, C, K)/(n, C, C)
-        # dedup broadcast tensors, no top_k in the step HLO; with
-        # cand_fused the candidates themselves are generated in-kernel
-        # (counter RNG + chained two-hop DMAs), so the (n, C) candidate
-        # tensor and the threefry chain vanish too.
+    if ctx.feat is None:
+        # §Perf H16 + H17: one launch generates the candidates (counter
+        # RNG + chained two-hop DMAs), scores, dedups and merges them --
+        # no (n, C) candidate or distance round-trip, no dedup broadcast
+        # tensors, no top_k in the step HLO.
         new_idx, new_d, improved = knn_merge(
-            X, ids, hd_l, hd_d_l, rev_l if cfg.cand_fused else cand,
-            cand_active=None if cfg.cand_fused else _take(st.active, cand),
-            backend=cfg.backend, **fused_kw)
+            X, ids, hd_l, hd_d_l, rev_l, backend=cfg.backend,
+            sources=sources, salt=salt, first_tables=firsts,
+            second_tables=seconds, active=st.active)
     else:
+        # Feature-axis sharding: each shard holds a slice of X's columns,
+        # so the merge must wait for the psum'd full distances -- the same
+        # candidates from the jnp sampler, merged in XLA.
+        cand = knn_lib.counter_candidates(salt, ids, sources, firsts,
+                                          seconds, n_total=n, extra=rev_l)
         valid = knn_lib.dedup_candidates(ids, hd_l, cand)
         valid &= _take(st.active, cand)
         cand_d = _row_sqdist(X, ids, cand, ctx, cfg)
@@ -537,75 +457,21 @@ def _ld_refine(cfg: FuncSNEConfig, st: FuncSNEState, rng, ctx: AxisCtx):
     ld_l = jax.lax.dynamic_slice_in_dim(st.ld_idx, start, n_loc)
     hd_l = jax.lax.dynamic_slice_in_dim(st.hd_idx, start, n_loc)
 
-    use_kernel = cfg.merge_fused and cfg.gather_fused
-    cand = None
-    fused_kw = {}
-    if cfg.cand_fused:
-        # §Perf H17: counter-RNG draws keyed on global row ids
-        salt = knn_lib.hash3(knn_lib.as_salt(rng), st.step, _TAG_LD)
-        sources = (("two_hop", 0, 0, cfg.c_ld_non),
-                   ("one_hop", 1, cfg.c_ld_hd),
-                   ("uniform", cfg.c_ld_rand))
-        firsts, seconds = (ld_l, hd_l), (st.ld_idx,)
-        if use_kernel:
-            fused_kw = dict(sources=sources, salt=salt,
-                            first_tables=firsts, second_tables=seconds,
-                            active=st.active)
-        else:
-            cand = knn_lib.counter_candidates(salt, ids, sources, firsts,
-                                              seconds, n_total=n)
-    else:
-        if ctx.all_rows is not None:
-            rng = jax.random.fold_in(rng, jax.lax.axis_index(ctx.all_rows))
-        r = jax.random.split(rng, 3)
-        parts = []
-        if cfg.c_ld_non:
-            parts.append(knn_lib.sample_hops(r[0], ld_l, st.ld_idx, ids,
-                                             cfg.c_ld_non))
-        if cfg.c_ld_hd:
-            # HD neighbours: stable LD candidates unaffected by embedding
-            # motion
-            parts.append(knn_lib.sample_direct(r[1], hd_l, cfg.c_ld_hd))
-        if cfg.c_ld_rand:
-            parts.append(knn_lib.sample_uniform(r[2], n_loc, n,
-                                                cfg.c_ld_rand))
-        cand = jnp.concatenate(parts, axis=1)
-
-    if use_kernel:
-        # §Perf H16 (+H17): one launch generates (cand_fused) or stages
-        # the candidates, gathers + re-scores current AND candidate rows
-        # (the embedding moved since the last merge), dedups and merges
-        # in-register -- the whole LD selection epilogue is gone from the
-        # step HLO.
-        cur_valid = (ld_l != SENTINEL) & _take(st.active, ld_l)
-        new_idx, new_d, _ = knn_merge(
-            st.Y, ids, ld_l, None, cand,
-            cand_active=None if cfg.cand_fused else _take(st.active, cand),
-            cur_valid=cur_valid, backend=cfg.backend, **fused_kw)
-    else:
-        valid = knn_lib.dedup_candidates(ids, ld_l, cand)
-        valid &= _take(st.active, cand)
-
-        # refresh stored distances (embedding moved since the last merge)
-        cur_valid = (ld_l != SENTINEL) & _take(st.active, ld_l)
-        if cfg.gather_fused:
-            # §Perf H12: index-taking kernel -- no (n_loc, K+C, d)
-            # Y-gather buffers; one fused launch scores current +
-            # candidate neighbours
-            both = jnp.concatenate([ld_l, cand], axis=1)
-            both_d = pairwise_sqdist_gather(st.Y, ids, both,
-                                            backend=cfg.backend)
-            cur_d, cand_d = jnp.split(both_d, [ld_l.shape[1]], axis=1)
-        else:
-            y_l = st.Y[ids]
-            cur_nbr = _take(st.Y, ld_l)
-            cur_d = jnp.sum((cur_nbr - y_l[:, None, :]) ** 2, axis=-1)
-            cand_nbr = _take(st.Y, cand)
-            cand_d = jnp.sum((cand_nbr - y_l[:, None, :]) ** 2, axis=-1)
-        cur_d = jnp.where(cur_valid, cur_d, jnp.inf)
-
-        new_idx, new_d, _ = knn_lib.merge_knn(ld_l, cur_d, cand, cand_d,
-                                              valid)
+    # §Perf H16 + H17: one launch generates the candidates (counter RNG
+    # keyed on global row ids), gathers + re-scores current AND candidate
+    # rows (the embedding moved since the last merge), dedups and merges
+    # in-register -- the whole LD selection epilogue is gone from the step
+    # HLO.
+    salt = knn_lib.hash3(knn_lib.as_salt(rng), st.step, _TAG_LD)
+    sources = (("two_hop", 0, 0, cfg.c_ld_non),
+               ("one_hop", 1, cfg.c_ld_hd),
+               ("uniform", cfg.c_ld_rand))
+    cur_valid = (ld_l != SENTINEL) & _take(st.active, ld_l)
+    new_idx, new_d, _ = knn_merge(
+        st.Y, ids, ld_l, None, None, cur_valid=cur_valid,
+        backend=cfg.backend, sources=sources, salt=salt,
+        first_tables=(ld_l, hd_l), second_tables=(st.ld_idx,),
+        active=st.active)
     ld_idx = _gather_rows(new_idx, ctx.all_rows)
     if ctx.all_rows is None:
         ld_d = new_d
@@ -627,9 +493,6 @@ def _forces_update(cfg: FuncSNEConfig, st: FuncSNEState, hp: HParams, rng,
     n, d = cfg.n_points, cfg.dim_ld
     start, n_loc = _phase_rows(n, ctx.all_rows)
     ids = start + jnp.arange(n_loc, dtype=jnp.int32)
-    if ctx.all_rows is not None and not cfg.cand_fused:
-        # counter-RNG draws are keyed on global row ids -> shard-invariant
-        rng = jax.random.fold_in(rng, jax.lax.axis_index(ctx.all_rows))
 
     hd_i = jax.lax.dynamic_slice_in_dim(st.hd_idx, start, n_loc)
     hd_d = jax.lax.dynamic_slice_in_dim(st.hd_d, start, n_loc)
@@ -655,69 +518,47 @@ def _forces_update(cfg: FuncSNEConfig, st: FuncSNEState, hp: HParams, rng,
     # the momentum-conservation tests, where every edge is symmetrised.
     have_neg = cfg.n_negatives > 0
     if have_neg:
-        if cfg.cand_fused:
-            # §Perf H17: counter-RNG negatives -- no threefry in the HLO
-            salt = knn_lib.hash3(knn_lib.as_salt(rng), st.step,
-                                 _TAG_NEG)
-            draws = jnp.arange(cfg.n_negatives, dtype=jnp.int32)[None, :]
-            neg = knn_lib.counter_randint(salt, ids[:, None], draws, n)
-        else:
-            neg = knn_lib.sample_uniform(rng, n_loc, n, cfg.n_negatives)
+        # §Perf H17: counter-RNG negatives keyed on global row ids --
+        # shard-invariant, no threefry in the HLO
+        salt = knn_lib.hash3(knn_lib.as_salt(rng), st.step, _TAG_NEG)
+        draws = jnp.arange(cfg.n_negatives, dtype=jnp.int32)[None, :]
+        neg = knn_lib.counter_randint(salt, ids[:, None], draws, n)
         neg = jnp.where(neg == ids[:, None], (neg + 1) % n, neg)
         coef_n = (_take(st.active, neg) & act_l[:, None]).astype(jnp.float32)
         scale_neg = jnp.maximum(n_act - 1.0 - cfg.k_ld, 1.0) / cfg.n_negatives
     else:
         scale_neg = jnp.float32(0.0)
 
-    scatter_fused = cfg.gather_fused and cfg.scatter_fused
-    if cfg.gather_fused:
-        # §Perf H13: ONE batched launch over the concatenated neighbour
-        # axis replaces the three per-step force launches; y_l is read
-        # once (DMA'd in-kernel) instead of three gathered (n, K, d)
-        # buffers round-tripping through HBM.
-        nbr_idx = jnp.concatenate([hd_i, ld_i] + ([neg] if have_neg else []),
-                                  axis=1)
-        coef = jnp.concatenate([coef_a, coef_r]
-                               + ([coef_n] if have_neg else []), axis=1)
-        segments = (("attraction", cfg.k_hd), ("repulsion", cfg.k_ld)) \
-            + ((("repulsion", cfg.n_negatives),) if have_neg else ())
-        if scatter_fused:
-            # §Perf H14: the kernel bins every edge force (and its
-            # symmetric reaction, except for negatives) straight into
-            # per-segment (n, d) fields -- no per-edge output exists.
-            scats, wsums = ne_forces_gather(
-                st.Y, ids, nbr_idx, coef, hp.alpha, segments=segments,
-                scatter_fused=True,
-                scatter_back=(True, True) + ((False,) if have_neg else ()),
-                backend=cfg.backend)
-        else:
-            # negatives' edges are never scattered back -> skip their HBM
-            # write
-            emit = (True, True) + ((False,) if have_neg else ())
-            aggs, edges, wsums = ne_forces_gather(st.Y, ids, nbr_idx, coef,
-                                                  hp.alpha,
-                                                  segments=segments,
-                                                  emit_edges=emit,
-                                                  backend=cfg.backend)
-            agg_a, agg_r = aggs[0], aggs[1]
-            agg_n = aggs[2] if have_neg else 0.0
-            edge_a, edge_r = edges[0], edges[1]
-        wsum_r = wsums[1]
-        wsum_n = wsums[2] if have_neg else jnp.float32(0.0)
+    # §Perf H13: ONE launch over the concatenated neighbour axis (static
+    # segments: HD attraction, LD repulsion, negatives); rows of Y are
+    # read in-kernel, so no gathered (n, K, d) buffer reaches HBM.
+    nbr_idx = jnp.concatenate([hd_i, ld_i] + ([neg] if have_neg else []),
+                              axis=1)
+    coef = jnp.concatenate([coef_a, coef_r]
+                           + ([coef_n] if have_neg else []), axis=1)
+    segments = (("attraction", cfg.k_hd), ("repulsion", cfg.k_ld)) \
+        + ((("repulsion", cfg.n_negatives),) if have_neg else ())
+    if cfg.scatter_fused:
+        # §Perf H14: the kernel bins every edge force (and its symmetric
+        # reaction, except for negatives) straight into per-segment
+        # (n, d) fields -- no per-edge output exists.
+        scats, wsums = ne_forces_gather(
+            st.Y, ids, nbr_idx, coef, hp.alpha, segments=segments,
+            scatter_fused=True,
+            scatter_back=(True, True) + ((False,) if have_neg else ()),
+            backend=cfg.backend)
     else:
-        y_l = st.Y[ids]
-        agg_a, edge_a, _ = ne_forces(y_l, _take(st.Y, hd_i), coef_a,
-                                     hp.alpha, mode="attraction",
-                                     backend=cfg.backend)
-        agg_r, edge_r, wsum_r = ne_forces(y_l, _take(st.Y, ld_i), coef_r,
-                                          hp.alpha, mode="repulsion",
-                                          backend=cfg.backend)
-        if have_neg:
-            agg_n, _, wsum_n = ne_forces(y_l, _take(st.Y, neg), coef_n,
-                                         hp.alpha, mode="repulsion",
-                                         backend=cfg.backend)
-        else:
-            agg_n, wsum_n = 0.0, jnp.float32(0.0)
+        # negatives' edges are never scattered back -> skip their HBM write
+        emit = (True, True) + ((False,) if have_neg else ())
+        aggs, edges, wsums = ne_forces_gather(st.Y, ids, nbr_idx, coef,
+                                              hp.alpha, segments=segments,
+                                              emit_edges=emit,
+                                              backend=cfg.backend)
+        agg_a, agg_r = aggs[0], aggs[1]
+        agg_n = aggs[2] if have_neg else 0.0
+        edge_a, edge_r = edges[0], edges[1]
+    wsum_r = wsums[1]
+    wsum_n = wsums[2] if have_neg else jnp.float32(0.0)
 
     # ---- Z estimator:  Z ~= sum_i [ sum_{j in LD_i} w_ij + scale * mean_neg ]
     # (x2 undoes the 0.5 symmetrisation coefficient baked into coef_r)
@@ -741,7 +582,7 @@ def _forces_update(cfg: FuncSNEConfig, st: FuncSNEState, hp: HParams, rng,
     # ---- assemble the displacement field (one (N, d) buffer + one psum)
     attr_s = hp.attraction * hp.exaggeration
     rep_s = hp.repulsion / zhat
-    if scatter_fused:
+    if cfg.scatter_fused:
         # §Perf H14: the kernel already binned edge + reaction forces by
         # row; the epilogue is three AXPYs on (n, d) partials -- the
         # ``.at[].add`` scatters below (and the edge tensors feeding
@@ -803,22 +644,15 @@ def _step_flags(cfg: FuncSNEConfig, st: FuncSNEState, X, hp: HParams,
     # stochastic HD refinement: p = 0.05 + 0.95 E[N_new/N]  (paper Sec. 3)
     p_ref = cfg.min_refresh_prob + (1.0 - cfg.min_refresh_prob) \
         * st.ema_new_frac
-    if cfg.cand_fused:
-        # §Perf H17: the state key is only *read* (its raw bits fold into
-        # one int32 base salt), every draw this step -- gate, candidates,
-        # negatives, reverse-edge fill -- is a counter hash of
-        # (salt, step, tag, row, slot): zero threefry ops in the HLO.
-        base = knn_lib.key_salt(st.rng)
-        r_hd = r_ld = r_force = base
-        u = knn_lib.counter_uniform01(
-            knn_lib.hash3(base, st.step, _TAG_GATE))
-        do_hd = u < jnp.clip(p_ref, 0.0, 1.0)
-    else:
-        rng = jax.random.fold_in(st.rng, st.step)
-        r_gate, r_hd, r_ld, r_force = jax.random.split(rng, 4)
-        do_hd = jax.random.bernoulli(r_gate, jnp.clip(p_ref, 0.0, 1.0))
+    # §Perf H17: the state key is only *read* (its raw bits fold into one
+    # int32 base salt), every draw this step -- gate, candidates,
+    # negatives, reverse-edge fill -- is a counter hash of
+    # (salt, step, tag, row, slot): zero threefry ops in the HLO.
+    base = knn_lib.key_salt(st.rng)
+    u = knn_lib.counter_uniform01(knn_lib.hash3(base, st.step, _TAG_GATE))
+    do_hd = u < jnp.clip(p_ref, 0.0, 1.0)
     st = jax.lax.cond(do_hd,
-                      lambda s: _hd_refine(cfg, s, X, r_hd, ctx),
+                      lambda s: _hd_refine(cfg, s, X, base, ctx),
                       lambda s: s, st)
 
     do_sigma = (st.step % cfg.sigma_refresh_every == 0) \
@@ -827,8 +661,8 @@ def _step_flags(cfg: FuncSNEConfig, st: FuncSNEState, X, hp: HParams,
                       lambda s: _sigma_refresh(cfg, s, hp, ctx),
                       lambda s: s, st)
 
-    st = _ld_refine(cfg, st, r_ld, ctx)
-    st = _forces_update(cfg, st, hp, r_force, ctx)
+    st = _ld_refine(cfg, st, base, ctx)
+    st = _forces_update(cfg, st, hp, base, ctx)
     return st._replace(step=st.step + 1), do_hd, do_sigma
 
 
@@ -906,20 +740,14 @@ def init_state(rng, X, cfg: FuncSNEConfig, *, init: str = "pca",
 
     ids = jnp.arange(n, dtype=jnp.int32)
     hd_idx = knn_lib.init_knn_idx(r_hd, n, n, cfg.k_hd)
-    if cfg.gather_fused:
-        hd_d = pairwise_sqdist_gather(X, ids, hd_idx, backend=cfg.backend)
-    else:
-        hd_d = pairwise_sqdist(X, X[hd_idx], backend=cfg.backend)
+    hd_d = pairwise_sqdist_gather(X, ids, hd_idx, backend=cfg.backend)
     hd_d = jnp.where(active[hd_idx] & active[:, None], hd_d, jnp.inf)
     order = jnp.argsort(hd_d, axis=1)
     hd_idx = jnp.take_along_axis(hd_idx, order, axis=1)
     hd_d = jnp.take_along_axis(hd_d, order, axis=1)
 
     ld_idx = knn_lib.init_knn_idx(r_ld, n, n, cfg.k_ld)
-    if cfg.gather_fused:
-        ld_d = pairwise_sqdist_gather(Y, ids, ld_idx, backend=cfg.backend)
-    else:
-        ld_d = jnp.sum((Y[:, None, :] - Y[ld_idx]) ** 2, axis=-1)
+    ld_d = pairwise_sqdist_gather(Y, ids, ld_idx, backend=cfg.backend)
     ld_d = jnp.where(active[ld_idx] & active[:, None], ld_d, jnp.inf)
 
     beta = affinities.solve_beta(hd_d, perplexity, n_iter=24)

@@ -116,7 +116,7 @@ def counter_candidates(salt, rows, sources, first_tables=(),
     """Pure-jnp reference of the candidate-fused sampler (§Perf H17).
 
     Generates the (B, C) candidate block that ``knn_merge``'s
-    ``cand_fused`` kernel derives in-kernel, with bit-identical draws:
+    candidate-fused kernel derives in-kernel, with bit-identical draws:
     slot ``g`` of row ``r`` consumes ``hash3(salt, rows[r], 2g)`` (the
     'a' stream) and, for two-hop slots, ``hash3(salt, rows[r], 2g+1)``
     (the 'b' stream).  Being keyed on *global* row ids makes the draws
@@ -128,8 +128,8 @@ def counter_candidates(salt, rows, sources, first_tables=(),
       ("one_hop", f, c)        c entries of ``first_tables[f]`` (own row)
       ("two_hop", f, s, c)     c chained picks
                                ``second_tables[s][first_tables[f][r, a], b]``
-                               (SENTINEL mids fall back to the row id, as
-                               ``sample_hops`` does); the gather is flat
+                               (SENTINEL mids fall back to the row id);
+                               the gather is flat
                                (``reshape(-1)``), so no (B, c, K2)
                                broadcast exists in the HLO
       ("extra", c)             c precomputed candidates from ``extra``
@@ -173,7 +173,7 @@ def two_hop_picks(salt, rows_c, slots, first, second):
 
     rows_c: (B, 1) global row ids; slots: (1, c) slot ids; first: (B, K1)
     the rows' own lists; second: (N2, K2) global table.  SENTINEL mids
-    fall back to the row id, as ``sample_hops`` does.  The gather is flat
+    fall back to the row id.  The gather is flat
     (``reshape(-1)``), so no (B, c, K2) broadcast exists in the HLO.
     """
     n2, k2 = second.shape
@@ -208,50 +208,19 @@ def init_knn_idx(rng, n_rows, n_total, k, row_offset: int = 0):
     return ((rows + offs) % n_total).astype(jnp.int32)
 
 
-def sample_hops(rng, first_idx, second_idx, rows, n_samples):
-    """Two-hop candidates: second_idx[first_idx[i, a], b] for random (a, b).
-
-    first_idx: (n, K1) rows for the local points; second_idx: (N, K2) global
-    table (may equal first_idx's global source).  Returns (n, n_samples).
-    """
-    n, k1 = first_idx.shape
-    k2 = second_idx.shape[1]
-    ra, rb = jax.random.split(rng)
-    a = jax.random.randint(ra, (n, n_samples), 0, k1)
-    b = jax.random.randint(rb, (n, n_samples), 0, k2)
-    mid = jnp.take_along_axis(first_idx, a, axis=1)          # (n, s)
-    mid = jnp.where(mid == SENTINEL, rows[:, None] % second_idx.shape[0], mid)
-    cand = second_idx[jnp.clip(mid, 0, second_idx.shape[0] - 1)]  # (n, s, K2)
-    return jnp.take_along_axis(cand, b[..., None], axis=2)[..., 0]
-
-
-def sample_direct(rng, idx, n_samples):
-    """One-hop candidates: random entries of the point's own list."""
-    n, k = idx.shape
-    a = jax.random.randint(rng, (n, n_samples), 0, k)
-    return jnp.take_along_axis(idx, a, axis=1)
-
-
-def sample_uniform(rng, n, n_total, n_samples):
-    return jax.random.randint(rng, (n, n_samples), 0, n_total,
-                              dtype=jnp.int32)
-
-
-def reverse_neighbors(idx, n_total, r, fill_rng=None, fill=None):
+def reverse_neighbors(idx, n_total, r, *, fill):
     """Sampled reverse edges: up to ``r`` points that list i as a neighbour.
 
     Built with one argsort over the E = n*K directed edges (TPU-friendly
     replacement for the GPU scatter-append).  Rows with fewer than r reverse
-    edges are padded with uniform random points: either threefry-sampled
-    from ``fill_rng`` (legacy) or a caller-precomputed ``fill`` table (the
-    counter-RNG path, which must keep threefry out of the step HLO).
+    edges are padded from the caller's (n_total, r) ``fill`` table of
+    uniform random points (:func:`counter_fill`).
 
     The full rebuild costs an argsort over all n*K directed edges, so
     callers cache the result in state and refresh it every
-    ``rev_refresh`` steps (``refresh=1`` == the legacy per-iteration
-    rebuild, bit-for-bit).
+    ``rev_refresh`` steps (``rev_refresh=1`` == a rebuild every
+    iteration, bit-for-bit).
     """
-    assert (fill is None) != (fill_rng is None), "pass fill_rng xor fill"
     n, k = idx.shape
     tgt = idx.reshape(-1)
     src = jnp.repeat(jnp.arange(n, dtype=jnp.int32), k)
@@ -263,8 +232,6 @@ def reverse_neighbors(idx, n_total, r, fill_rng=None, fill=None):
     pos = starts[:, None] + jnp.arange(r)[None, :]
     valid = jnp.arange(r)[None, :] < counts[:, None]
     gathered = src_s[jnp.clip(pos, 0, src_s.shape[0] - 1)]
-    if fill is None:
-        fill = sample_uniform(fill_rng, n_total, n_total, r)
     return jnp.where(valid, gathered, fill)
 
 

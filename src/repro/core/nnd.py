@@ -17,106 +17,71 @@ import jax.numpy as jnp
 
 from repro.core import knn as knn_lib
 from repro.kernels.knn_merge.ops import knn_merge
-from repro.kernels.pairwise_sqdist.ops import (pairwise_sqdist,
-                                               pairwise_sqdist_gather)
+from repro.kernels.pairwise_sqdist.ops import pairwise_sqdist_gather
 
 
 @dataclasses.dataclass(frozen=True)
 class NNDConfig:
+    """Candidates come from the counter-based hash RNG (funcsne §Perf
+    H17) and are generated, scored, dedup'd and merged by one
+    ``knn_merge`` launch (§H16); the cached reverse edges ride in as
+    "extra" slots."""
     k: int = 32
     c_fwd: int = 8         # forward neighbours-of-neighbours per iter
     c_rev: int = 4         # reverse-edge hops per iter
     backend: str = "auto"
-    # index-taking distance kernel (funcsne §Perf H12): candidate rows are
-    # DMA'd in-kernel instead of XLA materialising X[cand] in HBM per
-    # iteration; False keeps the legacy pre-gather wiring (equivalence
-    # tests / A-B benches)
-    gather_fused: bool = True
-    # merge-fused selection (funcsne §Perf H16): dedup + sorted top-K
-    # merge run inside the gather kernel instead of the XLA
-    # dedup_candidates/merge_knn epilogue; requires gather_fused
-    merge_fused: bool = True
-    # candidate-fused sampling (funcsne §Perf H17): forward and
-    # reverse-edge hops come from the counter-based hash RNG and are
-    # generated inside the merge kernel on the pallas path (the cached
-    # reverse table rides in as "extra" slots).  False keeps the legacy
-    # threefry sampler -- the paper baseline's random stream.
-    cand_fused: bool = False
     # refresh cadence of the reverse-edge table in the ``nnd`` driver:
     # the argsort over all n*K directed edges rebuilds every
     # rev_refresh iterations instead of every iteration.  Default 1 ==
-    # the legacy per-iteration rebuild, bit-for-bit: NND is a *baseline*
-    # whose tol-based termination is sensitive to stale reverse edges,
-    # so amortisation is opt-in here (funcsne defaults to 10).
+    # the per-iteration rebuild, bit-for-bit: NND is a *baseline* whose
+    # tol-based termination is sensitive to stale reverse edges, so
+    # amortisation is opt-in here (funcsne defaults to 10).
     rev_refresh: int = 1
-
-
-def _cand_sqdist(X, ids, cand, cfg: NNDConfig):
-    if cfg.gather_fused:
-        return pairwise_sqdist_gather(X, ids, cand, backend=cfg.backend)
-    return pairwise_sqdist(X[ids], X[jnp.clip(cand, 0, X.shape[0] - 1)],
-                           backend=cfg.backend)
 
 
 def nnd_init(rng, X, cfg: NNDConfig):
     n = X.shape[0]
     ids = jnp.arange(n, dtype=jnp.int32)
     idx = knn_lib.init_knn_idx(rng, n, n, cfg.k)
-    d = _cand_sqdist(X, ids, idx, cfg)
+    d = pairwise_sqdist_gather(X, ids, idx, backend=cfg.backend)
     order = jnp.argsort(d, axis=1)
     return (jnp.take_along_axis(idx, order, axis=1),
             jnp.take_along_axis(d, order, axis=1))
+
+
+def _reverse_table(idx, rng, cfg: NNDConfig):
+    """(n, c_rev) sampled reverse edges, padded from the counter fill
+    keyed on ``split(rng)[1]`` -- the one protocol both the in-step and
+    the cached rebuild use."""
+    n = idx.shape[0]
+    fill = knn_lib.counter_fill(knn_lib.key_salt(jax.random.split(rng)[1]),
+                                n, cfg.c_rev)
+    return knn_lib.reverse_neighbors(idx, n, cfg.c_rev, fill=fill)
 
 
 def nnd_step(rng, X, idx, d, cfg: NNDConfig, rev=None):
     """One NND iteration; returns (idx, d, update_fraction).
 
     ``rev`` is the cached (n, c_rev) reverse-edge table; ``None`` rebuilds
-    it in-step from ``split(rng, 3)[1]`` -- the legacy per-iteration
-    behaviour, which the :func:`nnd` driver reproduces bit-for-bit at
-    ``rev_refresh=1`` by passing a table built with the same key.
+    it in-step (:func:`_reverse_table`), which the :func:`nnd` driver
+    reproduces bit-for-bit at ``rev_refresh=1``.
     """
     n = X.shape[0]
     ids = jnp.arange(n, dtype=jnp.int32)
-    r1, r2, r3 = jax.random.split(rng, 3)
     if cfg.c_rev and rev is None:
-        rev = knn_lib.reverse_neighbors(idx, n, cfg.c_rev, r2)
-    use_kernel = cfg.merge_fused and cfg.gather_fused
-    cand = None
-    fused_kw = {}
-    if cfg.cand_fused:
-        # funcsne §Perf H17: counter-RNG forward hops + rev-of-fwd hops;
-        # the cached reverse edges ride in as "extra" slots
-        salt = knn_lib.key_salt(r1)
-        sources = (("two_hop", 0, 0, cfg.c_fwd),)
-        firsts = (idx,)
-        if cfg.c_rev:
-            sources += (("extra", cfg.c_rev), ("two_hop", 1, 0, cfg.c_rev))
-            firsts += (rev,)
-        if use_kernel:
-            fused_kw = dict(sources=sources, salt=salt,
-                            first_tables=firsts, second_tables=(idx,))
-        else:
-            cand = knn_lib.counter_candidates(
-                salt, ids, sources, firsts, (idx,), n_total=n,
-                extra=rev if cfg.c_rev else None)
-    else:
-        parts = [knn_lib.sample_hops(r1, idx, idx, ids, cfg.c_fwd)]
-        if cfg.c_rev:
-            # hop once through a reverse edge (rev-of-fwd closes the
-            # local join)
-            parts.append(rev)
-            parts.append(knn_lib.sample_hops(r3, rev, idx, ids,
-                                             cfg.c_rev))
-        cand = jnp.concatenate(parts, axis=1)
-    if use_kernel:
-        extra = (rev if cfg.c_rev else None) if cfg.cand_fused else cand
-        idx, d, improved = knn_merge(X, ids, idx, d, extra,
-                                     backend=cfg.backend, **fused_kw)
-    else:
-        valid = knn_lib.dedup_candidates(ids, idx, cand)
-        cand_d = _cand_sqdist(X, ids, cand, cfg)
-        idx, d, improved = knn_lib.merge_knn(idx, d, cand, cand_d, valid)
+        rev = _reverse_table(idx, rng, cfg)
+    # forward hops + rev-of-fwd hops (closing the local join); the cached
+    # reverse edges ride in as "extra" slots
+    salt = knn_lib.key_salt(jax.random.split(rng)[0])
+    sources = (("two_hop", 0, 0, cfg.c_fwd),)
+    firsts = (idx,)
+    if cfg.c_rev:
+        sources += (("extra", cfg.c_rev), ("two_hop", 1, 0, cfg.c_rev))
+        firsts += (rev,)
+    idx, d, improved = knn_merge(X, ids, idx, d, rev if cfg.c_rev else None,
+                                 backend=cfg.backend, sources=sources,
+                                 salt=salt, first_tables=firsts,
+                                 second_tables=(idx,))
     return idx, d, jnp.mean(improved.astype(jnp.float32))
 
 
@@ -126,21 +91,18 @@ def nnd(X, cfg: NNDConfig = NNDConfig(), rng=None, max_iter: int = 40,
 
     The reverse-edge table is cached across iterations and rebuilt every
     ``cfg.rev_refresh`` steps (the argsort over all n*K directed edges
-    was the only per-iteration O(E log E) term left): the rebuild uses
-    the same ``split(fold_in(rng, it), 3)[1]`` fill key the in-step
-    rebuild would.  ``rev_refresh=1`` (the default) runs the legacy
-    in-step rebuild program itself, so it is bit-identical to the
-    pre-cache behaviour -- caching is a ``rev_refresh>1`` opt-in.
+    was the only per-iteration O(E log E) term left) with the same
+    protocol as the in-step rebuild.  ``rev_refresh=1`` (the default)
+    runs the in-step rebuild program itself, so caching is a
+    ``rev_refresh>1`` opt-in.
     """
     X = jnp.asarray(X, jnp.float32)
     if rng is None:
         rng = jax.random.PRNGKey(0)
-    n = X.shape[0]
     idx, d = nnd_init(rng, X, cfg)
     cache = cfg.c_rev > 0 and cfg.rev_refresh > 1
     step = jax.jit(lambda r, i, dd, rv: nnd_step(r, X, i, dd, cfg, rev=rv))
-    rebuild = jax.jit(lambda i, r: knn_lib.reverse_neighbors(
-        i, n, cfg.c_rev, jax.random.split(r, 3)[1]))
+    rebuild = jax.jit(lambda i, r: _reverse_table(i, r, cfg))
     history = []
     rev = None
     for it in range(max_iter):

@@ -16,7 +16,8 @@ Kernels (the compute hot-spots the paper optimises on GPU, re-tiled for TPU):
 
 The two NE kernels each come in two flavours: the pre-gather form takes
 already-gathered (B, C, M) / (B, K, d) operands, and the gather-fused form
-(``*_gather``) takes *indices* and DMAs only the needed rows in-kernel
+(``*_gather``, the only form the step runs) takes *indices* and DMAs only
+the needed rows in-kernel
 (source matrix stays in HBM/ANY, read as lane-padded rows; index slabs
 staged into SMEM by the pipeline).  At d <= 4, when the embedding fits
 a VMEM budget, ``ne_forces_gather``'s edge mode and the candidate-fused
@@ -28,10 +29,10 @@ its activity flags there (``knn_merge.ops.merge_sources``).
 reactions are index-binned in-kernel into
 per-segment (N, d) displacement fields (reference on
 ``jax.ops.segment_sum``), so the per-edge tensors never round-trip
-through HBM.  The gather-fused forms are the per-iteration default
-(funcsne §Perf H12/H13); the edge-emitting force epilogue is the
-default and scatter fusion (H14) an option; the pre-gather forms remain
-for A/B testing and as building blocks elsewhere.
+through HBM.  The edge-emitting force epilogue is the default and
+scatter fusion (funcsne §Perf H14) an option.  The pre-gather forms and
+the operand-taking ``knn_merge_pallas`` no longer run in the step; they
+serve tests, ``core/baselines.py`` and the benchmark scripts.
 
 ``backend.resolve`` maps ``backend="auto"`` to 'pallas' on a TPU and
 'xla' elsewhere for every ``ops.py`` wrapper.

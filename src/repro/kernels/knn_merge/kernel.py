@@ -313,13 +313,10 @@ def knn_merge_pallas(
 # Candidate-fused sampling (§Perf H17): the kernel *generates* the
 # candidate slots it scores.
 #
-# After PR 4 the selection epilogue lived in-kernel but candidate
-# *generation* still ran as plain XLA: per step, `sample_hops`
-# materialised an (n, s, K2) two-hop gather broadcast in HBM, the
-# threefry split/randint chain re-ran, and the resulting (n, C) candidate
-# tensor round-tripped HBM just to be re-read by this kernel's SMEM
-# slabs.  Here the candidates are *derived* inside the kernel from state
-# it already stages:
+# Generating candidates in XLA would materialise an (n, s, K2) two-hop
+# gather broadcast in HBM and round-trip the (n, C) candidate tensor just
+# to be re-read by this kernel's SMEM slabs.  Here the candidates are
+# *derived* inside the kernel from state it already stages:
 #
 #   * draws come from the counter-based hash RNG in ``repro.core.knn``
 #     (``hash3(salt, row, draw)``): the identical int32 arithmetic runs

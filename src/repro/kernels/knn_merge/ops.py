@@ -3,9 +3,8 @@
 Backend selection matches the other kernel packages:
   'pallas'    -- compiled Pallas kernel (TPU runtime)
   'interpret' -- Pallas interpret mode (CPU validation of the kernel body)
-  'xla'       -- legacy selection pipeline (dedup_candidates + gather-ref
-                 distances + merge_knn): flipping ``cfg.merge_fused`` is
-                 bit-neutral on this path
+  'xla'       -- the pure-jnp reference: the selection pipeline
+                 (dedup_candidates + gather-ref distances + merge_knn)
   'auto'      -- 'pallas' when a TPU is present, else 'xla'
 """
 from __future__ import annotations

@@ -2,11 +2,9 @@
 
 Two reference implementations, same interface as the kernel:
 
-  * :func:`knn_merge_ref` -- the exact legacy selection pipeline
+  * :func:`knn_merge_ref` -- the selection pipeline
     (``knn_lib.dedup_candidates`` + gather-ref distances +
-    ``knn_lib.merge_knn``).  This is the 'xla' backend: with it, flipping
-    ``cfg.merge_fused`` is bit-neutral on the XLA path, the same contract
-    the gather-fused rewiring established.
+    ``knn_lib.merge_knn``).  This is the 'xla' backend.
   * :func:`knn_merge_rank_ref` -- the kernel's stable-rank selection
     (``merge_select``) as a flat XLA program: identical outputs with no
     ``top_k``/sort and no (B, C, K) dedup broadcast, used as the
@@ -30,7 +28,8 @@ def _knn_lib():
 
 
 def _score(x, qid, cur_idx, cur_d, cand, cur_valid):
-    """(cur_d, cand_d) exactly as the legacy call sites computed them."""
+    """(cur_d, cand_d): stored or re-scored current distances and the
+    candidates' distances, via the gather reference."""
     if cur_d is None:
         # LD rescore: one fused launch scores current + candidate rows
         # (the embedding moved since the list was merged)
@@ -75,7 +74,7 @@ def knn_merge_cand_ref(x, qid, cur_idx, cur_d, *, salt, sources,
     two-hop gathers, no (B, s, K2) broadcast, no threefry) and resolves
     per-candidate activity as ``active[clip(cand)]`` exactly like the
     kernel's element DMAs.  ``rank=True`` runs the stable-rank selection
-    (the kernel's algorithm as flat XLA) instead of the legacy
+    (the kernel's algorithm as flat XLA) instead of the
     dedup+top_k pipeline; both give identical outputs.
     """
     knn_lib = _knn_lib()

@@ -4,28 +4,30 @@ Contract layers:
 
   * RNG statistics -- chi-square uniformity of the counter hash (it is a
     deterministic function, so the test is exactly reproducible) and a
-    distribution match of the fused two-hop sampler against the legacy
-    ``jax.random`` ``sample_hops`` (same tables, aggregated marginals).
+    distribution match of the fused two-hop sampler against a NumPy
+    statement of the two-hop distribution (same tables, aggregated
+    marginals).
   * kernel vs oracle -- the candidate-generating Pallas kernel
     (interpret mode) must reproduce the pure-jnp counter sampler
-    (``knn_lib.counter_candidates``) feeding the legacy selection
+    (``knn_lib.counter_candidates``) feeding the reference selection
     pipeline EXACTLY on discrete outputs: quantised coordinates make
     every distance representable, so generation, chained two-hop DMAs,
     per-candidate active DMAs, dedup and merge are all pinned bitwise.
   * step level -- a 50-step trajectory with in-kernel generation is
     bit-equal to the same 50 steps where the jnp reference sampler
     generates the candidates and feeds them to the operand-taking merge
-    kernel (the acceptance anchor); on the 'xla' backend the
-    ``merge_fused`` flag stays bit-neutral within ``cand_fused=True``.
-  * HLO -- with ``cand_fused=True`` the compiled step contains NO
-    threefry ops and NO (n, s, K2) two-hop gather broadcast; the legacy
-    flag is the positive control for both detectors.
-  * satellites -- cached reverse-edge table (legacy fill protocol
-    bit-parity at ``rev_refresh=1``, cache-corruption invariance,
-    cadence negative control, the ``nnd`` driver's parity) and the
+    kernel (the acceptance anchor).
+  * HLO -- the compiled step's instructions hold NO threefry ops and NO
+    (n, s, K2) two-hop gather broadcast; a jitted ``jax.random`` draw
+    and a hand-built two-hop gather are the positive controls for the
+    two detectors.
+  * satellites -- cached reverse-edge table (cache-corruption
+    invariance at ``rev_refresh=1``, cadence negative control, the
+    ``nnd`` driver's cache vs in-step parity) and the
     ``fit(auto_rescale=)`` ChunkMetrics consumer.
 """
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -85,30 +87,33 @@ def test_counter_stream_shard_invariance():
 
 
 def test_two_hop_marginal_matches_legacy_sampler():
-    """The fused two-hop source must draw from the same distribution as
-    ``sample_hops`` (uniform a, SENTINEL fallback, uniform b): aggregate
-    marginals over many trials agree within a small TV distance."""
+    """The fused two-hop source must draw from the two-hop distribution
+    ``second[first[r, a], b]`` with a uniform, SENTINEL mids falling back
+    to the row id, and b uniform: aggregate marginals over many trials
+    agree with a NumPy sampler of that distribution within a small TV
+    distance."""
     rng = np.random.default_rng(0)
     n, k1, k2, s, trials = 50, 6, 5, 4, 300
     first = rng.integers(0, n, (n, k1)).astype(np.int32)
     first[rng.random((n, k1)) < 0.2] = SENTINEL
-    second = jnp.asarray(rng.integers(0, n, (n, k2)).astype(np.int32))
-    first = jnp.asarray(first)
-    rows = jnp.arange(n, dtype=jnp.int32)
+    second = rng.integers(0, n, (n, k2)).astype(np.int32)
+    rows = np.arange(n, dtype=np.int32)
 
-    legacy = []
-    for t in range(trials):
-        key = jax.random.fold_in(jax.random.PRNGKey(9), t)
-        legacy.append(np.asarray(
-            knn_lib.sample_hops(key, first, second, rows, s)))
+    ref = []
+    for _ in range(trials):
+        a = rng.integers(0, k1, (n, s))
+        mid = np.take_along_axis(first, a, axis=1)
+        mid = np.where(mid == SENTINEL, rows[:, None] % n, mid)
+        b = rng.integers(0, k2, (n, s))
+        ref.append(second[mid, b])
     fused = []
     for t in range(trials):
         fused.append(np.asarray(knn_lib.counter_candidates(
-            jnp.int32(t), rows, (("two_hop", 0, 0, s),), (first,),
-            (second,))))
-    h_leg = np.bincount(np.concatenate(legacy).ravel(), minlength=n)
+            jnp.int32(t), jnp.asarray(rows), (("two_hop", 0, 0, s),),
+            (jnp.asarray(first),), (jnp.asarray(second),))))
+    h_ref = np.bincount(np.concatenate(ref).ravel(), minlength=n)
     h_fus = np.bincount(np.concatenate(fused).ravel(), minlength=n)
-    tv = 0.5 * np.abs(h_leg / h_leg.sum() - h_fus / h_fus.sum()).sum()
+    tv = 0.5 * np.abs(h_ref / h_ref.sum() - h_fus / h_fus.sum()).sum()
     assert tv < 0.03, tv
 
 
@@ -180,7 +185,7 @@ def _assert_cand_parity(n, m, b, k, seed, *, rescore, use_active,
 @pytest.mark.parametrize("rescore", [False, True])
 def test_cand_kernel_vs_ref_sweep(n, m, b, k, bb, bm, rescore):
     """In-kernel generation (hash draws, chained two-hop element DMAs,
-    active DMAs) == jnp counter sampler + legacy selection, exactly."""
+    active DMAs) == jnp counter sampler + reference selection, exactly."""
     _assert_cand_parity(n, m, b, k, seed=n + m + k, rescore=rescore,
                         use_active=True, use_extra=True, block_b=bb,
                         block_m=bm)
@@ -291,22 +296,6 @@ def test_cand_fused_step_bit_equal_to_jnp_sampler_feed(monkeypatch):
     _assert_states_equal(st_kernel, st_feed)
 
 
-def test_cand_fused_merge_flag_bit_neutral_on_xla():
-    """Within cand_fused=True the merge_fused anchor survives: on the
-    'xla' backend both settings run the jnp sampler + legacy selection,
-    so 50 steps are bit-identical."""
-    X, _ = blobs(n=257, dim=13, n_centers=4, center_std=5.0, seed=0)
-    Xj = jnp.asarray(X)
-    cfg_m = funcsne.FuncSNEConfig(n_points=257, dim_hd=13, backend="xla",
-                                  c_hd_rev=2, merge_fused=True)
-    cfg_l = dataclasses.replace(cfg_m, merge_fused=False)
-    st0 = funcsne.init_state(jax.random.PRNGKey(0), Xj, cfg_m)
-    hp = funcsne.default_hparams(257)
-    st_m = _run_steps(cfg_m, st0, Xj, hp, 50)
-    st_l = _run_steps(cfg_l, st0, Xj, hp, 50)
-    _assert_states_equal(st_m, st_l)
-
-
 # --------------------------------------------------------------------------
 # HLO: threefry and the two-hop broadcast are structurally gone
 
@@ -320,6 +309,19 @@ def _step_hlo_text(cfg, n):
     return step.lower(st_, X, hp).compile().as_text()
 
 
+def _instruction_lines(text):
+    """The instruction lines (``%name = ...``) of an HLO module's text.
+    The module also carries stack-frame tables of file and function
+    names -- this test's own name among them -- which are not program."""
+    return [l for l in text.splitlines()
+            if re.match(r"\s*(ROOT\s+)?%[\w.\-]+ = ", l)]
+
+
+def _random_op_lines(text):
+    return [l for l in _instruction_lines(text)
+            if "threefry" in l.lower() or "rng-bit-generator" in l.lower()]
+
+
 def _twohop_broadcast_shapes(text, cfg, n):
     from repro.launch.hlo_analysis import module_array_shapes
     tails = {(cfg.c_hd_non, cfg.k_hd), (cfg.c_hd_ld_non, cfg.k_ld),
@@ -331,27 +333,40 @@ def _twohop_broadcast_shapes(text, cfg, n):
 
 @pytest.mark.parametrize("backend", ["interpret", "xla"])
 def test_cand_fused_step_hlo_no_threefry_no_twohop_broadcast(backend):
-    """Acceptance: with cfg.cand_fused=True the compiled step contains
-    no threefry/random-bits ops anywhere (gate, candidates, negatives
-    all run on the counter RNG) and no (n, s, K2) two-hop gather
-    broadcast (in-kernel chains / flat gathers).  The legacy flag is the
-    positive control for both detectors."""
+    """Acceptance: the compiled step contains no threefry/random-bits
+    ops anywhere (gate, candidates, negatives all run on the counter
+    RNG) and no (n, s, K2) two-hop gather broadcast (in-kernel chains /
+    flat gathers).  A jitted ``jax.random`` split + randint and a
+    hand-built (n, s, K2) two-hop gather are the positive controls."""
     n = 257
-    kw = dict(n_points=n, dim_hd=7, backend=backend)
-    cfg_f = funcsne.FuncSNEConfig(cand_fused=True, **kw)
-    text_f = _step_hlo_text(cfg_f, n)
-    low = text_f.lower()
-    assert low.count("threefry") == 0, "threefry back in the fused step"
-    assert "rng-bit-generator" not in low
-    assert _twohop_broadcast_shapes(text_f, cfg_f, n) == [], \
-        "(n, s, K2) two-hop broadcast back in the fused step"
+    cfg = funcsne.FuncSNEConfig(n_points=n, dim_hd=7, backend=backend)
+    text = _step_hlo_text(cfg, n)
+    assert _random_op_lines(text) == [], "threefry back in the step"
+    assert _twohop_broadcast_shapes(text, cfg, n) == [], \
+        "(n, s, K2) two-hop broadcast back in the step"
 
-    cfg_l = funcsne.FuncSNEConfig(cand_fused=False, **kw)
-    text_l = _step_hlo_text(cfg_l, n)
-    assert text_l.lower().count("threefry") > 0, \
-        "detector is blind: legacy path shows no threefry"
-    assert _twohop_broadcast_shapes(text_l, cfg_l, n), \
-        "detector is blind: legacy path shows no two-hop broadcast"
+    def draw(key):
+        ka, kb = jax.random.split(key)
+        return (jax.random.randint(ka, (n, cfg.c_hd_rand), 0, n)
+                + jax.random.randint(kb, (n, cfg.c_hd_rand), 0, n))
+
+    text_rng = jax.jit(draw).lower(jax.random.PRNGKey(0)).compile() \
+        .as_text()
+    assert _random_op_lines(text_rng), \
+        "detector is blind: jax.random shows no threefry"
+
+    def two_hop(first, second, a, b):
+        mid = jnp.take_along_axis(first, a, axis=1)       # (n, s)
+        cand = second[mid]                                 # (n, s, K2)
+        return jnp.take_along_axis(cand, b[..., None], axis=2)[..., 0]
+
+    s_, k2 = cfg.c_hd_non, cfg.k_hd
+    args = (jnp.zeros((n, cfg.k_hd), jnp.int32),
+            jnp.zeros((n, k2), jnp.int32),
+            jnp.zeros((n, s_), jnp.int32), jnp.zeros((n, s_), jnp.int32))
+    text_hop = jax.jit(two_hop).lower(*args).compile().as_text()
+    assert _twohop_broadcast_shapes(text_hop, cfg, n), \
+        "detector is blind: a two-hop gather shows no (n, s, K2) operand"
 
 
 def test_cand_fused_chunked_hlo_no_threefry():
@@ -365,48 +380,24 @@ def test_cand_fused_chunked_hlo_no_threefry():
     hp = funcsne.default_hparams(n)
     chunk = funcsne.make_chunked_step(cfg, 4)
     text = chunk.lower(st_, X, hp).compile().as_text()
-    assert text.lower().count("threefry") == 0
+    assert _instruction_lines(text), "no instruction lines parsed"
+    assert _random_op_lines(text) == []
 
 
 # --------------------------------------------------------------------------
 # Satellite: cached reverse-edge table
 
 
-def test_rev_cache_matches_legacy_fill_protocol():
-    """rev_refresh=1 on the legacy sampler reproduces the pre-cache
-    semantics bit-for-bit: after a step whose refinement ran, the cached
-    table equals a fresh ``reverse_neighbors`` built with exactly the
-    r[4] key the inline rebuild used."""
-    n = 128
-    X, _ = blobs(n=n, dim=9, n_centers=3, seed=3)
-    Xj = jnp.asarray(X)
-    cfg = funcsne.FuncSNEConfig(n_points=n, dim_hd=9, c_hd_rev=3,
-                                rev_refresh=1, cand_fused=False,
-                                backend="xla", min_refresh_prob=1.0)
-    st0 = funcsne.init_state(jax.random.PRNGKey(0), Xj, cfg)
-    hp = funcsne.default_hparams(n)
-    hd_before = jnp.array(st0.hd_idx, copy=True)
-    rng0 = st0.rng
-    st1 = _run_steps(cfg, st0, Xj, hp, 1)
-    r_hd = jax.random.split(jax.random.fold_in(rng0, 0), 4)[1]
-    fill_key = jax.random.split(r_hd, 5)[4]
-    want = knn_lib.reverse_neighbors(hd_before, n, 3, fill_rng=fill_key)
-    np.testing.assert_array_equal(np.asarray(st1.rev_idx),
-                                  np.asarray(want))
-
-
-@pytest.mark.parametrize("cand_fused", [False, True])
-def test_rev_cache_never_read_at_refresh_1(cand_fused):
+def test_rev_cache_never_read_at_refresh_1():
     """At rev_refresh=1 the cache is rebuilt before every use, so
     corrupting it between steps must not change the trajectory -- the
-    bit-parity argument that refresh=1 IS the legacy per-refinement
-    rebuild."""
+    bit-parity argument that refresh=1 IS a rebuild at every
+    refinement."""
     n = 96
     X, _ = blobs(n=n, dim=8, n_centers=3, seed=4)
     Xj = jnp.asarray(X)
     cfg = funcsne.FuncSNEConfig(n_points=n, dim_hd=8, c_hd_rev=2,
-                                rev_refresh=1, cand_fused=cand_fused,
-                                backend="xla")
+                                rev_refresh=1, backend="xla")
     st_a = funcsne.init_state(jax.random.PRNGKey(1), Xj, cfg)
     st_b = jax.tree.map(lambda x: jnp.array(x, copy=True), st_a)
     hp = funcsne.default_hparams(n)
@@ -488,27 +479,30 @@ def test_rev_cache_cadence_changes_candidates():
 
 
 def test_nnd_rev_cache_refresh1_bit_equals_legacy():
-    """The nnd driver's cached reverse table at rev_refresh=1 is
-    bit-identical to the legacy in-step rebuild (rev=None), and a
-    coarser cadence is a real behaviour change."""
+    """The nnd driver's cached reverse table, rebuilt every iteration, is
+    bit-identical to the in-step rebuild (rev=None): both pad with the
+    same counter fill.  A coarser cadence is a real behaviour change."""
+    from repro.core import nnd as nnd_lib
     from repro.core.nnd import NNDConfig, nnd, nnd_init, nnd_step
     X, _ = blobs(n=150, dim=12, n_centers=4, seed=9)
     Xj = jnp.asarray(X)
     cfg = NNDConfig(k=8, c_fwd=4, c_rev=2, backend="xla", rev_refresh=1)
     rng = jax.random.PRNGKey(0)
 
-    idx_c, d_c, hist_c = nnd(Xj, cfg, rng=rng, max_iter=6, tol=-1.0)
+    idx_s, d_s, hist_s = nnd(Xj, cfg, rng=rng, max_iter=6, tol=-1.0)
 
     idx, d = nnd_init(rng, Xj, cfg)
     step = jax.jit(lambda r, i, dd, rv: nnd_step(r, Xj, i, dd, cfg,
                                                  rev=rv))
+    rebuild = jax.jit(lambda i, r: nnd_lib._reverse_table(i, r, cfg))
     hist = []
     for it in range(6):
-        idx, d, frac = step(jax.random.fold_in(rng, it), idx, d, None)
+        r_it = jax.random.fold_in(rng, it)
+        idx, d, frac = step(r_it, idx, d, rebuild(idx, r_it))
         hist.append(float(frac))
-    np.testing.assert_array_equal(np.asarray(idx_c), np.asarray(idx))
-    np.testing.assert_array_equal(np.asarray(d_c), np.asarray(d))
-    assert hist_c == hist
+    np.testing.assert_array_equal(np.asarray(idx_s), np.asarray(idx))
+    np.testing.assert_array_equal(np.asarray(d_s), np.asarray(d))
+    assert hist_s == hist
 
     cfg3 = dataclasses.replace(cfg, rev_refresh=3)
     idx_3, _, _ = nnd(Xj, cfg3, rng=rng, max_iter=6, tol=-1.0)
@@ -516,8 +510,8 @@ def test_nnd_rev_cache_refresh1_bit_equals_legacy():
 
 
 def test_nnd_cand_fused_backends_agree():
-    """NND's cand_fused mode: the jnp sampler ('xla') and the generating
-    kernel ('interpret') produce identical refinements."""
+    """NND's candidate-generating merge: the jnp sampler ('xla') and the
+    generating kernel ('interpret') produce identical refinements."""
     from repro.core.nnd import NNDConfig, nnd_init, nnd_step
     rng = np.random.default_rng(2)
     X = jnp.asarray((rng.integers(-8, 9, (80, 10)) / 4.0)
@@ -525,8 +519,7 @@ def test_nnd_cand_fused_backends_agree():
     key = jax.random.PRNGKey(4)
     outs = {}
     for backend in ("xla", "interpret"):
-        cfg = NNDConfig(k=6, c_fwd=3, c_rev=2, backend=backend,
-                        cand_fused=True)
+        cfg = NNDConfig(k=6, c_fwd=3, c_rev=2, backend=backend)
         idx, d = nnd_init(key, X, cfg)
         for it in range(3):
             idx, d, _ = nnd_step(jax.random.fold_in(key, it), X, idx, d,

@@ -247,7 +247,7 @@ def test_cfg_compat_mismatch_raises_structured(tmp_path):
     ck.restore(_like(), expect_compat=cfg_compat(cfg))
     for other in (_cfg(n=N + 16),                      # n differs
                   _cfg(dim=DIM + 1),                   # d differs
-                  _cfg(cand_fused=not cfg.cand_fused)):  # flag matrix
+                  _cfg(scatter_fused=not cfg.scatter_fused)):  # flags
         with pytest.raises(CheckpointIncompatible) as ei:
             ck.restore(_like(), expect_compat=cfg_compat(other))
         assert ei.value.mismatches, ei.value
@@ -264,7 +264,7 @@ def test_fit_resume_mismatched_cfg_raises(tmp_path):
     policy = ResiliencePolicy(checkpoint_dir=str(tmp_path),
                               checkpoint_every=1)
     funcsne.fit(X, cfg=cfg, n_iter=8, chunk_size=4, resilience=policy)
-    bad_cfg = _cfg(cand_fused=not cfg.cand_fused)
+    bad_cfg = _cfg(scatter_fused=not cfg.scatter_fused)
     with pytest.raises(CheckpointIncompatible):
         funcsne.fit(X, cfg=bad_cfg, n_iter=8, chunk_size=4,
                     resilience=ResiliencePolicy(),

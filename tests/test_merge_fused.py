@@ -17,15 +17,12 @@ Contract layers:
     shapes/seeds over the same discrete-parity assertion plus the list
     invariants (sorted ascending, self-free, duplicate-free among finite,
     monotone improvement).
-  * step level -- flipping ``cfg.merge_fused`` on the XLA backend is
-    bit-neutral over 50 steps (the ref IS the legacy pipeline), and the
-    interpret backend drives a full step through the kernel.
-  * HLO -- the merge-fused step's compiled module contains NO top-k /
-    sort (the ``merge_knn`` selection this PR removes) and NO full-size
-    (n, C, K) / (n, C, C) dedup broadcast operand; the legacy flag is the
-    positive control for both detectors.
+  * HLO -- the step's compiled module contains NO top-k / sort (the
+    ``merge_knn`` selection the kernel replaces) and NO full-size
+    (n, C, K) / (n, C, C) dedup broadcast operand; small jitted
+    ``lax.top_k`` and ``dedup_candidates`` programs are the positive
+    controls for the two detectors.
 """
-import dataclasses
 
 import jax
 import jax.numpy as jnp
@@ -185,88 +182,6 @@ def test_property_merge_fused_discrete_parity(n, m, b, k, c, rescore, seed):
 
 
 # --------------------------------------------------------------------------
-# Step level: flag parity and interpret-backend execution
-
-
-@pytest.mark.slow
-def test_merge_fused_step_bit_equivalent_on_xla():
-    """cfg.merge_fused is bit-neutral on the XLA backend: the ref IS the
-    legacy dedup/top_k pipeline, so 50 steps from the same seed must
-    produce identical state (the gather_fused precedent)."""
-    from repro.data.synthetic import blobs
-    X, _ = blobs(n=257, dim=13, n_centers=4, center_std=5.0, seed=0)
-    Xj = jnp.asarray(X)
-    cfg_m = funcsne.FuncSNEConfig(n_points=257, dim_hd=13, backend="xla",
-                                  merge_fused=True)
-    cfg_l = dataclasses.replace(cfg_m, merge_fused=False)
-    st0 = funcsne.init_state(jax.random.PRNGKey(0), Xj, cfg_m)
-    hp = funcsne.default_hparams(257)
-
-    def run(cfg, st):
-        step = jax.jit(lambda s, x, h: funcsne.funcsne_step(cfg, s, x, h))
-        for _ in range(50):
-            st = step(st, Xj, hp)
-        return st
-
-    st_m = run(cfg_m, st0)
-    st_l = run(cfg_l, st0)
-    for name in funcsne.FuncSNEState._fields:
-        np.testing.assert_array_equal(np.asarray(getattr(st_m, name)),
-                                      np.asarray(getattr(st_l, name)),
-                                      err_msg=name)
-
-
-@pytest.mark.slow
-def test_merge_fused_step_interpret_trajectory():
-    """A few steps with the merge kernel (interpret) vs the XLA selection
-    epilogue, same interpret distance kernels: fp32-tolerance parity of
-    the embedding (the kernels reassociate distance sums, so bit equality
-    is not the contract here)."""
-    from repro.data.synthetic import blobs
-    X, _ = blobs(n=96, dim=10, n_centers=3, center_std=5.0, seed=1)
-    Xj = jnp.asarray(X)
-    kw = dict(n_points=96, dim_hd=10, k_hd=8, k_ld=6, n_negatives=5,
-              backend="interpret")
-    cfg_m = funcsne.FuncSNEConfig(merge_fused=True, **kw)
-    cfg_l = funcsne.FuncSNEConfig(merge_fused=False, **kw)
-    st_m = funcsne.init_state(jax.random.PRNGKey(3), Xj, cfg_m)
-    st_l = funcsne.init_state(jax.random.PRNGKey(3), Xj, cfg_l)
-    hp = funcsne.default_hparams(96)
-    for _ in range(3):
-        st_m = funcsne.funcsne_step(cfg_m, st_m, Xj, hp)
-        st_l = funcsne.funcsne_step(cfg_l, st_l, Xj, hp)
-    np.testing.assert_allclose(np.asarray(st_m.Y), np.asarray(st_l.Y),
-                               rtol=1e-4, atol=1e-5)
-
-
-def test_nnd_merge_fused_bit_equivalent():
-    """nnd.py's port onto knn_merge is bit-neutral on the XLA backend."""
-    from repro.core.nnd import NNDConfig, nnd_init, nnd_step
-    from repro.data.synthetic import blobs
-    X, _ = blobs(n=150, dim=12, n_centers=4, seed=9)
-    Xj = jnp.asarray(X)
-    cfg_m = NNDConfig(k=8, c_fwd=4, c_rev=2, backend="xla",
-                      merge_fused=True)
-    cfg_l = dataclasses.replace(cfg_m, merge_fused=False)
-    rng = jax.random.PRNGKey(0)
-
-    def run(cfg):
-        idx, d = nnd_init(rng, Xj, cfg)
-        fracs = []
-        for it in range(5):
-            idx, d, frac = nnd_step(jax.random.fold_in(rng, it), Xj, idx,
-                                    d, cfg)
-            fracs.append(float(frac))
-        return np.asarray(idx), np.asarray(d), fracs
-
-    idx_m, d_m, f_m = run(cfg_m)
-    idx_l, d_l, f_l = run(cfg_l)
-    np.testing.assert_array_equal(idx_m, idx_l)
-    np.testing.assert_array_equal(d_m, d_l)
-    assert f_m == f_l
-
-
-# --------------------------------------------------------------------------
 # HLO: the selection epilogue is structurally gone
 
 
@@ -294,26 +209,31 @@ def _dedup_broadcast_shapes(text, cfg, n):
 
 
 def test_merge_fused_step_hlo_has_no_topk_and_no_dedup_broadcast():
-    """Acceptance: with cfg.merge_fused=True (interpret backend = the
-    Pallas data path lowered on CPU) the compiled step contains no top-k
-    / sort anywhere and no full-size (n, C, K) or (n, C, C) dedup
-    broadcast tensor.  The legacy flag is the positive control for both
-    detectors."""
+    """Acceptance: on the interpret backend (the Pallas data path lowered
+    on CPU) the compiled step contains no top-k / sort anywhere and no
+    full-size (n, C, K) or (n, C, C) dedup broadcast tensor.  Small
+    jitted ``lax.top_k`` and ``dedup_candidates`` programs at the step's
+    shapes are the positive controls for the two detectors."""
     n = 257
-    kw = dict(n_points=n, dim_hd=7, backend="interpret")
-    cfg_m = funcsne.FuncSNEConfig(merge_fused=True, **kw)
-    text_m = _step_hlo_text(cfg_m, n)
-    assert _topk_or_sort_lines(text_m) == [], \
+    cfg = funcsne.FuncSNEConfig(n_points=n, dim_hd=7, backend="interpret")
+    text = _step_hlo_text(cfg, n)
+    assert _topk_or_sort_lines(text) == [], \
         "top_k/sort back in the merge-fused step"
-    assert _dedup_broadcast_shapes(text_m, cfg_m, n) == [], \
+    assert _dedup_broadcast_shapes(text, cfg, n) == [], \
         "full-size dedup broadcast back in the merge-fused step"
 
-    cfg_l = funcsne.FuncSNEConfig(merge_fused=False, **kw)
-    text_l = _step_hlo_text(cfg_l, n)
-    assert _topk_or_sort_lines(text_l), \
-        "detector is blind: legacy path shows no top_k/sort"
-    assert _dedup_broadcast_shapes(text_l, cfg_l, n), \
-        "detector is blind: legacy path shows no dedup broadcast"
+    d = jnp.zeros((n, cfg.k_hd + cfg.c_hd), jnp.float32)
+    text_topk = jax.jit(lambda d: jax.lax.top_k(-d, cfg.k_hd)) \
+        .lower(d).compile().as_text()
+    assert _topk_or_sort_lines(text_topk), \
+        "detector is blind: lax.top_k shows no top_k/sort"
+    ids = jnp.arange(n, dtype=jnp.int32)
+    cur = jnp.zeros((n, cfg.k_hd), jnp.int32)
+    cand = jnp.zeros((n, cfg.c_hd), jnp.int32)
+    text_dedup = jax.jit(knn_lib.dedup_candidates) \
+        .lower(ids, cur, cand).compile().as_text()
+    assert _dedup_broadcast_shapes(text_dedup, cfg, n), \
+        "detector is blind: dedup_candidates shows no dedup broadcast"
 
 
 def test_merge_fused_chunked_step_hlo_clean():

@@ -42,7 +42,7 @@ def _random_forces_state(n, k_hd, k_ld, n_neg, d, seed, *,
     rng = np.random.default_rng(seed)
     cfg = funcsne.FuncSNEConfig(n_points=n, dim_hd=4, dim_ld=d, k_hd=k_hd,
                                 k_ld=k_ld, n_negatives=n_neg, backend="xla",
-                                gather_fused=True, scatter_fused=True)
+                                scatter_fused=True)
     hd_idx = rng.integers(0, n, (n, k_hd)).astype(np.int32)
     hd_d = np.sort(rng.random((n, k_hd)).astype(np.float32) * 5.0, axis=1)
     # invalid slots in all the ways _forces_update must mask: SENTINEL
@@ -374,7 +374,7 @@ def test_scatter_fused_step_hlo_has_no_edge_tensor():
     backend = the Pallas kernel data path, lowered on CPU).  The legacy
     edge-emitting path is the positive control for the detector."""
     n = 257
-    kw = dict(n_points=n, dim_hd=7, backend="interpret", gather_fused=True)
+    kw = dict(n_points=n, dim_hd=7, backend="interpret")
     fused = _edge_shapes_in_step_hlo(
         funcsne.FuncSNEConfig(scatter_fused=True, **kw), n)
     assert fused == [], f"per-edge tensors back in the hot path: {fused}"
